@@ -22,7 +22,7 @@ import optax
 
 import horovod_tpu as hvd
 from horovod_tpu import models
-from horovod_tpu.parallel import data_parallel_step
+from horovod_tpu.parallel import data_parallel_step, shard_batch
 
 
 def main():
@@ -43,15 +43,19 @@ def main():
     n = hvd.size()
     batch = args.batch_size * n
     sz = args.image_size
-    images = jnp.asarray(np.random.RandomState(0).randn(batch, sz, sz, 3),
-                         jnp.bfloat16)
-    labels = jnp.asarray(np.random.RandomState(1).randint(0, 1000, (batch,)))
+    # each process makes the shard of its own chips; shard_batch spreads it
+    # over them (a bare jnp.asarray parks the whole batch on one device)
+    local = args.batch_size * hvd.global_process_set().local_size
+    host_images = np.random.RandomState(0).randn(
+        local, sz, sz, 3).astype(jnp.bfloat16)
+    images, labels = shard_batch(
+        (host_images, np.random.RandomState(1).randint(0, 1000, (local,))))
 
     # extra rngs are ignored by models that take none (flax contract), so
     # one init/apply shape serves BN-only, dropout-only, and plain models
     rngs = {"params": jax.random.PRNGKey(0),
             "dropout": jax.random.PRNGKey(17)}
-    variables = model.init(rngs, images[:2], train=True)
+    variables = model.init(rngs, jnp.asarray(host_images[:2]), train=True)
     params = variables["params"]
     batch_stats = variables.get("batch_stats")
     compression = hvd.Compression.fp16 if args.fp16_allreduce else hvd.Compression.none

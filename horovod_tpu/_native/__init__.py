@@ -62,7 +62,7 @@ def _build(so: str, mode: str) -> bool:
                 check=True, capture_output=True, timeout=120)
         return True
     except Exception as e:
-        LOG.debug("native core build failed (%s); using numpy fallback", e)
+        LOG.warning("native core build failed (%s); using numpy fallback", e)
         return False
 
 
@@ -117,7 +117,8 @@ def lib():
                 return None
             _libs[so] = L
         except Exception as e:
-            LOG.debug("native core load failed: %s", e)
+            LOG.warning("native core load failed (%s); using numpy fallback",
+                        e)
     return _libs[so]
 
 

@@ -188,16 +188,15 @@ def _maybe_init_distributed():
 
     if getattr(_dist.global_state, "client", None) is not None:
         return  # already initialized
-    try:
-        jax.distributed.initialize(
-            coordinator_address=coord,
-            num_processes=nproc,
-            process_id=int(os.environ[env_schema.HOROVOD_TPU_PROCESS_ID]),
-        )
-        LOG.info("jax.distributed initialized via %s", coord)
-        _install_fatal_exit_hook()
-    except Exception as e:
-        LOG.warning("jax.distributed.initialize failed: %s", e)
+    # a failure raises: a launcher-spawned worker that carried on as a
+    # world of one would train on its own and report success
+    jax.distributed.initialize(
+        coordinator_address=coord,
+        num_processes=nproc,
+        process_id=int(os.environ[env_schema.HOROVOD_TPU_PROCESS_ID]),
+    )
+    LOG.info("jax.distributed initialized via %s", coord)
+    _install_fatal_exit_hook()
 
 
 def _install_fatal_exit_hook():
@@ -260,6 +259,12 @@ def init(ranks: Optional[Sequence[int]] = None, *, start_runtime: bool = True):
         if _ctx.initialized:
             return
         _maybe_init_distributed()
+        if os.environ.get(env_schema.HOROVOD_RANK) is not None:
+            # launcher-spawned workers compile the same programs: they
+            # share one persistent cache, placed by the one rule
+            from ..utils.compile_cache import enable_compilation_cache
+
+            enable_compilation_cache()
         _ctx.config = RuntimeConfig.from_env()
         devices = _sorted_devices()
         if ranks is not None:

@@ -74,13 +74,11 @@ HOROVOD_ELASTIC_RESPAWN_BACKOFF = "HOROVOD_ELASTIC_RESPAWN_BACKOFF"
 HOROVOD_ELASTIC_EPOCH = "HOROVOD_ELASTIC_EPOCH"
 HOROVOD_ELASTIC_GEN = "HOROVOD_ELASTIC_GEN"
 HOROVOD_ELASTIC_STORE = "HOROVOD_ELASTIC_STORE"
-# steady-state fast path (docs/performance.md): staging-ring slot count,
-# escape hatch disabling compiled fused-chunk plans (legacy per-cycle
-# eager dispatch), and the backend liveness-probe timeout in seconds
-# (common/util.py probe_backend; the verdict is cached per process)
+# steady-state fast path (docs/performance.md): staging-ring slot count
+# and the escape hatch disabling compiled fused-chunk plans (legacy
+# per-cycle eager dispatch)
 HOROVOD_STAGING_RING_SLOTS = "HOROVOD_STAGING_RING_SLOTS"
 HOROVOD_FUSED_PLAN_DISABLE = "HOROVOD_FUSED_PLAN_DISABLE"
-HOROVOD_BACKEND_PROBE_TIMEOUT = "HOROVOD_BACKEND_PROBE_TIMEOUT"
 # cross-rank distributed tracing (utils/tracing.py; docs/timeline.md):
 # master switch, buffered-span cap per rank, and a clock-offset override
 # (seconds this rank's clock must be shifted to match the rendezvous
@@ -88,8 +86,6 @@ HOROVOD_BACKEND_PROBE_TIMEOUT = "HOROVOD_BACKEND_PROBE_TIMEOUT"
 HOROVOD_TRACE = "HOROVOD_TRACE"
 HOROVOD_TRACE_BUFFER = "HOROVOD_TRACE_BUFFER"
 HOROVOD_TRACE_CLOCK_OFFSET = "HOROVOD_TRACE_CLOCK_OFFSET"
-# persistent jit compile cache directory toggle (utils/compile_cache.py)
-HOROVOD_COMPILE_CACHE = "HOROVOD_COMPILE_CACHE"
 # runtime lock-order/hold auditor (utils/lockcheck.py; docs/development.md):
 # master switch and the held-too-long warning threshold in milliseconds
 HOROVOD_LOCKCHECK = "HOROVOD_LOCKCHECK"

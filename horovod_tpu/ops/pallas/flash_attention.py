@@ -28,8 +28,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# lane width of a TPU vector register: the last dimension of every block
+# the TPU compiler accepts is a multiple of it (or the whole array's)
+LANES = 128
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
@@ -59,12 +63,14 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
             cols = ki * block_k + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(rows >= cols + causal_offset, s, NEG_INF)
+        # running stats stay [bq, 1] columns (one per score row): the
+        # TPU has no 1-D vector layout
         m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[:] = l_scr[:] * alpha + p.sum(axis=-1)
-        acc_scr[:] = acc_scr[:] * alpha[:, None] + jax.lax.dot_general(
+        l_scr[:] = l_scr[:] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
             p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[:] = m_new
@@ -81,14 +87,34 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     def _finalize():
         # guard fully-masked rows (l == 0 never happens when causal includes
         # the diagonal, but ring callers may pass degenerate blocks)
-        l = jnp.where(l_scr[:] == 0.0, 1.0, l_scr[:])
-        o_ref[0] = (acc_scr[:] / l[:, None]).astype(o_ref.dtype)
-        m_ref[0] = m_scr[:]
-        l_ref[0] = l_scr[:]
+        l = l_scr[:]
+        o_ref[0] = (acc_scr[:] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
+
+        def row(col):
+            # [bq, 1] column -> [1, bq] lane-major row for the [B, 1, sq]
+            # outputs: spread over a lane tile, transpose, keep one row
+            return jnp.broadcast_to(col, (block_q, LANES)).T[:1]
+
+        m_ref[0] = row(m_scr[:])
+        l_ref[0] = row(l)
 
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def kernel_tiles(sq: int, sk: int, block_q: int, block_k: int,
+                 lane_aligned: bool = True) -> bool:
+    """Can the kernel take these shapes? Blocks must divide the sequences;
+    the TPU compiler (``lane_aligned``) also wants every block a multiple
+    of the lane width, or the whole sequence — the [1, bq] rows of m/l and
+    the [bq, bk] score tile. Interpret mode needs only the first."""
+    bq, bk = min(block_q, sq), min(block_k, sk)
+    if sq % bq or sk % bk:
+        return False
+    return not lane_aligned or all(
+        b % LANES == 0 or b == n for b, n in ((bq, sq), (bk, sk)))
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
@@ -104,12 +130,14 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
     sk = k.shape[1]
     bq = min(block_q, sq)
     bk = min(block_k, sk)
-    if sq % bq or sk % bk:
+    interpret = _interpret()
+    if not kernel_tiles(sq, sk, bq, bk, lane_aligned=not interpret):
         raise ValueError(
             f"sequence lengths ({sq}, {sk}) must be divisible by the block "
-            f"sizes ({bq}, {bk}); pick block_q/block_k that tile the "
-            "sequence or use the blockwise XLA fallback (scan_stats / "
-            "use_flash=False)")
+            f"sizes ({bq}, {bk}), and on TPU each block a multiple of "
+            f"{LANES} or the whole sequence; pick block_q/block_k that "
+            "tile the sequence or use the blockwise XLA fallback "
+            "(scan_stats / use_flash=False)")
     nq, nk = sq // bq, sk // bk
     scale = d ** -0.5
 
@@ -117,8 +145,12 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
         _flash_fwd_kernel, scale=scale, causal=causal,
         causal_offset=causal_offset, block_q=bq, block_k=bk,
         num_k_blocks=nk)
-    from jax.experimental.pallas import tpu as pltpu
-
+    # inside a vma-checked shard_map (ring attention) the outputs vary
+    # over the mesh axes the inputs vary over; frozenset() elsewhere
+    vma = frozenset().union(*(jax.typeof(x).vma for x in (q, k, v)))
+    # m/l leave the kernel as [B, 1, sq]: a (1, 1, bq) block is legal on
+    # TPU (second-to-last dim = the whole array's, last a lane multiple),
+    # a (1, bq) block of [B, sq] is not
     o, m, l = pl.pallas_call(
         kernel,
         grid=(B, nq, nk),
@@ -129,22 +161,22 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i)),
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((B, sq), jnp.float32),
-            jax.ShapeDtypeStruct((B, sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, sq, d), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((B, 1, sq), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((B, 1, sq), jnp.float32, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=interpret,
     )(q, k, v)
-    return o, m, l
+    return o, m[:, 0], l[:, 0]
 
 
 def _reference_attention(q, k, v, causal: bool, causal_offset: int = 0):

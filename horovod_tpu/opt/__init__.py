@@ -24,8 +24,8 @@ transposition — gradients arrive pre-summed and a manual allreduce would
 double-count. The Horovod contract (local gradients, explicit allreduce —
 what this module provides) corresponds to ``check_vma=False`` shard_map
 regions, which is what `horovod_tpu.parallel.dp` train-step builders use.
-In vma-typed code, either keep params varying (``lax.pvary``) or skip the
-manual allreduce.
+In vma-typed code, either keep params varying
+(``lax.pcast(..., to="varying")``) or skip the manual allreduce.
 
 Fusion note: inside jit, per-tensor ``psum`` calls are fused by XLA; with
 ``fuse_buckets=True`` we additionally flatten the gradient pytree into one

@@ -63,21 +63,30 @@ def _ring_scan(q, k, v, axis_name, round_stats):
         return (kf, vf, m_new, l_new, o_acc), None
 
     init = (to_flat(k), to_flat(v),
-            lax.pvary(jnp.full((b * h, s), NEG_INF, jnp.float32), axis_name),
-            lax.pvary(jnp.zeros((b * h, s), jnp.float32), axis_name),
-            lax.pvary(jnp.zeros((b * h, s, d), jnp.float32), axis_name))
+            _varying(jnp.full((b * h, s), NEG_INF, jnp.float32), axis_name),
+            _varying(jnp.zeros((b * h, s), jnp.float32), axis_name),
+            _varying(jnp.zeros((b * h, s, d), jnp.float32), axis_name))
     (_, _, _, l_acc, o_acc), _ = lax.scan(round_fn, init, jnp.arange(n))
     out = o_acc / jnp.where(l_acc == 0.0, 1.0, l_acc)[..., None]
     return (out.reshape(b, h, s, d).transpose(0, 2, 1, 3)).astype(q.dtype)
 
 
+def _varying(x, axis_name):
+    """Type a constant as device-varying over ``axis_name``: constants are
+    replication-typed, and scan carries / switch branches demand the same
+    type as the per-chip values they sit beside."""
+    return lax.pcast(x, axis_name, to="varying")
+
+
 def _auto_flash(s, block_q, block_k, use_flash):
     if use_flash is not None:
         return use_flash
-    # kernel blocks must tile the local sequence exactly; fall back to
-    # the XLA stats path for shapes that don't
+    from ..ops.pallas.flash_attention import kernel_tiles
+
+    # on TPU the kernel answers for every local sequence its blocks tile;
+    # only shapes the TPU compiler would refuse take the XLA stats path
     return (jax.default_backend() == "tpu"
-            and s % min(block_q, s) == 0 and s % min(block_k, s) == 0)
+            and kernel_tiles(s, s, block_q, block_k))
 
 
 def ring_attention(q, k, v, axis_name: str = "sp", use_flash=None,
@@ -113,12 +122,10 @@ def ring_attention(q, k, v, axis_name: str = "sp", use_flash=None,
         return lax.switch(branch, [
             lambda kv: stats(qf, kv[0], kv[1], True),
             lambda kv: stats(qf, kv[0], kv[1], False),
-            # pvary: constants are replication-typed; the other branches'
-            # outputs vary over the sp axis, and switch demands equal types
             lambda kv: (jnp.zeros_like(qf),
-                        lax.pvary(jnp.full((B, sq), NEG_INF, jnp.float32),
-                                  axis),
-                        lax.pvary(jnp.zeros((B, sq), jnp.float32), axis)),
+                        _varying(jnp.full((B, sq), NEG_INF, jnp.float32),
+                                 axis),
+                        _varying(jnp.zeros((B, sq), jnp.float32), axis)),
         ], (kf, vf))
 
     return _ring_scan(q, k, v, axis_name, round_stats)

@@ -7,6 +7,9 @@ SSH fan-out :226-271), mpi_run.py. TPU-native differences:
 - rendezvous = our HTTP KV store + ``jax.distributed.initialize`` (the
   coordination service replaces MPI/Gloo bootstrap);
 - one worker process per host VM drives all local chips (slots default 1);
+  with as many local workers as the host has chips, worker k gets chip k
+  and nothing else (``slot_env``); the launcher itself never initialises
+  a JAX backend, because a process that has done so holds the chips;
 - NIC discovery is a launcher-side route probe (runner/network.py) instead
   of the reference's SSH'd task-service intersection protocol — ICI
   topology is discovered by the TPU runtime itself, the launcher only has
@@ -22,6 +25,7 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import shlex
 import signal
@@ -46,10 +50,64 @@ def _free_port() -> int:
     return port
 
 
+#: chips on a host -> the host's chip grid (libtpu's x,y,z bounds). Only
+#: what has run: a v5e 2x2 host.
+_HOST_CHIP_BOUNDS = {4: "2,2,1"}
+
+
+def host_chips() -> int:
+    """TPU chips attached to this host, counted from their device files:
+    the launcher must not ask JAX, because a process that has initialised
+    a backend holds the chips and its workers then fail or hang."""
+    return len(glob.glob("/dev/vfio/[0-9]*") + glob.glob("/dev/accel[0-9]*"))
+
+
+def _chip_env(slot: SlotInfo, coordinator: str, platforms: str) -> dict:
+    """One process for each chip: the libtpu settings that give the worker
+    with local rank k chip k and nothing else, as one process of a slice
+    that spans the host, so ``jax.distributed`` assembles one world of
+    ``local_size`` devices. Empty — nothing changes — for a single local
+    worker (it drives every chip), for a job held to another platform,
+    on a host without chips, and for remote hosts, whose chips the
+    launcher cannot count."""
+    if slot.local_size <= 1:
+        return {}
+    if platforms and "tpu" not in platforms.split(","):
+        return {}
+    chips = host_chips()
+    if not chips:
+        return {}
+    from .network import is_local_host
+
+    if not is_local_host(slot.hostname):
+        return {}
+    if (slot.cross_size != 1 or slot.local_size != chips
+            or chips not in _HOST_CHIP_BOUNDS):
+        raise ValueError(
+            f"{slot.local_size} workers on a host with {chips} TPU chip(s): "
+            "hvdrun gives each worker its own chip only for a single-host "
+            "job with one worker per chip on a host of "
+            f"{sorted(_HOST_CHIP_BOUNDS)} chips; use -np 1 to drive every "
+            "chip from one process")
+    # the slice-builder ports sit next to the coordinator's: every worker
+    # of the job derives the same list from what it is already given
+    base = int(coordinator.rsplit(":", 1)[1]) + 1
+    return {
+        "TPU_VISIBLE_CHIPS": str(slot.local_rank),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": _HOST_CHIP_BOUNDS[chips],
+        "TPU_PROCESS_ADDRESSES": ",".join(
+            f"localhost:{base + k}" for k in range(chips)),
+        "TPU_PROCESS_PORT": str(base + slot.local_rank),
+        "CLOUD_TPU_TASK_ID": str(slot.local_rank),
+    }
+
+
 def slot_env(slot: SlotInfo, rendezvous_addr: str, rendezvous_port: int,
              coordinator: str, extra_env: Optional[dict] = None) -> dict:
     """Per-slot env injection (reference gloo_run.py:65
-    create_slot_env_vars + gloo_context.cc:136-192 consumption)."""
+    create_slot_env_vars + gloo_context.cc:136-192 consumption), plus the
+    worker's chip when the host has one for each (``_chip_env``)."""
     e = dict(os.environ)
     # Workers must be able to import horovod_tpu even when the launcher runs
     # from a source checkout (python adds the *script* dir to sys.path, not
@@ -80,6 +138,7 @@ def slot_env(slot: SlotInfo, rendezvous_addr: str, rendezvous_port: int,
     })
     if extra_env:
         e.update(extra_env)
+    e.update(_chip_env(slot, coordinator, e.get("JAX_PLATFORMS", "")))
     return e
 
 

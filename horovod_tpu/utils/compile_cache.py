@@ -1,14 +1,20 @@
 """Persistent XLA compilation cache helper.
 
-On tunneled/remote TPU platforms, compiles are RPCs to a service whose
-availability can flap; a persistent cache makes every successfully
-compiled program a one-time cost for the machine rather than per
-process. (The reference has no analogue — CUDA kernels ship prebuilt;
-for XLA the compile IS the build step, so cache management belongs in
-the framework.)
+For XLA the compile IS the build step (the reference has no analogue —
+CUDA kernels ship prebuilt), and the ResNet-50 train step alone costs the
+better part of a minute of it, so every entry point — ``bench.py``,
+``chip_smoke.py``, ``benchmarks/*`` and launcher-spawned workers — shares
+one persistent cache, placed by one rule:
 
-The enabled cache directory is recorded (:func:`active_cache_dir`) so
-the memledger's compile instrumentation (utils/memledger.py) can infer
+- ``JAX_COMPILATION_CACHE_DIR`` set: JAX already points there; this
+  module sets no directory in code.
+- otherwise ``<checkout>/.jax_cache``, derived from the package path. The
+  path is part of the cache key, so it never depends on the home
+  directory, a temporary name, a pid or a time: a directory that moves
+  never hits.
+
+The directory in use is recorded (:func:`active_cache_dir`) so the
+memledger's compile instrumentation (utils/memledger.py) can infer
 persistent-cache hit/miss from the cache-dir entry delta across a
 compile, and a failure to enable is visible three ways instead of being
 a mystery recompile per process: a one-time warning with the reason,
@@ -20,9 +26,11 @@ import logging
 import os
 from typing import Optional
 
-from ..common import env as env_schema
-
 LOG = logging.getLogger("horovod_tpu")
+
+JAX_CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 _ACTIVE_DIR: Optional[str] = None
 _WARNED = False
@@ -34,29 +42,35 @@ def active_cache_dir() -> Optional[str]:
     return _ACTIVE_DIR
 
 
-def enable_compilation_cache(cache_dir: Optional[str] = None,
-                             min_compile_time_secs: float = 1.0,
-                             ) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``cache_dir``
-    (default: ``$HOROVOD_COMPILE_CACHE`` or ``~/.cache/horovod_tpu_xla``).
+def cache_entries() -> int:
+    """Number of entries in the active cache directory, -1 when there is
+    none or it cannot be read. A compile that leaves the count unchanged
+    was a hit."""
+    try:
+        return len(os.listdir(_ACTIVE_DIR)) if _ACTIVE_DIR else -1
+    except OSError:
+        return -1
+
+
+def enable_compilation_cache() -> Optional[str]:
+    """Turn JAX's persistent compilation cache on, placed by the module's
+    one rule. Call before the process's first compile.
 
     Returns None on success, else the failure reason (also warned once
     per process and published on the ``hvd_compile_cache_enabled``
-    gauge). Never raises: the cache is an optimization — but a
-    mis-pointed ``HOROVOD_COMPILE_CACHE`` must be visible, not silent.
+    gauge). Never raises: the cache is an optimization — but a cache
+    that is not there must be visible, not silent.
     """
     global _ACTIVE_DIR, _WARNED
     import jax
 
     try:
-        cache_dir = (cache_dir
-                     or os.environ.get(env_schema.HOROVOD_COMPILE_CACHE)
-                     or os.path.join(os.path.expanduser("~"), ".cache",
-                                     "horovod_tpu_xla"))
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          float(min_compile_time_secs))
+        cache_dir = os.environ.get(JAX_CACHE_DIR_ENV)
+        if not cache_dir:
+            cache_dir = os.path.join(_CHECKOUT, ".jax_cache")
+            os.makedirs(cache_dir, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
         reason = None
     except Exception as e:

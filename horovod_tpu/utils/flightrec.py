@@ -45,7 +45,6 @@ CATEGORIES = (
     ("fault_injected", "chaos fault fired at an instrumented site"),
     ("plan_cache_invalidated", "compiled fused-chunk plans dropped"),
     ("reshard", "sharded-update layout (re)built"),
-    ("probe_verdict", "backend liveness probe decided"),
     ("watchdog", "wedge watchdog fired"),
     ("diag_dump", "diagnostic bundle written"),
     ("quant_fallback", "tensor kept off the quantized wire"),

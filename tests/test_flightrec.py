@@ -157,9 +157,9 @@ def test_init_recorder_idempotent_and_module_note(recorder):
     rec = recorder(rank=2, capacity=64)
     assert rec is not None and rec.capacity == 64 and rec.rank == 2
     assert flightrec.init_recorder(rank=9) is rec  # reused, rank kept
-    flightrec.note("probe_verdict", ok=True)
+    flightrec.note("watchdog", ok=True)
     evs = rec.events()
-    assert evs and evs[-1]["cat"] == "probe_verdict"
+    assert evs and evs[-1]["cat"] == "watchdog"
     assert evs[-1]["rank"] == 2 and evs[-1]["kv"] == {"ok": True}
 
 
