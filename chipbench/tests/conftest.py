@@ -1,0 +1,13 @@
+"""chipbench's own tests: CPU only, not part of tier-1.
+
+    python -m pytest chipbench/tests -q
+"""
+
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
