@@ -1,0 +1,10 @@
+"""attention_ms: per step, the device time under the scope
+``hvd.model/attention`` (QKV, scores, softmax, output projection; set in
+horovod_tpu/models/transformer.py), forward, recompute and backward together;
+mean over the cell's devices. Program span."""
+
+from chipbench import step_split
+
+
+def read(trace, host, cell):
+    return step_split.ms(trace, ["hvd.model/attention"], by="part")

@@ -1,0 +1,10 @@
+"""forward_ms: per step, the device time of the instructions whose ``op_name``
+carries JAX's ``jvp(`` and none of ``hvd.grad_exchange``, ``hvd.optimizer``,
+``rematted_computation``, ``transpose(`` (phase ``forward`` of
+horovod_tpu/utils/scopes.py); mean over the cell's devices. Program span."""
+
+from chipbench import step_split
+
+
+def read(trace, host, cell):
+    return step_split.ms(trace, ["forward"])

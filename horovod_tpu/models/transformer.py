@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..utils import scopes
+
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -138,34 +140,42 @@ def apply(params, tokens, cfg: TransformerConfig, *, use_constraints: bool = Tru
     aspec = act_spec(cfg)
     if positions is None:
         positions = jnp.arange(tokens.shape[1])
-    x = params["embed"][tokens].astype(cfg.dtype)
-    x = x + params["pos"][positions].astype(cfg.dtype)[None]
+    with jax.named_scope(scopes.EMBED):
+        x = params["embed"][tokens].astype(cfg.dtype)
+        x = x + params["pos"][positions].astype(cfg.dtype)[None]
     x = _constrain(x, aspec, use_constraints)
 
+    # the scopes sit inside the block, so they survive jax.checkpoint
     def _block(x, blk):
-        h = _rmsnorm(x, blk["ln1"]["scale"])
-        q = jnp.einsum("bsd,dhk->bshk", h, blk["wq"].astype(cfg.dtype))
-        k = jnp.einsum("bsd,dhk->bshk", h, blk["wk"].astype(cfg.dtype))
-        v = jnp.einsum("bsd,dhk->bshk", h, blk["wv"].astype(cfg.dtype))
-        if attn_fn is None:
-            o = causal_attention(q, k, v)
-        else:
-            o = attn_fn(q, k, v)
-        o = jnp.einsum("bshk,hkd->bsd", o, blk["wo"].astype(cfg.dtype))
-        x = _constrain(x + o, aspec, use_constraints)
-        h = _rmsnorm(x, blk["ln2"]["scale"])
-        ff = jax.nn.gelu(jnp.einsum("bsd,df->bsf", h, blk["w1"].astype(cfg.dtype)))
-        ff = jnp.einsum("bsf,fd->bsd", ff, blk["w2"].astype(cfg.dtype))
-        return _constrain(x + ff, aspec, use_constraints)
+        with jax.named_scope(scopes.ATTENTION):
+            h = _rmsnorm(x, blk["ln1"]["scale"])
+            q = jnp.einsum("bsd,dhk->bshk", h, blk["wq"].astype(cfg.dtype))
+            k = jnp.einsum("bsd,dhk->bshk", h, blk["wk"].astype(cfg.dtype))
+            v = jnp.einsum("bsd,dhk->bshk", h, blk["wv"].astype(cfg.dtype))
+            if attn_fn is None:
+                o = causal_attention(q, k, v)
+            else:
+                o = attn_fn(q, k, v)
+            o = jnp.einsum("bshk,hkd->bsd", o, blk["wo"].astype(cfg.dtype))
+            x = x + o
+        x = _constrain(x, aspec, use_constraints)
+        with jax.named_scope(scopes.MLP):
+            h = _rmsnorm(x, blk["ln2"]["scale"])
+            ff = jax.nn.gelu(
+                jnp.einsum("bsd,df->bsf", h, blk["w1"].astype(cfg.dtype)))
+            ff = jnp.einsum("bsf,fd->bsd", ff, blk["w2"].astype(cfg.dtype))
+            x = x + ff
+        return _constrain(x, aspec, use_constraints)
 
     block_fn = jax.checkpoint(_block) if cfg.remat else _block
     for blk in params["blocks"]:
         x = block_fn(x, blk)
-    x = _rmsnorm(x, params["ln_f"]["scale"])
-    if return_hidden:
-        return x  # pre-projection activations for the chunked LM loss
-    logits = jnp.einsum("bsd,vd->bsv", x.astype(jnp.float32), params["embed"])
-    return logits
+    with jax.named_scope(scopes.HEAD):
+        x = _rmsnorm(x, params["ln_f"]["scale"])
+        if return_hidden:
+            return x  # pre-projection activations for the chunked LM loss
+        return jnp.einsum("bsd,vd->bsv", x.astype(jnp.float32),
+                          params["embed"])
 
 
 def causal_attention(q, k, v):
@@ -191,9 +201,11 @@ def lm_loss(params, tokens, cfg: TransformerConfig, **kw):
 
         h = apply(params, tokens[:, :-1], cfg, return_hidden=True, **kw)
         b, s, d = h.shape
-        return chunked_softmax_xent(h.reshape(b * s, d), params["embed"],
-                                    targets.reshape(-1), cfg.xent_chunk)
+        with jax.named_scope(scopes.HEAD):
+            return chunked_softmax_xent(h.reshape(b * s, d), params["embed"],
+                                        targets.reshape(-1), cfg.xent_chunk)
     logits = apply(params, tokens[:, :-1], cfg, **kw)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return -jnp.mean(ll)
+    with jax.named_scope(scopes.HEAD):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        return -jnp.mean(ll)

@@ -30,6 +30,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...utils import scopes
+
 NEG_INF = -1e30
 # lane width of a TPU vector register: the last dimension of every block
 # the TPU compiler accepts is a multiple of it (or the whole array's)
@@ -175,6 +177,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="hvd_flash_fwd",  # a trace's kernel events are found by it
     )(q, k, v)
     return o, m[:, 0], l[:, 0]
 
@@ -295,10 +298,12 @@ def _stats_fwd(q, k, v, causal, block_q, block_k, causal_offset):
 def _stats_bwd(causal, block_q, block_k, causal_offset, res, cts):
     q, k, v = res
     # blockwise recompute: never materializes [B, sq, sk]
-    _, vjp = jax.vjp(
-        lambda a, b, c: scan_stats(a, b, c, causal, causal_offset, block_k),
-        q, k, v)
-    return vjp(cts)
+    with jax.named_scope(scopes.ATTENTION):
+        _, vjp = jax.vjp(
+            lambda a, b, c: scan_stats(a, b, c, causal, causal_offset,
+                                       block_k),
+            q, k, v)
+        return vjp(cts)
 
 
 attention_stats.defvjp(_stats_fwd, _stats_bwd)
@@ -313,9 +318,11 @@ def _fwd(q, k, v, causal, block_q, block_k):
 
 def _bwd(causal, block_q, block_k, res, do):
     q, k, v = res
-    _, vjp = jax.vjp(
-        lambda a, b, c: scan_stats(a, b, c, causal, 0, block_k)[0], q, k, v)
-    return vjp(do)
+    with jax.named_scope(scopes.ATTENTION):
+        _, vjp = jax.vjp(
+            lambda a, b, c: scan_stats(a, b, c, causal, 0, block_k)[0],
+            q, k, v)
+        return vjp(do)
 
 
 flash_attention.defvjp(_fwd, _bwd)

@@ -45,6 +45,7 @@ import optax
 from ..common.context import DEFAULT_AXIS
 from ..ops import collectives as C
 from ..ops.collectives import ReduceOp
+from ..utils import scopes
 
 
 def _tree_allreduce(grads, op, axis_name, compression, prescale, postscale,
@@ -63,12 +64,33 @@ def _tree_allreduce(grads, op, axis_name, compression, prescale, postscale,
                                     compression=compression,
                                     prescale_factor=prescale,
                                     postscale_factor=postscale)
-    return jax.tree.map(
-        lambda g: C.allreduce(g, op=op, axis_name=axis_name,
-                              compression=compression,
-                              prescale_factor=prescale,
-                              postscale_factor=postscale),
-        grads)
+    scopes.note_exchange(jax.tree.leaves(grads), axis_name)
+    with jax.named_scope(scopes.REDUCE):
+        return jax.tree.map(
+            lambda g: C.allreduce(g, op=op, axis_name=axis_name,
+                                  compression=compression,
+                                  prescale_factor=prescale,
+                                  postscale_factor=postscale),
+            grads)
+
+
+def _pack(leaves, idxs, axis_name):
+    """The leaves ``idxs`` as the one flat buffer a collective takes."""
+    with jax.named_scope(scopes.PACK):
+        flats = [jnp.ravel(leaves[i]) for i in idxs]
+        fused = jnp.concatenate(flats) if len(flats) > 1 else flats[0]
+    scopes.note_exchange([fused], axis_name, packed=len(flats) > 1)
+    return fused
+
+
+def _unpack(red, leaves, idxs, out) -> None:
+    """`_pack`'s inverse on the reduced buffer, into ``out[i]``."""
+    off = 0
+    with jax.named_scope(scopes.UNPACK):
+        for i in idxs:
+            n = jnp.size(leaves[i])
+            out[i] = jnp.reshape(red[off:off + n], jnp.shape(leaves[i]))
+            off += n
 
 
 def _quant_partition(tree):
@@ -127,27 +149,23 @@ def quantized_tree_allreduce(tree, spec, *, op=ReduceOp.AVERAGE,
         return dict(sorted(groups.items()))
 
     for dt, idxs in _by_dtype(plain).items():
-        flats = [jnp.ravel(leaves[i]) for i in idxs]
-        fused = jnp.concatenate(flats) if len(flats) > 1 else flats[0]
-        red = C.allreduce(fused, op=op, axis_name=axis_name,
-                          prescale_factor=prescale_factor,
-                          postscale_factor=postscale_factor)
-        off = 0
-        for i in idxs:
-            n = leaves[i].size
-            out[i] = jnp.reshape(red[off:off + n], jnp.shape(leaves[i]))
-            off += n
+        fused = _pack(leaves, idxs, axis_name)
+        with jax.named_scope(scopes.REDUCE):
+            red = C.allreduce(fused, op=op, axis_name=axis_name,
+                              prescale_factor=prescale_factor,
+                              postscale_factor=postscale_factor)
+        _unpack(red, leaves, idxs, out)
     for dt, idxs in _by_dtype(elig).items():
-        flats = [jnp.ravel(leaves[i]) for i in idxs]
-        fused = jnp.concatenate(flats) if len(flats) > 1 else flats[0]
+        fused = _pack(leaves, idxs, axis_name)
         if traced:
             res = residuals.get(dt) if residuals else None
             if res is not None and res.shape != fused.shape:
                 res = None  # layout moved (resize/re-trace): clean reset
-            red, err = C.quantized_allreduce(
-                fused, axis_name, spec, op=op,
-                prescale_factor=prescale_factor,
-                postscale_factor=postscale_factor, residual=res)
+            with jax.named_scope(scopes.REDUCE):
+                red, err = C.quantized_allreduce(
+                    fused, axis_name, spec, op=op,
+                    prescale_factor=prescale_factor,
+                    postscale_factor=postscale_factor, residual=res)
             new_res[dt] = err
         else:
             # eager call (no axis in scope): the quant marker routes the
@@ -159,11 +177,7 @@ def quantized_tree_allreduce(tree, spec, *, op=ReduceOp.AVERAGE,
                               prescale_factor=prescale_factor,
                               postscale_factor=postscale_factor,
                               compression=marker)
-        off = 0
-        for i in idxs:
-            n = leaves[i].size
-            out[i] = jnp.reshape(red[off:off + n], jnp.shape(leaves[i]))
-            off += n
+        _unpack(red, leaves, idxs, out)
     return jax.tree.unflatten(treedef, out), new_res
 
 
@@ -197,16 +211,12 @@ def fused_tree_allreduce(tree, *, op=ReduceOp.AVERAGE, axis_name=DEFAULT_AXIS,
         by_dtype.setdefault(jnp.asarray(l).dtype, []).append(i)
     out = [None] * len(leaves)
     for dt, idxs in by_dtype.items():
-        flats = [jnp.ravel(leaves[i]) for i in idxs]
-        sizes = [f.shape[0] for f in flats]
-        fused = jnp.concatenate(flats) if len(flats) > 1 else flats[0]
-        red = C.allreduce(fused, op=op, axis_name=axis_name,
-                          prescale_factor=prescale_factor,
-                          postscale_factor=postscale_factor)
-        off = 0
-        for i, n in zip(idxs, sizes):
-            out[i] = jnp.reshape(red[off:off + n], jnp.shape(leaves[i]))
-            off += n
+        fused = _pack(leaves, idxs, axis_name)
+        with jax.named_scope(scopes.REDUCE):
+            red = C.allreduce(fused, op=op, axis_name=axis_name,
+                              prescale_factor=prescale_factor,
+                              postscale_factor=postscale_factor)
+        _unpack(red, leaves, idxs, out)
     if compression is not None:
         out = [compression.decompress(o, c) for o, c in zip(out, dectxs)]
     return jax.tree.unflatten(treedef, out)
@@ -299,7 +309,9 @@ def DistributedGradientTransformation(
                 prescale_factor=prescale_factor,
                 postscale_factor=postscale_factor,
                 residuals=state.residuals)
-            updates, inner = optimizer.update(reduced, state.inner, params)
+            with jax.named_scope(scopes.OPTIMIZER):
+                updates, inner = optimizer.update(reduced, state.inner,
+                                                  params)
             if not new_res:
                 new_res = state.residuals  # eager call: carry unchanged
             return updates, _QuantEFState(inner, new_res)
@@ -317,10 +329,13 @@ def DistributedGradientTransformation(
         return _tree_allreduce(grads, op, axis_name, compression,
                                prescale_factor, postscale_factor, fuse_buckets)
 
+    def _update(reduced, inner, params):
+        with jax.named_scope(scopes.OPTIMIZER):
+            return optimizer.update(reduced, inner, params)
+
     def update_fn(grads, state, params=None):
         if n <= 1:
-            reduced = _reduce(grads)
-            return optimizer.update(reduced, state, params)
+            return _update(_reduce(grads), state, params)
         acc = jax.tree.map(lambda a, g: a + g, state.acc, grads)
         counter = state.counter + 1
         is_step = counter >= n
@@ -328,7 +343,7 @@ def DistributedGradientTransformation(
         def do_step(_):
             scale = 1.0 / n if average_aggregated_gradients else 1.0
             reduced = _reduce(jax.tree.map(lambda a: a * scale, acc))
-            updates, inner = optimizer.update(reduced, state.inner, params)
+            updates, inner = _update(reduced, state.inner, params)
             zeroed = jax.tree.map(jnp.zeros_like, acc)
             return updates, _AggState(inner, zeroed, jnp.zeros((), jnp.int32))
 
@@ -473,25 +488,33 @@ def cross_replica_sharded_optimizer(inner: optax.GradientTransformation,
         groups = dict(sorted(groups.items()))
 
         def fuse(ls, dt):
-            flats = [jnp.ravel(x).astype(dt) for x in ls]
-            flat = flats[0] if len(flats) == 1 else jnp.concatenate(flats)
-            c = _chunk(flat.size)
-            return jnp.pad(flat, (0, c * num_shards - flat.size)), c
+            with jax.named_scope(scopes.PACK):
+                flats = [jnp.ravel(x).astype(dt) for x in ls]
+                flat = (flats[0] if len(flats) == 1
+                        else jnp.concatenate(flats))
+                c = _chunk(flat.size)
+                return jnp.pad(flat, (0, c * num_shards - flat.size)), c
 
         g_shard, p_shard = {}, {}
         for dt, idxs in groups.items():
             fused_g, c = fuse([leaves[i] for i in idxs], dt)
-            g_shard[dt] = jax.lax.psum_scatter(
-                fused_g, axis_name, tiled=True) / num_shards
+            scopes.note_exchange([fused_g], axis_name, packed=True)
+            with jax.named_scope(scopes.REDUCE):
+                g_shard[dt] = jax.lax.psum_scatter(
+                    fused_g, axis_name, tiled=True) / num_shards
             if p_leaves is not None:
                 fused_p, _ = fuse([p_leaves[i] for i in idxs], dt)
                 p_shard[dt] = jax.lax.dynamic_slice(fused_p, (idx * c,), (c,))
-        u_shard, new_inner = inner.update(
-            g_shard, state.inner, p_shard if p_leaves is not None else None)
+        with jax.named_scope(scopes.OPTIMIZER):
+            u_shard, new_inner = inner.update(
+                g_shard, state.inner,
+                p_shard if p_leaves is not None else None)
 
         out = list(leaves)
         for dt, idxs in groups.items():
-            full = jax.lax.all_gather(u_shard[dt], axis_name, tiled=True)
+            scopes.note_exchange([u_shard[dt]], axis_name)
+            with jax.named_scope(scopes.REDUCE):
+                full = jax.lax.all_gather(u_shard[dt], axis_name, tiled=True)
             off = 0
             for i in idxs:
                 # dtype ref: the param leaf when given — casting updates to
@@ -499,8 +522,9 @@ def cross_replica_sharded_optimizer(inner: optax.GradientTransformation,
                 # replicated trajectory
                 ref = p_leaves[i] if p_leaves is not None else leaves[i]
                 n_el = leaves[i].size
-                out[i] = jax.lax.slice(full, (off,), (off + n_el,)) \
-                    .reshape(leaves[i].shape).astype(ref.dtype)
+                with jax.named_scope(scopes.UNPACK):
+                    out[i] = jax.lax.slice(full, (off,), (off + n_el,)) \
+                        .reshape(leaves[i].shape).astype(ref.dtype)
                 off += n_el
         return jax.tree.unflatten(treedef, out), _ShardedUpdate(new_inner)
 

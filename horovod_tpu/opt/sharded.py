@@ -67,6 +67,7 @@ from ..ops.collectives import ReduceOp
 from ..parallel.sharding_policy import DEFAULT_MIN_SHARD_ELEMS, should_shard
 from ..utils import flightrec
 from ..utils import memledger as memledger_mod
+from ..utils import scopes
 
 _SUPPORTED_OPS = (ReduceOp.AVERAGE, ReduceOp.SUM)
 
@@ -279,11 +280,12 @@ def ShardedDistributedOptimizer(
         return optimizer.init(_combined_zeros(layout, jax.tree.leaves(params)))
 
     def _fuse(ls, dt, padded):
-        flats = [jnp.ravel(x).astype(dt) for x in ls]
-        flat = flats[0] if len(flats) == 1 else jnp.concatenate(flats)
-        if padded > flat.size:
-            flat = jnp.pad(flat, (0, padded - flat.size))
-        return flat
+        with jax.named_scope(scopes.PACK):
+            flats = [jnp.ravel(x).astype(dt) for x in ls]
+            flat = flats[0] if len(flats) == 1 else jnp.concatenate(flats)
+            if padded > flat.size:
+                flat = jnp.pad(flat, (0, padded - flat.size))
+            return flat
 
     def update_fn(grads, state, params=None):
         world = _axis_size(axis_name)
@@ -301,17 +303,23 @@ def ShardedDistributedOptimizer(
                                    world, min_shard_elems=mse, generation=0)
 
         g_rep = {}
+        scopes.note_exchange([leaves[i] for i in layout.replicated],
+                             axis_name)
         for i in layout.replicated:
-            g_rep[_rep_key(i)] = C.allreduce(
-                leaves[i], op=op, axis_name=axis_name,
-                prescale_factor=pre, postscale_factor=post)
+            with jax.named_scope(scopes.REDUCE):
+                g_rep[_rep_key(i)] = C.allreduce(
+                    leaves[i], op=op, axis_name=axis_name,
+                    prescale_factor=pre, postscale_factor=post)
         g_shard, p_shard = {}, {}
         for g in layout.groups:
             padded = layout.group_padded(g)
             fused = _fuse([leaves[i] for i in g.indices], g.dtype, padded)
             if pre != 1.0:
                 fused = fused * pre
-            scattered = jax.lax.psum_scatter(fused, axis_name, tiled=True)
+            scopes.note_exchange([fused], axis_name, packed=True)
+            with jax.named_scope(scopes.REDUCE):
+                scattered = jax.lax.psum_scatter(fused, axis_name,
+                                                 tiled=True)
             if op == ReduceOp.AVERAGE:
                 scattered = scattered / world
             if post != 1.0:
@@ -326,19 +334,23 @@ def ShardedDistributedOptimizer(
                                for i in layout.replicated},
                        "shard": p_shard}
                       if p_leaves is not None else None)
-        u, new_state = optimizer.update(combined_g, state, combined_p)
+        with jax.named_scope(scopes.OPTIMIZER):
+            u, new_state = optimizer.update(combined_g, state, combined_p)
 
         out = list(leaves)
         for i in layout.replicated:
             out[i] = u["rep"][_rep_key(i)]
         for g in layout.groups:
-            full = jax.lax.all_gather(u["shard"][g.dtype], axis_name,
-                                      tiled=True)
+            scopes.note_exchange([u["shard"][g.dtype]], axis_name)
+            with jax.named_scope(scopes.REDUCE):
+                full = jax.lax.all_gather(u["shard"][g.dtype], axis_name,
+                                          tiled=True)
             off = 0
             for i, n, shape in zip(g.indices, g.sizes, g.shapes):
                 ref = p_leaves[i] if p_leaves is not None else leaves[i]
-                out[i] = jax.lax.slice(full, (off,), (off + n,)) \
-                    .reshape(shape).astype(ref.dtype)
+                with jax.named_scope(scopes.UNPACK):
+                    out[i] = jax.lax.slice(full, (off,), (off + n,)) \
+                        .reshape(shape).astype(ref.dtype)
                 off += n
         return jax.tree.unflatten(treedef, out), new_state
 

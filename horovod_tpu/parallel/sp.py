@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..utils import scopes
+
 NEG_INF = -1e30
 
 
@@ -128,7 +130,8 @@ def ring_attention(q, k, v, axis_name: str = "sp", use_flash=None,
                         _varying(jnp.zeros((B, sq), jnp.float32), axis)),
         ], (kf, vf))
 
-    return _ring_scan(q, k, v, axis_name, round_stats)
+    with jax.named_scope(scopes.ATTENTION):
+        return _ring_scan(q, k, v, axis_name, round_stats)
 
 
 def striped_ring_attention(q, k, v, axis_name: str = "sp", use_flash=None,
@@ -171,7 +174,8 @@ def striped_ring_attention(q, k, v, axis_name: str = "sp", use_flash=None,
              lambda kv: stats(qf, kv[0], kv[1], 1)],
             (kf, vf))
 
-    return _ring_scan(q, k, v, axis_name, round_stats)
+    with jax.named_scope(scopes.ATTENTION):
+        return _ring_scan(q, k, v, axis_name, round_stats)
 
 
 def stripe_tokens(x, n: int, axis: int = 1):

@@ -1,0 +1,189 @@
+"""The names the compiled step gives its work, and how to read them back.
+
+A ``jax.named_scope`` costs nothing when a step runs: it is metadata
+(``op_name``) on the instructions of the compiled module. A TPU trace's
+``XLA Ops`` events are named by HLO text without that metadata, but the
+text's first token is the instruction's name, and the compiled module's
+text maps that name to its ``op_name``: `instruction_scopes` builds the
+map (``parallel.dp.scope_table()`` does it for a step it compiled),
+`seconds_by_phase`/`seconds_by_part` join it to any profile's op events.
+
+Scopes the program sets (the only place their names are written):
+
+- ``hvd.step``                  the per-chip body of ``data_parallel_step``
+- ``hvd.grad_exchange/pack``    gradients raveled into one flat buffer
+- ``hvd.grad_exchange/reduce``  the collective(s) over the data axis
+- ``hvd.grad_exchange/unpack``  slices of the buffer back into leaves
+- ``hvd.optimizer``             the inner optax update
+- ``hvd.model/embed|attention|mlp|head``  ``models/transformer.py``
+
+Forward, backward and recompute need no scope: JAX marks them itself
+(``jvp(``, ``transpose(``, ``rematted_computation``).
+
+Counters (`StepRecord.counters`, noted once while a step is traced, so
+per step and per chip): ``collectives`` the exchange issued,
+``collective_bytes`` handed to them, ``packed_bytes`` copied into flat
+buffers, ``axis_size``.
+"""
+
+from __future__ import annotations
+
+import collections
+import contextlib
+import contextvars
+import dataclasses
+import re
+from typing import Callable, Optional
+
+import jax
+
+STEP = "hvd.step"
+GRAD_EXCHANGE = "hvd.grad_exchange"
+PACK = GRAD_EXCHANGE + "/pack"
+REDUCE = GRAD_EXCHANGE + "/reduce"
+UNPACK = GRAD_EXCHANGE + "/unpack"
+OPTIMIZER = "hvd.optimizer"
+MODEL = "hvd.model"
+EMBED = MODEL + "/embed"
+ATTENTION = MODEL + "/attention"
+MLP = MODEL + "/mlp"
+HEAD = MODEL + "/head"
+
+#: in `phase_of`'s order of precedence
+PHASES = ("grad_exchange", "optimizer", "recompute", "backward", "forward",
+          "other")
+_PHASE_MARKS = ((GRAD_EXCHANGE, "grad_exchange"), (OPTIMIZER, "optimizer"),
+                ("rematted_computation", "recompute"),
+                ("transpose(", "backward"), ("jvp(", "forward"))
+_PARTS = (re.compile(re.escape(GRAD_EXCHANGE) + r"/\w+"),
+          re.compile(re.escape(MODEL) + r"/\w+"))
+_INSTRUCTION = re.compile(r"\s*(?:ROOT\s+)?%?([^\s=]+) = ")
+_OP_NAME = re.compile(r'\bop_name="((?:[^"\\]|\\.)*)"')
+_COMPUTATION = re.compile(r"(?:ENTRY\s+)?%?([^\s(]+) \(.*\{\s*$")
+_CALLS = re.compile(r"\bcalls=%?([^\s,}]+)")
+
+
+def phase_of(op_name: Optional[str]) -> str:
+    """The phase of a training step an instruction belongs to. XLA joins
+    the names of instructions it merges with ``;``: the precedence holds
+    over all of them."""
+    for mark, phase in _PHASE_MARKS:
+        if op_name and mark in op_name:
+            return phase
+    return "other"
+
+
+def part_of(op_name: Optional[str]) -> Optional[str]:
+    """``hvd.grad_exchange/<child>`` or ``hvd.model/<part>``, whichever
+    the name carries (the exchange first), else None."""
+    for part in _PARTS:
+        m = part.search(op_name or "")
+        if m:
+            return m.group(0)
+    return None
+
+
+def instruction_scopes(hlo_text: str) -> dict:
+    """``{instruction name: op_name}`` over every computation of a
+    compiled module's text (``compiled.as_text()``); an instruction
+    without metadata maps to ``""``.
+
+    A fusion's own ``op_name`` is its root's, which is right where the
+    root is the convolution or matmul that takes the time. Where it
+    reads ``other`` (on the v5e: AdamW's update fused under
+    ``optax.apply_updates``' unscoped add; PERF.md, PR 25) the fusion
+    takes the names inside the computation it calls instead, those of
+    the phase most of them have."""
+    table, inside, fusions, computation = {}, {}, {}, None
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            header = _COMPUTATION.match(line)
+            if header:
+                computation = header.group(1)
+            continue
+        op = _OP_NAME.search(line, m.end())
+        table[m.group(1)] = op.group(1) if op else ""
+        if op:
+            inside.setdefault(computation, []).append(op.group(1))
+        called = _CALLS.search(line, m.end())
+        if called and phase_of(table[m.group(1)]) == "other":
+            fusions[m.group(1)] = called.group(1)
+    for name, called in fusions.items():
+        names = inside.get(called, ())
+        votes = collections.Counter(phase_of(n) for n in names)
+        if votes:
+            winner = max(PHASES, key=lambda p: votes[p])  # ties: precedence
+            table[name] = ";".join(dict.fromkeys(
+                n for n in names if phase_of(n) == winner))
+    return table
+
+
+def _seconds_by(key_of: Callable, instructions: dict, table: dict):
+    seconds, found, total = {}, 0.0, 0.0
+    for hlo_text, seen in instructions.items():
+        name = hlo_text.split(" ", 1)[0].lstrip("%")
+        total += seen["seconds"]
+        if name in table:
+            found += seen["seconds"]
+        key = key_of(table.get(name))
+        if key is not None:
+            seconds[key] = seconds.get(key, 0.0) + seen["seconds"]
+    return seconds, (found / total if total else 0.0)
+
+
+def seconds_by_phase(instructions: dict, table: dict):
+    """``({phase: seconds}, found)`` for a profile's op events as
+    ``{hlo text: {"count", "seconds"}}`` (chipbench's ``instructions``;
+    the instruction's name is the text's first token). The phases
+    partition the seconds: an instruction the table lacks is ``other``.
+    ``found`` is the share of the seconds whose instruction the table
+    has; where it is low the module is not the one that was profiled."""
+    return _seconds_by(phase_of, instructions, table)
+
+
+def seconds_by_part(instructions: dict, table: dict):
+    """As `seconds_by_phase`, by `part_of`, over all phases; seconds
+    outside every part are left out."""
+    return _seconds_by(part_of, instructions, table)
+
+
+# -- what a traced step notes about itself ---------------------------------
+
+@dataclasses.dataclass
+class StepRecord:
+    """One ``data_parallel_step``'s abstract signature (what
+    ``scope_table`` lowers), its counters and its cached table."""
+
+    signature: Optional[tuple] = None
+    counters: dict = dataclasses.field(default_factory=dict)
+    table: Optional[dict] = None
+
+
+_tracing: contextvars.ContextVar = contextvars.ContextVar(
+    "hvd_step_record", default=None)
+
+
+@contextlib.contextmanager
+def recording(record: StepRecord):
+    """While a step is traced: `note_exchange` notes into ``record``."""
+    token = _tracing.set(record)
+    try:
+        yield record
+    finally:
+        _tracing.reset(token)
+
+
+def note_exchange(buffers, axis_name: str, packed: bool = False) -> None:
+    """Called where a gradient exchange is built, with the arrays handed
+    to collectives over ``axis_name`` (one collective each). A no-op
+    outside a traced ``data_parallel_step``."""
+    record = _tracing.get()
+    if record is None:
+        return
+    nbytes = sum(b.size * b.dtype.itemsize for b in buffers)
+    c = record.counters
+    c["collectives"] = c.get("collectives", 0) + len(buffers)
+    c["collective_bytes"] = c.get("collective_bytes", 0) + nbytes
+    c["packed_bytes"] = c.get("packed_bytes", 0) + (nbytes if packed else 0)
+    c["axis_size"] = int(jax.lax.axis_size(axis_name))

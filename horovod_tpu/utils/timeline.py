@@ -11,15 +11,15 @@ Here: a daemon writer thread fed by the native C++ SPSC ring
 boost::lockfree::spsc_queue) with a ``queue.SimpleQueue`` fallback when
 the native core isn't built; same JSON schema, so the output opens in
 ``chrome://tracing`` / Perfetto exactly like the reference's. Device-side
-timing on TPU comes from ``jax.profiler`` traces instead of CUDA events —
-`start_jax_profiler`/`stop_jax_profiler` bridge to XPlane dumps.
+timing on TPU comes from ``jax.profiler`` traces instead of CUDA events:
+the compiled step names its work for them (``utils/scopes.py``,
+docs/timeline.md "Profiling the compiled step").
 """
 
 from __future__ import annotations
 
 import ctypes
 import json
-import os
 import queue
 import threading
 import time
@@ -193,19 +193,3 @@ class Timeline:
             if self._file:
                 self._file.write(json.dumps(rec) + ",\n")
                 self._file.flush()
-
-
-def start_jax_profiler(logdir: str):
-    """Device-side profiling bridge: XPlane/perfetto dump via jax.profiler
-    (the TPU-native replacement for the reference's CUDA-event activity
-    timings, gpu_operations.h:110-119)."""
-    import jax
-
-    os.makedirs(logdir, exist_ok=True)
-    jax.profiler.start_trace(logdir)
-
-
-def stop_jax_profiler():
-    import jax
-
-    jax.profiler.stop_trace()

@@ -1,0 +1,234 @@
+"""utils/scopes.py and what ``parallel/dp.py`` remembers of a step it
+built: the scope vocabulary against the literal ``op_name`` forms the
+compiler writes, and two toy steps (a remat'd decoder, a small ResNet)
+through ``DistributedOptimizer`` + ``data_parallel_step`` on the CPU."""
+
+import collections
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+from jax import monitoring
+
+import horovod_tpu as hvd
+from horovod_tpu.models import transformer as T
+from horovod_tpu.models.resnet import ResNet
+from horovod_tpu.parallel import data_parallel_step, dp
+from horovod_tpu.utils import scopes
+
+PRE = "jit(hvd_data_parallel_step)/shard_map/hvd.step/"
+
+
+@pytest.mark.parametrize("op_name,phase,part", [
+    (PRE + "jvp(hvd.model/mlp)/dot_general", "forward", "hvd.model/mlp"),
+    (PRE + "transpose(jvp(hvd.model/head))/mul", "backward",
+     "hvd.model/head"),
+    (PRE + "transpose(jvp(hvd.step))/jvp()/checkpoint/hvd.model/attention/"
+     "dot_general", "backward", "hvd.model/attention"),
+    (PRE + "transpose(jvp(hvd.step))/jvp()/checkpoint/rematted_computation/"
+     "hvd.model/attention/dot_general", "recompute", "hvd.model/attention"),
+    (PRE + "hvd.optimizer/sub", "optimizer", None),
+    (PRE + "hvd.grad_exchange/pack/concatenate", "grad_exchange",
+     "hvd.grad_exchange/pack"),
+    (PRE + "hvd.grad_exchange/reduce/psum", "grad_exchange",
+     "hvd.grad_exchange/reduce"),
+    (PRE + "hvd.grad_exchange/unpack/slice", "grad_exchange",
+     "hvd.grad_exchange/unpack"),
+    # XLA joins merged instructions' names: the precedence is over all
+    (PRE + "transpose(jvp(hvd.model/mlp))/mul;" + PRE
+     + "hvd.grad_exchange/pack/concatenate", "grad_exchange",
+     "hvd.grad_exchange/pack"),
+    (PRE + "transpose(jvp(hvd.model/mlp))/mul;" + PRE
+     + "transpose(jvp(hvd.model/mlp))/broadcast_in_dim", "backward",
+     "hvd.model/mlp"),
+    ("jit(hvd_data_parallel_step)/shard_map/hvd.step/psum", "other", None),
+    ("args[0]['embed']", "other", None),
+    ("", "other", None),
+    (None, "other", None),
+])
+def test_phase_and_part_of_literal_op_names(op_name, phase, part):
+    assert scopes.phase_of(op_name) == phase
+    assert scopes.part_of(op_name) == part
+    assert phase in scopes.PHASES
+
+
+def test_instruction_scopes_and_seconds_by_phase():
+    def named(op):
+        return 'metadata={op_name="' + PRE + op + '" stack_frame_id=7}'
+
+    text = "\n".join([
+        "HloModule jit_hvd_data_parallel_step, entry_computation_layout={}",
+        "%fused_computation.1 (p: f32[4]) -> f32[4] {",
+        "  %p = f32[4]{0} parameter(0)",
+        "  %slice.1 = f32[4]{0} slice(%p), "
+        + named("hvd.grad_exchange/unpack/slice"),
+        "  %mul.3 = f32[4]{0} multiply(%p, %slice.1), "
+        + named("hvd.optimizer/mul"),
+        "  %sub.4 = f32[4]{0} subtract(%p, %mul.3), "
+        + named("hvd.optimizer/sub"),
+        "  ROOT %add.5 = f32[4]{0} add(%p, %sub.4), " + named("add"),
+        "}",
+        "%fused_computation.2 (q: f32[4]) -> f32[4] {",
+        "  %q = f32[4]{0} parameter(0)",
+        "  ROOT %dynamic-update-slice.6 = f32[4]{0} "
+        "dynamic-update-slice(%q, %q), backend_config={}",
+        "}",
+        "ENTRY %main.9 (a: f32[4]) -> f32[4] {",
+        '  %a = f32[4]{0} parameter(0), metadata={op_name="args[0]"}',
+        # its root is the user's add: named by what is inside, the phase
+        # most of the names have (not by `phase_of`'s precedence)
+        "  %fusion.1 = f32[4]{0} fusion(%a), kind=kLoop, "
+        "calls=%fused_computation.1, " + named("add"),
+        # no name on it, none inside: stays unnamed
+        "  %update_fusion.4 = f32[4]{0} fusion(%a), kind=kLoop, "
+        "calls=%fused_computation.2, backend_config={}",
+        "  %copy.5 = f32[4]{0} copy(%update_fusion.4)",
+        # a root with a phase keeps it, whatever is fused in
+        "  %fusion.7 = f32[4]{0} fusion(%a), kind=kOutput, "
+        "calls=%fused_computation.1, "
+        + named("transpose(jvp(hvd.model/mlp))/dot_general"),
+        "  ROOT %dot.2 = f32[4]{0} dot(%fusion.1, %a), "
+        + named("jvp(hvd.model/mlp)/dot_general") + ', source_file="x.py"',
+        "}"])
+    table = scopes.instruction_scopes(text)
+    assert {k: v for k, v in table.items() if "." in k or k == "a"} == {
+        "slice.1": PRE + "hvd.grad_exchange/unpack/slice",
+        "mul.3": PRE + "hvd.optimizer/mul", "sub.4": PRE + "hvd.optimizer/sub",
+        "add.5": PRE + "add", "dynamic-update-slice.6": "", "a": "args[0]",
+        "fusion.1": PRE + "hvd.optimizer/mul;" + PRE + "hvd.optimizer/sub",
+        "update_fusion.4": "", "copy.5": "",
+        "fusion.7": PRE + "transpose(jvp(hvd.model/mlp))/dot_general",
+        "dot.2": PRE + "jvp(hvd.model/mlp)/dot_general"}
+    instructions = {
+        "%fusion.1 = f32[4]{0} fusion(%a), kind=kLoop":
+            {"count": 2, "seconds": 0.5},
+        "%dot.2 = f32[4]{0} dot(%fusion.1, %a)": {"count": 2, "seconds": 1.0},
+        "%copy.8 = f32[4]{0} copy(%a)": {"count": 2, "seconds": 0.5},
+    }
+    by_phase, found = scopes.seconds_by_phase(instructions, table)
+    assert by_phase == {"optimizer": 0.5, "forward": 1.0, "other": 0.5}
+    assert found == 0.75  # %copy.8 is not in the table
+    by_part, found = scopes.seconds_by_part(instructions, table)
+    assert by_part == {"hvd.model/mlp": 1.0} and found == 0.75
+
+
+@pytest.fixture(scope="module")
+def compiles():
+    """Every trace and compile request JAX makes from here on, through
+    ``jax.monitoring`` (a listener cannot be taken off again: one for
+    the module)."""
+    heard = []
+
+    def listen(event, duration, **kw):
+        if event.endswith(("jaxpr_trace_duration",
+                           "backend_compile_duration")):
+            heard.append(event)
+
+    monitoring.register_event_duration_secs_listener(listen)
+    return heard
+
+
+def _lm_step(mesh):
+    cfg = T.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
+                              n_layers=2, d_ff=64, max_seq=16, remat=True)
+    opt = hvd.DistributedOptimizer(optax.adamw(1e-3))
+
+    def step(params, opt_state, tokens):
+        loss, grads = jax.value_and_grad(T.lm_loss)(
+            params, tokens, cfg, use_constraints=False)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return (optax.apply_updates(params, updates), opt_state,
+                jax.lax.pmean(loss, "hvd"))
+
+    params = T.init(jax.random.PRNGKey(0), cfg)
+    return (data_parallel_step(step, mesh=mesh), params,
+            (params, opt.init(params), jnp.zeros((8, 17), jnp.int32)),
+            {"forward", "backward", "recompute", "optimizer",
+             "grad_exchange"})
+
+
+def _resnet_step(mesh):
+    model = ResNet(stage_sizes=[1, 1], num_filters=8, num_classes=10)
+    opt = hvd.DistributedOptimizer(optax.sgd(0.01, momentum=0.9))
+
+    def step(state, opt_state, images, labels):
+        params, stats = state
+
+        def loss_fn(p):
+            logits, upd = model.apply({"params": p, "batch_stats": stats},
+                                      images, train=True,
+                                      mutable=["batch_stats"])
+            onehot = jax.nn.one_hot(labels, 10)
+            return (-jnp.mean(jnp.sum(jax.nn.log_softmax(logits) * onehot,
+                                      -1)), upd["batch_stats"])
+
+        (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            params)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return ((optax.apply_updates(params, updates), stats), opt_state,
+                jax.lax.pmean(loss, "hvd"))
+
+    images = jnp.zeros((8, 32, 32, 3), jnp.bfloat16)
+    variables = model.init(jax.random.PRNGKey(0), images[:2], train=True)
+    params = variables["params"]
+    return (data_parallel_step(step, mesh=mesh, batch_argnums=(2, 3)),
+            params,
+            ((params, variables["batch_stats"]), opt.init(params), images,
+             jnp.zeros((8,), jnp.int32)),
+            {"forward", "backward", "optimizer", "grad_exchange"})
+
+
+@pytest.mark.parametrize("build", [_lm_step, _resnet_step],
+                         ids=["dense_lm", "resnet"])
+def test_a_traced_step_describes_itself(build, compiles):
+    mesh = hvd.global_process_set().mesh
+    step, params, args, phases = build(mesh)
+    assert dp.scope_table(step) is None and dp.step_counters(step) is None
+
+    step.lower(*args)
+    table = dp.scope_table(step)
+    assert dp.scope_table() is table  # the step traced last is this one
+
+    # every phase the model has is there; the phases partition the table
+    count = collections.Counter(scopes.phase_of(v) for v in table.values())
+    assert phases <= {p for p, n in count.items() if n}
+    assert ("recompute" in count) == ("recompute" in phases)
+    assert sum(count.values()) == len(table) and set(count) <= set(
+        scopes.PHASES)
+    parts = {scopes.part_of(v) for v in table.values()}
+    assert {scopes.PACK, scopes.REDUCE, scopes.UNPACK} <= parts
+    if build is _lm_step:
+        assert {scopes.EMBED, scopes.ATTENTION, scopes.MLP,
+                scopes.HEAD} <= parts
+
+    # one fused float32 buffer, one collective, by hand from the tree
+    nbytes = sum(p.size * 4 for p in jax.tree.leaves(params))
+    assert dp.step_counters(step) == {
+        "collectives": 1, "collective_bytes": nbytes,
+        "packed_bytes": nbytes, "axis_size": mesh.devices.size}
+
+    before = len(compiles)
+    assert dp.scope_table(step) is table
+    assert len(compiles) == before  # the second call compiles nothing
+    assert before > 0               # and the listener does hear compiles
+
+
+def test_unfused_exchange_counts_a_collective_per_leaf():
+    mesh = hvd.global_process_set().mesh
+    opt = hvd.DistributedOptimizer(optax.sgd(0.1), fuse_buckets=False)
+    params = {"a": jnp.ones((3, 5)), "b": jnp.ones((7,))}
+
+    def step(params, opt_state, x):
+        grads = jax.grad(lambda p: jnp.sum(p["a"]) * jnp.sum(x)
+                         + jnp.sum(p["b"]))(params)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, jnp.sum(x)
+
+    step = data_parallel_step(step, mesh=mesh)
+    step.lower(params, opt.init(params), jnp.ones((8, 2)))
+    assert dp.step_counters(step) == {
+        "collectives": 2, "collective_bytes": (15 + 7) * 4,
+        "packed_bytes": 0, "axis_size": mesh.devices.size}
+    phases = {scopes.phase_of(v) for v in dp.scope_table(step).values()}
+    assert {"grad_exchange", "optimizer"} <= phases

@@ -70,6 +70,7 @@ def test_flash_fwd_compiles(topo, s, causal, offset):
                               block_k=BLOCK,
                               causal_offset=offset).compile().as_text()
     assert "tpu_custom_call" in text
+    assert "hvd_flash_fwd" in text  # the kernel's stable name in a trace
 
 
 @pytest.mark.parametrize("offset", [0, 1])
@@ -131,3 +132,51 @@ def test_fused_chunk_plan_compiles_for_64mib_over_four_processes(topo):
     compiled = plan.run.lower(g).compile()
     assert "all-reduce" in compiled.as_text()
     assert [o.shape for o in compiled.out_info] == [(n,), (2, n // 2)]
+
+
+def test_toy_lm_step_carries_every_phase_on_four_chips(topo):
+    """A remat'd decoder step through ``DistributedOptimizer`` +
+    ``data_parallel_step`` as the chip's compiler leaves it: the scopes
+    of utils/scopes.py survive into the entry computation's fusions (the
+    instructions a trace's ``XLA Ops`` events are named by), and an
+    instruction's name identifies it within the module."""
+    import optax
+
+    import horovod_tpu as hvd
+    from horovod_tpu.models import transformer as T
+    from horovod_tpu.parallel import data_parallel_step, dp
+    from horovod_tpu.utils import scopes
+
+    mesh = Mesh(np.array(topo.devices), ("hvd",))
+    cfg = T.TransformerConfig(vocab_size=512, d_model=256, n_heads=2,
+                              n_layers=2, d_ff=512, max_seq=128, remat=True)
+    opt = hvd.DistributedOptimizer(optax.adamw(1e-3))
+
+    def step(params, opt_state, tokens):
+        loss, grads = jax.value_and_grad(T.lm_loss)(
+            params, tokens, cfg, use_constraints=False)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return (optax.apply_updates(params, updates), opt_state,
+                jax.lax.pmean(loss, "hvd"))
+
+    def placed(tree, spec):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, spec)), tree)
+
+    params = jax.eval_shape(lambda: T.init(jax.random.PRNGKey(0), cfg))
+    step = data_parallel_step(step, mesh=mesh)
+    text = step.lower(
+        placed(params, P()), placed(jax.eval_shape(opt.init, params), P()),
+        placed(jax.ShapeDtypeStruct((8, 129), jnp.int32), P("hvd"))
+    ).compile().as_text()
+    # what the step remembered of its arguments lowers to the same module
+    assert dp.scope_table(step) == scopes.instruction_scopes(text)
+
+    instruction_lines = [l for l in text.splitlines() if " = " in l
+                         and l.startswith(" ")]
+    assert len(instruction_lines) == len(dp.scope_table(step))
+    entry = scopes.instruction_scopes(text[text.index("\nENTRY "):])
+    phases = {scopes.phase_of(dp.scope_table(step)[name]) for name in entry
+              if "fusion" in name}
+    assert phases >= {"forward", "backward", "recompute", "optimizer",
+                      "grad_exchange"}
