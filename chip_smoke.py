@@ -19,7 +19,9 @@ entry points a user calls, and checks what comes out:
 3. kernel   — ``flash_attention`` and ``attention_stats``, forward and
    ``jax.grad``, at B=128, s in {1024, 2048}, d=128, bf16, against
    ``_reference_attention`` in float32, with ``tpu_custom_call`` in the
-   lowered text (neither interpret mode nor ``scan_stats`` answered).
+   lowered text (neither interpret mode nor ``scan_stats`` answered) and
+   the three kernels by name (``hvd_flash_fwd``; ``hvd_flash_bwd_dq`` and
+   ``hvd_flash_bwd_dkv`` behind ``jax.grad(flash_attention)``).
 
 ``--chips 4`` runs only what exists across chips, each in its own child,
 one after the other, from a parent that never initialises a JAX backend
@@ -41,6 +43,7 @@ import dataclasses
 import functools
 import json
 import os
+import re
 import signal
 import subprocess
 import sys
@@ -436,14 +439,20 @@ def phase_kernel(sz: Sizes, seed: int, on_chip: bool) -> None:
             for arg, g, w in zip("qkv", fn(q, k, v), want):
                 errs[f"grad {name} d{arg}"] = close(
                     f"grad {name} s={s} d{arg}", g, w, tol)
+        kernels = sorted({name for t in lowered
+                          for name in re.findall(r"hvd_flash_\w+", t)})
         if on_chip:
             check(all("tpu_custom_call" in t for t in lowered),
                   "no tpu_custom_call in the lowered text: interpret mode "
                   "or scan_stats answered for the kernel")
+            # jax.grad(flash_attention) is the two backward kernels
+            check(kernels == ["hvd_flash_bwd_dkv", "hvd_flash_bwd_dq",
+                              "hvd_flash_fwd"],
+                  f"the lowered programs name the kernels {kernels}")
         say(f"kernel: B={sz.attn_batch} s={s} d={sz.attn_dim} bf16 block "
             f"{blk} | {len(lowered)} programs"
-            + (", tpu_custom_call in each" if on_chip else
-               " (interpret mode off the chip)")
+            + (f", tpu_custom_call in each ({', '.join(kernels)})"
+               if on_chip else " (interpret mode off the chip)")
             + f" | largest error {max(errs.values()):.2e} "
             f"({max(errs, key=errs.get)}) of the reference's scale")
 

@@ -22,6 +22,7 @@ MODULES = [
     ("horovod_tpu.utils.checkpoint", "Checkpoints"),
     ("horovod_tpu.utils.timeline", "Timeline/profiling"),
     ("horovod_tpu.models", "Model zoo"),
+    ("horovod_tpu.models.transformer", "Dense decoder"),
     ("horovod_tpu.ops.pallas.flash_attention", "Pallas kernels"),
 ]
 

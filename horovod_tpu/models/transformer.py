@@ -130,8 +130,10 @@ def apply(params, tokens, cfg: TransformerConfig, *, use_constraints: bool = Tru
 
     ``attn_fn(q, k, v)`` hook (q/k/v: [b, s, h, hd]) lets
     `horovod_tpu.parallel.sp` substitute ring attention or Ulysses
-    attention; default is full causal attention (XLA reshards over 'sp'
-    automatically under GSPMD).
+    attention; default is full causal attention: the fused Pallas
+    kernels where `fused_attention_blocks` picks them (a TPU, a call XLA
+    need not partition, a long enough sequence that tiles), else
+    `causal_attention` (XLA reshards over 'sp' automatically under GSPMD).
 
     ``positions`` ([s] global position ids) must be supplied when running
     inside a shard_map with the sequence sharded (ring attention): each
@@ -145,18 +147,24 @@ def apply(params, tokens, cfg: TransformerConfig, *, use_constraints: bool = Tru
         x = x + params["pos"][positions].astype(cfg.dtype)[None]
     x = _constrain(x, aspec, use_constraints)
 
+    # the fused kernels' tile shape, where the default attention takes them
+    blocks = None if attn_fn else fused_attention_blocks(
+        tokens.shape[1], cfg.head_dim, use_constraints)
+
     # the scopes sit inside the block, so they survive jax.checkpoint
     def _block(x, blk):
         with jax.named_scope(scopes.ATTENTION):
             h = _rmsnorm(x, blk["ln1"]["scale"])
-            q = jnp.einsum("bsd,dhk->bshk", h, blk["wq"].astype(cfg.dtype))
-            k = jnp.einsum("bsd,dhk->bshk", h, blk["wk"].astype(cfg.dtype))
-            v = jnp.einsum("bsd,dhk->bshk", h, blk["wv"].astype(cfg.dtype))
             if attn_fn is None:
-                o = causal_attention(q, k, v)
+                scopes.note_attention(kernel=blocks is not None)
+            if blocks is not None:
+                o = _fused_attention(h, blk, cfg, blocks)
             else:
-                o = attn_fn(q, k, v)
-            o = jnp.einsum("bshk,hkd->bsd", o, blk["wo"].astype(cfg.dtype))
+                q, k, v = (jnp.einsum("bsd,dhk->bshk", h,
+                                      blk[w].astype(cfg.dtype))
+                           for w in ("wq", "wk", "wv"))
+                o = (attn_fn or causal_attention)(q, k, v)
+                o = jnp.einsum("bshk,hkd->bsd", o, blk["wo"].astype(cfg.dtype))
             x = x + o
         x = _constrain(x, aspec, use_constraints)
         with jax.named_scope(scopes.MLP):
@@ -178,8 +186,72 @@ def apply(params, tokens, cfg: TransformerConfig, *, use_constraints: bool = Tru
                           params["embed"])
 
 
+#: the shortest sequence at which the fused kernels beat the einsum path
+#: on the chip (PERF.md, PR 26: the v5e measurements that set it)
+FUSED_ATTENTION_MIN_SEQ = 512
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _partitioned_by_xla() -> bool:
+    """Would XLA have to partition a kernel called here? It cannot (a
+    Mosaic call lowers only where every mesh axis is manual, or for one
+    device). Inside a ``shard_map`` over every axis of its mesh, as
+    ``data_parallel_step`` runs its per-chip body, the answer is no;
+    under a bare ``jit`` the trace does not say what the arguments are
+    sharded over (``fsdp_train_step``: GSPMD, no constraints), so only
+    a process with one device is sure."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return jax.device_count() > 1
+    return not mesh.are_all_axes_manual
+
+
+def fused_attention_blocks(s: int, head_dim: int, use_constraints: bool):
+    """The fused kernels' ``(block_q, block_k)`` where the default
+    attention takes them, else None (`causal_attention`). From what the
+    call can see and nothing else: a TPU, nothing for XLA to partition
+    (no GSPMD constraints asked for, and `_partitioned_by_xla` says
+    no), a sequence long enough for the kernels to win, and one they
+    tile."""
+    if (use_constraints or not _on_tpu() or s < FUSED_ATTENTION_MIN_SEQ
+            or _partitioned_by_xla()):
+        return None
+    from ..ops.pallas.flash_attention import block_sizes
+
+    return block_sizes(s, head_dim)
+
+
+def _fused_attention(h, blk, cfg: TransformerConfig, blocks):
+    """`causal_attention` between its projections, through the Pallas
+    kernels (ops/pallas/flash_attention.py: the scores stay in VMEM,
+    forward and backward). The kernels take the heads side by side,
+    [b, s, h*hd], and pick a head by block index, so no transpose of q,
+    k, v or o is paid. The projections are plain [d, h*hd] matmuls
+    here, and here only: the TPU compiler lays a ``dhk`` dot's output
+    out sequence-minor and then copies it for the kernels, while on the
+    einsum path the plain form costs a copy of every converted weight
+    instead (both seen in the step compiled for a v5e; PERF.md, PR 26)."""
+    from ..ops.pallas.flash_attention import flash_attention
+
+    q, k, v = (
+        jnp.einsum("bsd,de->bse", h,
+                   blk[w].astype(cfg.dtype).reshape(cfg.d_model, -1))
+        for w in ("wq", "wk", "wv"))
+    o = flash_attention(q, k, v, True, *blocks, blk["wq"].shape[1])
+    return jnp.einsum("bse,ed->bsd", o,
+                      blk["wo"].astype(cfg.dtype).reshape(-1, cfg.d_model))
+
+
 def causal_attention(q, k, v):
-    """Plain causal attention, [b, s, h, hd] layout, f32 softmax."""
+    """Plain causal attention, [b, s, h, hd] layout, f32 softmax: an
+    einsum writes the [b, h, s, s] float32 logits and XLA's softmax
+    passes over them. What ``apply`` runs wherever
+    `fused_attention_blocks` says None (short or untileable sequences,
+    off the TPU, under GSPMD), and the numerics the fused kernels are
+    held to."""
     scale = q.shape[-1] ** -0.5
     logits = jnp.einsum("bshk,bthk->bhst", q, k).astype(jnp.float32) * scale
     s, t = logits.shape[-2], logits.shape[-1]

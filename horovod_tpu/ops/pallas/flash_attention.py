@@ -1,105 +1,236 @@
-"""Fused block (flash) attention — the Pallas TPU kernel behind
-`horovod_tpu.parallel.sp.ring_attention`'s inner step (SURVEY.md §5.7
-"pallas splash-attention kernels"; greenfield — the reference has no
-attention kernels at all).
+"""Fused block (flash) attention — the Pallas TPU kernels behind the dense
+decoder's attention (``models/transformer.py`` picks them from the
+sequence length) and `horovod_tpu.parallel.sp.ring_attention`'s inner
+step (SURVEY.md §5.7 "pallas splash-attention kernels"; greenfield — the
+reference has no attention kernels at all).
 
-Forward is a single Pallas kernel: for each Q block the K/V blocks stream
-through VMEM while an online softmax (running max ``m``, running sum ``l``,
-rescaled accumulator) lives in VMEM scratch — logits never round-trip to
-HBM, which is the whole point on a bandwidth-bound chip. The kernel also
-returns ``(m, l)`` so ring attention can combine partial results from
-other chips' K/V shards exactly.
+Forward is a single Pallas kernel (``hvd_flash_fwd``): for each Q block
+the K/V blocks stream through VMEM while an online softmax (running max
+``m``, running sum ``l``, rescaled accumulator) lives in VMEM scratch —
+logits never round-trip to HBM, which is the whole point on a
+bandwidth-bound chip. The kernel also returns ``(m, l)`` so ring
+attention can combine partial results from other chips' K/V shards
+exactly.
 
-Backward is a rematerialized BLOCKWISE VJP: autodiff through
-``scan_stats`` — a ``lax.scan`` over K/V blocks with a checkpointed
-body — so both directions hold one [B, sq, block_k] score block, never
-the full matrix. Only q/k/v are residuals. A fused backward kernel is
-a later optimization.
+Backward has two paths, by what the caller differentiates:
 
-On non-TPU backends the kernel runs in Pallas interpret mode (tests on the
-virtual CPU mesh), so one code path serves everywhere.
+- ``flash_attention`` (cotangent of ``o`` alone; the decoder's path):
+  two Pallas kernels. Residuals are ``(q, k, v, o, lse)`` with ``lse = m
+  + log l`` from the forward's own outputs. ``hvd_flash_bwd_dq`` walks
+  Q blocks and accumulates ``dq`` over the K/V blocks; it holds ``do``
+  and ``o`` of its block, so it also makes ``delta = rowsum(do * o)``
+  and hands it on. ``hvd_flash_bwd_dkv`` walks K/V blocks and
+  accumulates ``dk``, ``dv`` over the Q blocks. Each recomputes ``p =
+  exp(s - lse)`` per tile in float32, so the score matrix goes to HBM
+  in neither direction.
+- ``attention_stats`` (cotangents of ``o``, ``m`` and ``l``; ring
+  attention): a rematerialized BLOCKWISE VJP in XLA, autodiff through
+  ``scan_stats`` — a ``lax.scan`` over K/V blocks with a checkpointed
+  body — which holds one [B, sq, block_k] score block. Only q/k/v are
+  residuals. ``scan_stats`` is also the kernels' oracle in tests.
+
+All three kernels skip the tiles the causal mask empties (no compute,
+and the block index is held so no DMA either) and mask only the tiles
+the diagonal crosses. `block_sizes` is the one place the tile shape is
+chosen, from ``(s, head_dim)``.
+
+On non-TPU backends the kernels run in Pallas interpret mode (tests on
+the virtual CPU mesh), so one code path serves everywhere.
+
+What the kernels cost a job's start: importing this module imports
+JAX's Pallas (about a second), so it is imported only where a kernel is
+taken; each kernel's call sits under ``jax.jit`` with static block
+sizes, so a program traces and lowers it once however many layers,
+recomputations and directions call it.
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import sys
 
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from ...utils import scopes
+
+#: what ``jax._src.pallas.pallas_call`` imports for ``interpret=`` of GPU
+#: kernels, inside a ``try``/``except ImportError`` of its own
+_GPU_INTERPRETER = "jax._src.pallas.mosaic_gpu.interpret.interpret_pallas_call"
+
+
+def _on_a_started_tpu() -> bool:
+    """Is JAX's backend up, and a TPU? Asked without starting one: an
+    import must not (a job may yet have to call
+    ``jax.distributed.initialize``). The decoder imports this module at
+    its first trace, when the backend is long up."""
+    from jax._src import xla_bridge
+
+    started = getattr(xla_bridge, "backends_are_initialized", None)
+    return bool(started and started()) and jax.default_backend() == "tpu"
+
+
+@contextlib.contextmanager
+def _without_gpu_interpreter():
+    """Import Pallas as an installation without its GPU interpreter
+    does. ``import jax.experimental.pallas`` spends two thirds of its
+    second on that interpreter and the Mosaic GPU dialects behind it,
+    and a job pays it at its first trace; ``pallas_call`` treats the
+    module as optional, so on a TPU, which never interprets a GPU
+    kernel, it is left out (PERF.md, PR 27: 0.7 s of ``setup_s``). A
+    None in ``sys.modules`` is Python's own way to say "not here"; it is
+    taken away again, so a later import of that module works."""
+    blocked = _on_a_started_tpu() and _GPU_INTERPRETER not in sys.modules
+    if blocked:
+        sys.modules[_GPU_INTERPRETER] = None
+    try:
+        yield
+    finally:
+        if blocked:
+            del sys.modules[_GPU_INTERPRETER]
+
+
+try:
+    with _without_gpu_interpreter():
+        from jax.experimental import pallas as pl
+except ImportError:  # a JAX that insists on it
+    from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu  # noqa: E402
 
 NEG_INF = -1e30
 # lane width of a TPU vector register: the last dimension of every block
 # the TPU compiler accepts is a multiple of it (or the whole array's)
 LANES = 128
+#: the tile edges `block_sizes` tries, best first (PERF.md, PR 26: the
+#: sweep on the v5e that ordered them)
+BLOCKS = (1024, 512, 256)
+
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+
+# The kernel bodies below are written with ``lax`` functions, not with
+# operators or ``jnp``: on a tracer ``a * b`` and ``jnp.where`` each go
+# through a jitted numpy wrapper, and a body has sixty of them. The
+# jaxprs are the same; a program traces them in half the time (PERF.md,
+# PR 27).
+
+
+def _on_visible_tiles(block, causal: bool, q_block, k_block, block_q: int,
+                      block_k: int, causal_offset: int = 0):
+    """Run ``block(masked)`` on the score tile of Q block ``q_block`` and
+    K block ``k_block`` as the mask ``row >= col + causal_offset`` leaves
+    it: not at all where the mask empties it, with the mask where the
+    diagonal cuts it, else plain."""
+    if not causal:
+        block(False)
+        return
+    first_col = lax.add(lax.mul(k_block, block_k), causal_offset)
+    visible = lax.lt(first_col, lax.mul(lax.add(q_block, 1), block_q))
+    cut = lax.lt(lax.mul(q_block, block_q),
+                 lax.add(first_col, block_k - 1))
+    pl.when(lax.bitwise_and(visible, cut))(lambda: block(True))
+    pl.when(lax.bitwise_and(visible, lax.bitwise_not(cut)))(
+        lambda: block(False))
+
+
+def _causal(s, q_block, k_block, block_q: int, block_k: int,
+            causal_offset: int = 0, q_axis: int = 0):
+    """The score tile ``s`` of Q block ``q_block`` and K block
+    ``k_block`` with NEG_INF wherever ``row < col + causal_offset``; Q
+    rows run along ``q_axis`` of the tile."""
+    rows = lax.add(lax.mul(q_block, block_q),
+                   lax.broadcasted_iota(jnp.int32, s.shape, q_axis))
+    cols = lax.add(lax.add(lax.mul(k_block, block_k), causal_offset),
+                   lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis))
+    return lax.select(lax.ge(rows, cols), s, lax.full_like(s, NEG_INF))
+
+
+def _zeros(ref):
+    return lax.full(ref.shape, 0, ref.dtype)
+
+
+def _row_max(x):
+    """[n, m] -> [n, 1]."""
+    return lax.broadcast_in_dim(lax.reduce_max(x, (1,)), (x.shape[0], 1),
+                                (0,))
+
+
+def _row_sum(x):
+    """[n, m] -> [n, 1]."""
+    return lax.broadcast_in_dim(lax.reduce_sum(x, (1,)), (x.shape[0], 1),
+                                (0,))
+
+
+def _dot(a, b, dims):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _as_row(col):
+    """[n, 1] column -> [1, n] lane-major row, as the [.., 1, s] row
+    statistics are stored: spread over a lane tile, transpose, keep one
+    row. The TPU has no 1-D vector layout, and per-row statistics are
+    columns wherever they meet a [rows, cols] score tile."""
+    n = col.shape[0]
+    wide = lax.broadcast_in_dim(col, (n, LANES), (0, 1))
+    return lax.slice(lax.transpose(wide, (1, 0)), (0, 0), (1, n))
+
+
+def _as_col(row):
+    """The reverse of `_as_row`: [1, n] -> [n, 1]."""
+    n = row.shape[1]
+    wide = lax.broadcast_in_dim(row, (LANES, n), (0, 1))
+    return lax.slice(lax.transpose(wide, (1, 0)), (0, 0), (n, 1))
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                       acc_scr, m_scr, l_scr, *, scale: float, causal: bool,
                       causal_offset: int, block_q: int, block_k: int,
                       num_k_blocks: int):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    qi = pl.program_id(2)
+    ki = pl.program_id(3)
 
-    @pl.when(ki == 0)
+    @pl.when(lax.eq(ki, 0))
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = lax.full(m_scr.shape, NEG_INF, m_scr.dtype)
+        l_scr[...] = _zeros(l_scr)
+        acc_scr[...] = _zeros(acc_scr)
 
-    def _block():
-        q = q_ref[0]                      # [bq, d]
-        k = k_ref[0]                      # [bk, d]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [bq, bk]
-        if causal:
+    def _block(masked: bool):
+        # q [bq, d] x k [bk, d] -> [bq, bk]
+        s = lax.mul(_dot(q_ref[0], k_ref[0], _NT), scale)
+        if masked:
             # causal_offset=0: standard (row >= col); =1: STRICT (row > col)
             # — striped ring attention's j>i rounds exclude the diagonal
-            rows = qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = ki * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols + causal_offset, s, NEG_INF)
+            s = _causal(s, qi, ki, block_q, block_k, causal_offset)
         # running stats stay [bq, 1] columns (one per score row): the
         # TPU has no 1-D vector layout
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[:] = l_scr[:] * alpha + p.sum(axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = m_new
+        m_prev = m_scr[...]
+        m_new = lax.max(m_prev, _row_max(s))
+        p = lax.exp(lax.sub(s, m_new))
+        alpha = lax.exp(lax.sub(m_prev, m_new))
+        l_scr[...] = lax.add(lax.mul(l_scr[...], alpha), _row_sum(p))
+        v = v_ref[0]
+        acc_scr[...] = lax.add(
+            lax.mul(acc_scr[...], alpha),
+            _dot(lax.convert_element_type(p, v.dtype), v, _NN))
+        m_scr[...] = m_new
 
-    if causal:
-        # skip blocks whose mask is entirely empty
-        @pl.when(ki * block_k + causal_offset < (qi + 1) * block_q)
-        def _():
-            _block()
-    else:
-        _block()
+    _on_visible_tiles(_block, causal, qi, ki, block_q, block_k,
+                      causal_offset)
 
-    @pl.when(ki == num_k_blocks - 1)
+    @pl.when(lax.eq(ki, num_k_blocks - 1))
     def _finalize():
         # guard fully-masked rows (l == 0 never happens when causal includes
         # the diagonal, but ring callers may pass degenerate blocks)
-        l = l_scr[:]
-        o_ref[0] = (acc_scr[:] / jnp.where(l == 0.0, 1.0, l)).astype(
-            o_ref.dtype)
-
-        def row(col):
-            # [bq, 1] column -> [1, bq] lane-major row for the [B, 1, sq]
-            # outputs: spread over a lane tile, transpose, keep one row
-            return jnp.broadcast_to(col, (block_q, LANES)).T[:1]
-
-        m_ref[0] = row(m_scr[:])
-        l_ref[0] = row(l)
+        l = l_scr[...]
+        safe = lax.select(lax.eq(l, 0.0), lax.full_like(l, 1.0), l)
+        o_ref[0] = lax.convert_element_type(lax.div(acc_scr[...], safe),
+                                            o_ref.dtype)
+        m_ref[0] = _as_row(m_scr[...])
+        l_ref[0] = _as_row(l)
 
 
 def _interpret() -> bool:
@@ -119,19 +250,29 @@ def kernel_tiles(sq: int, sk: int, block_q: int, block_k: int,
         b % LANES == 0 or b == n for b, n in ((bq, sq), (bk, sk)))
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
-                                             "causal_offset"))
-def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
-               causal_offset: int = 0):
-    """q: [B, sq, d], k/v: [B, sk, d] → (o [B, sq, d], m [B, sq], l [B, sq]).
+def block_sizes(s: int, head_dim: int):
+    """``(block_q, block_k)`` for a decoder's self-attention over ``s``
+    positions with its heads side by side, ``head_dim`` wide each, or
+    None where the TPU kernels cannot take that (a head that is no lane
+    multiple, a length none of `BLOCKS` tiles). The one place a caller
+    with no reason of its own gets its tile shape (ring attention passes
+    its own). Square, and the largest that tiles: on the v5e a larger
+    tile beat a finer causal skip at every length tried (PERF.md, PR 26;
+    the sweep ran at ``head_dim`` 128 only)."""
+    if head_dim % LANES:
+        return None
+    for block in BLOCKS:
+        if kernel_tiles(s, s, block, block):
+            return min(block, s), min(block, s)
+    return None
 
-    o is *normalized* (already divided by l); combining across ring steps
-    uses (m, l) to undo/redo normalization exactly.
-    """
-    B, sq, d = q.shape
-    sk = k.shape[1]
-    bq = min(block_q, sq)
-    bk = min(block_k, sk)
+
+def _blocks(sq: int, sk: int, block_q: int, block_k: int, d: int,
+            heads: int):
+    """The blocks as the kernels take them (no longer than the
+    sequences) and whether they run interpreted; raises where the
+    shapes do not tile."""
+    bq, bk = min(block_q, sq), min(block_k, sk)
     interpret = _interpret()
     if not kernel_tiles(sq, sk, bq, bk, lane_aligned=not interpret):
         raise ValueError(
@@ -140,46 +281,117 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
             f"{LANES} or the whole sequence; pick block_q/block_k that "
             "tile the sequence or use the blockwise XLA fallback "
             "(scan_stats / use_flash=False)")
-    nq, nk = sq // bq, sk // bk
-    scale = d ** -0.5
+    if heads > 1 and d % LANES and not interpret:
+        raise ValueError(
+            f"heads side by side must each be a multiple of {LANES} wide "
+            f"on TPU, not {d}: fold them into the batch (heads=1)")
+    return bq, bk, interpret
 
+
+#: every grid is (batch, head, outer block, inner block): the inner one
+#: accumulates into scratch, the rest are independent
+_GRID_SEMANTICS = pltpu.CompilerParams(dimension_semantics=(
+    "parallel", "parallel", "parallel", "arbitrary"))
+
+
+def _specs(bq: int, bk: int, d: int, heads: int, q_block, k_block):
+    """BlockSpecs over a grid ``(b, head, x, y)``: one head's ``d``
+    columns of a block of rows of a [b, s, heads*d] array, for Q-like and
+    K-like arrays, and a block of a [b*heads, 1, sq] row statistic.
+    ``q_block(x, y)`` and ``k_block(x, y)`` give the row blocks.
+
+    The statistics are [.., 1, sq] because a (1, 1, bq) block is legal
+    on TPU (second-to-last dim = the whole array's, last a lane
+    multiple) and a (1, bq) block of [.., sq] is not."""
+    return (
+        pl.BlockSpec((1, bq, d), lambda b, h, x, y: (b, q_block(x, y), h)),
+        pl.BlockSpec((1, bk, d), lambda b, h, x, y: (b, k_block(x, y), h)),
+        pl.BlockSpec((1, 1, bq), lambda b, h, x, y: (
+            lax.add(lax.mul(b, heads), h), 0, q_block(x, y))))
+
+
+def _visible_k_block(causal: bool, bq: int, bk: int, causal_offset: int,
+                     i, j):
+    """K block ``j`` of Q block ``i``'s row of tiles, or, where the mask
+    empties that tile, the last one it leaves: a tile that does nothing
+    holds its neighbour's block index and asks for no DMA either."""
+    if causal:
+        last = lax.div(lax.sub(lax.mul(lax.add(i, 1), bq),
+                               causal_offset + 1), bk)
+        j = lax.min(j, lax.max(last, 0))
+    return j
+
+
+def _vma(*arrays):
+    """Inside a vma-checked shard_map (ring attention) a kernel's outputs
+    vary over the mesh axes its inputs vary over; frozenset() elsewhere."""
+    return frozenset().union(*(jax.typeof(x).vma for x in arrays))
+
+
+def _fwd_call(q, k, v, causal: bool, block_q: int, block_k: int,
+              causal_offset: int, heads: int):
+    """The forward kernel's call: q [B, sq, heads*d], k/v [B, sk,
+    heads*d] -> (o [B, sq, heads*d], m, l [B*heads, 1, sq] float32)."""
+    B, sq, width = q.shape
+    sk, d = k.shape[1], width // heads
+    bq, bk, interpret = _blocks(sq, sk, block_q, block_k, d, heads)
+    nq, nk = sq // bq, sk // bk
     kernel = functools.partial(
-        _flash_fwd_kernel, scale=scale, causal=causal,
+        _flash_fwd_kernel, scale=d ** -0.5, causal=causal,
         causal_offset=causal_offset, block_q=bq, block_k=bk,
         num_k_blocks=nk)
-    # inside a vma-checked shard_map (ring attention) the outputs vary
-    # over the mesh axes the inputs vary over; frozenset() elsewhere
-    vma = frozenset().union(*(jax.typeof(x).vma for x in (q, k, v)))
-    # m/l leave the kernel as [B, 1, sq]: a (1, 1, bq) block is legal on
-    # TPU (second-to-last dim = the whole array's, last a lane multiple),
-    # a (1, bq) block of [B, sq] is not
-    o, m, l = pl.pallas_call(
+    vma = _vma(q, k, v)
+    k_block = functools.partial(_visible_k_block, causal, bq, bk,
+                                causal_offset)
+    q_spec, k_spec, row_spec = _specs(bq, bk, d, heads, lambda i, j: i,
+                                      k_block)
+    row = jax.ShapeDtypeStruct((B * heads, 1, sq), jnp.float32, vma=vma)
+    return pl.pallas_call(
         kernel,
-        grid=(B, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
-            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, sq, d), q.dtype, vma=vma),
-            jax.ShapeDtypeStruct((B, 1, sq), jnp.float32, vma=vma),
-            jax.ShapeDtypeStruct((B, 1, sq), jnp.float32, vma=vma),
-        ],
+        grid=(B, heads, nq, nk),
+        in_specs=[q_spec, k_spec, k_spec],
+        out_specs=[q_spec, row_spec, row_spec],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma), row, row],
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        interpret=interpret,
+        compiler_params=_GRID_SEMANTICS, interpret=interpret,
         name="hvd_flash_fwd",  # a trace's kernel events are found by it
     )(q, k, v)
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
+                                             "causal_offset", "heads"))
+def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
+               causal_offset: int = 0, heads: int = 1):
+    """q: [B, sq, heads*d], k/v: [B, sk, heads*d] → (o [B, sq, heads*d],
+    m [B*heads, sq], l [B*heads, sq]).
+
+    o is *normalized* (already divided by l); combining across ring steps
+    uses (m, l) to undo/redo normalization exactly. With ``heads`` > 1
+    each head is ``d`` adjacent columns — a decoder's [b, s, h, hd]
+    activations as its projections write them, no transpose — and the
+    kernel takes them head by head through its block index.
+    """
+    o, m, l = _fwd_call(q, k, v, causal, block_q, block_k, causal_offset,
+                        heads)
     return o, m[:, 0], l[:, 0]
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
+                                             "heads"))
+def _flash_fwd_lse(q, k, v, causal: bool, block_q: int, block_k: int,
+                   heads: int = 1):
+    """`flash_attention`'s forward, for its primal and for its VJP alike:
+    (o, lse [B*heads, 1, sq]) with ``lse = m + log l``, all the backward
+    kernels need of the softmax (a row the mask empties, l == 0, cannot
+    occur at causal_offset 0). One jitted entry for both, so a program
+    traces and lowers the forward kernel once however many blocks call
+    it, differentiated, recomputed or plain."""
+    o, m, l = _fwd_call(q, k, v, causal, block_q, block_k, 0, heads)
+    return o, lax.add(m, lax.log(l))
 
 
 def _reference_attention(q, k, v, causal: bool, causal_offset: int = 0):
@@ -195,12 +407,22 @@ def _reference_attention(q, k, v, causal: bool, causal_offset: int = 0):
     return jnp.einsum("bqk,bkd->bqd", p.astype(v.dtype), v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _pinned_mesh():
+    """JAX keys a jit's trace on the abstract-mesh context, which reads
+    None where a ``custom_vjp`` traces its primal and an empty mesh under
+    ``jax.checkpoint``'s JVP; pinned to what it is, both find the one
+    trace of each kernel's entry (PERF.md, PR 26: 0.2 s of a job's
+    set-up)."""
+    return jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh())
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
-                    block_k: int = 512):
-    """Fused attention: q [B, sq, d] × k/v [B, sk, d] → [B, sq, d]."""
-    o, _, _ = _flash_fwd(q, k, v, causal, block_q, block_k)
-    return o
+                    block_k: int = 512, heads: int = 1):
+    """Fused attention: q [B, sq, heads*d] × k/v [B, sk, heads*d] →
+    [B, sq, heads*d], each head ``d`` adjacent columns."""
+    with _pinned_mesh():
+        return _flash_fwd_lse(q, k, v, causal, block_q, block_k, heads)[0]
 
 
 def flash_attention_stats(q, k, v, causal: bool = True, block_q: int = 512,
@@ -309,20 +531,165 @@ def _stats_bwd(causal, block_q, block_k, causal_offset, res, cts):
 attention_stats.defvjp(_stats_fwd, _stats_bwd)
 
 
-def _fwd(q, k, v, causal, block_q, block_k):
-    o, m, l = _flash_fwd(q, k, v, causal, block_q, block_k)
-    # only the inputs are residuals: the blockwise VJP recomputes its
-    # own stats, so o/lse must not stay live across fwd->bwd
-    return o, (q, k, v)
+def _p_and_ds(q, k, v, do, lse, delta, qi, ki, *, scale: float, masked: bool,
+              block_q: int, block_k: int, transposed: bool):
+    """One tile of the backward pass, recomputed from the forward's
+    ``lse``: ``p = exp(s - lse)`` and ``ds = p * (dp - delta)`` (without
+    the ``scale`` factor, which the caller applies once to its sum), both
+    float32, [bq, bk] — or, ``transposed``, [bk, bq], a K row per
+    sublane and a Q row per lane. ``lse``/``delta`` are per Q row:
+    [bq, 1] columns, or [1, bq] rows when transposed."""
+    a, b, c, e = (k, q, v, do) if transposed else (q, k, do, v)
+    s = lax.mul(_dot(a, b, _NT), scale)
+    if masked:
+        s = _causal(s, qi, ki, block_q, block_k, q_axis=int(transposed))
+    p = lax.exp(lax.sub(s, lse))
+    return p, lax.mul(p, lax.sub(_dot(c, e, _NT), delta))
 
 
-def _bwd(causal, block_q, block_k, res, do):
-    q, k, v = res
+def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                          dk_ref, dv_ref, dk_scr, dv_scr, *, scale: float,
+                          causal: bool, block_q: int, block_k: int,
+                          num_q_blocks: int):
+    ki = pl.program_id(2)
+    qi = pl.program_id(3)
+
+    @pl.when(lax.eq(qi, 0))
+    def _init():
+        dk_scr[...] = _zeros(dk_scr)
+        dv_scr[...] = _zeros(dv_scr)
+
+    def _block(masked: bool):
+        # the tile is held TRANSPOSED: the per-Q-row statistics are
+        # [1, bq] rows as they come from HBM, and both accumulations are
+        # plain [bk, bq] x [bq, d] products, with nothing to transpose
+        q, do = q_ref[0], do_ref[0]       # [bq, d]
+        pt, dst = _p_and_ds(
+            q, k_ref[0], v_ref[0], do, lse_ref[0], delta_ref[0], qi, ki,
+            scale=scale, masked=masked, block_q=block_q, block_k=block_k,
+            transposed=True)
+        dv_scr[...] = lax.add(dv_scr[...], _dot(
+            lax.convert_element_type(pt, do.dtype), do, _NN))
+        dk_scr[...] = lax.add(dk_scr[...], _dot(
+            lax.convert_element_type(dst, q.dtype), q, _NN))
+
+    _on_visible_tiles(_block, causal, qi, ki, block_q, block_k)
+
+    @pl.when(lax.eq(qi, num_q_blocks - 1))
+    def _finalize():
+        dk_ref[0] = lax.convert_element_type(lax.mul(dk_scr[...], scale),
+                                             dk_ref.dtype)
+        dv_ref[0] = lax.convert_element_type(dv_scr[...], dv_ref.dtype)
+
+
+def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+                         dq_ref, delta_ref, dq_scr, lse_scr, delta_scr, *,
+                         scale: float, causal: bool, block_q: int,
+                         block_k: int, num_k_blocks: int):
+    qi = pl.program_id(2)
+    ki = pl.program_id(3)
+
+    @pl.when(lax.eq(ki, 0))
+    def _init():
+        dq_scr[...] = _zeros(dq_scr)
+        lse_scr[...] = _as_col(lse_ref[0])
+        # delta = rowsum(do * o), once per Q block while both are here;
+        # it leaves as a row too, for the dK/dV kernel
+        delta = _row_sum(lax.mul(
+            lax.convert_element_type(do_ref[0], jnp.float32),
+            lax.convert_element_type(o_ref[0], jnp.float32)))   # [bq, 1]
+        delta_scr[...] = delta
+        delta_ref[0] = _as_row(delta)
+
+    def _block(masked: bool):
+        k = k_ref[0]                      # [bk, d]
+        _, ds = _p_and_ds(
+            q_ref[0], k, v_ref[0], do_ref[0], lse_scr[...], delta_scr[...],
+            qi, ki, scale=scale, masked=masked, block_q=block_q,
+            block_k=block_k, transposed=False)
+        dq_scr[...] = lax.add(dq_scr[...], _dot(
+            lax.convert_element_type(ds, k.dtype), k, _NN))
+
+    _on_visible_tiles(_block, causal, qi, ki, block_q, block_k)
+
+    @pl.when(lax.eq(ki, num_k_blocks - 1))
+    def _finalize():
+        dq_ref[0] = lax.convert_element_type(lax.mul(dq_scr[...], scale),
+                                             dq_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
+                                             "heads"))
+def _flash_bwd(q, k, v, do, o, lse, causal: bool, block_q: int,
+               block_k: int, heads: int = 1):
+    """q/do/o: [B, sq, heads*d], k/v: [B, sk, heads*d], lse: [B*heads,
+    1, sq] float32 → (dq, dk, dv): the VJP of `flash_attention` as two
+    kernels. The dQ kernel runs first: it has ``do`` and ``o`` of a Q
+    block together, so ``delta = rowsum(do * o)`` is made there and
+    handed to the dK/dV kernel."""
+    B, sq, width = q.shape
+    sk, d = k.shape[1], width // heads
+    bq, bk, interpret = _blocks(sq, sk, block_q, block_k, d, heads)
+    nq, nk = sq // bq, sk // bk
+    static = dict(scale=d ** -0.5, causal=causal, block_q=bq, block_k=bk)
+    vma = _vma(q, k, v, do)
+    params = dict(compiler_params=_GRID_SEMANTICS, interpret=interpret)
+
+    # as in the forward, a tile the mask empties holds the block index
+    # of the nearest visible one
+    def q_block(j, i):   # dkv: Q blocks above K block j's diagonal
+        if causal:
+            i = lax.min(lax.max(i, lax.div(lax.mul(j, bk), bq)), nq - 1)
+        return i
+
+    q_spec, k_spec, row_spec = _specs(
+        bq, bk, d, heads, lambda i, j: i,
+        functools.partial(_visible_k_block, causal, bq, bk, 0))
+    row = jax.ShapeDtypeStruct(lse.shape, jnp.float32, vma=vma)
+    dq, delta = pl.pallas_call(
+        functools.partial(_flash_bwd_dq_kernel, num_k_blocks=nk, **static),
+        grid=(B, heads, nq, nk),
+        in_specs=[q_spec, k_spec, k_spec, q_spec, q_spec, row_spec],
+        out_specs=[q_spec, row_spec],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma), row],
+        scratch_shapes=[
+            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+        ],
+        name="hvd_flash_bwd_dq", **params,
+    )(q, k, v, do, o, lse)
+    q_spec, k_spec, row_spec = _specs(bq, bk, d, heads, q_block,
+                                      lambda j, i: j)
+    dk, dv = pl.pallas_call(
+        functools.partial(_flash_bwd_dkv_kernel, num_q_blocks=nq, **static),
+        grid=(B, heads, nk, nq),
+        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        out_specs=[k_spec, k_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct(k.shape, k.dtype, vma=vma),
+            jax.ShapeDtypeStruct(v.shape, v.dtype, vma=vma),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, d), jnp.float32),
+        ],
+        name="hvd_flash_bwd_dkv", **params,
+    )(q, k, v, do, lse, delta)
+    return dq, dk, dv
+
+
+def _fwd(q, k, v, causal, block_q, block_k, heads):
+    with _pinned_mesh():
+        o, lse = _flash_fwd_lse(q, k, v, causal, block_q, block_k, heads)
+    return o, (q, k, v, o, lse)
+
+
+def _bwd(causal, block_q, block_k, heads, res, do):
+    q, k, v, o, lse = res
     with jax.named_scope(scopes.ATTENTION):
-        _, vjp = jax.vjp(
-            lambda a, b, c: scan_stats(a, b, c, causal, 0, block_k)[0],
-            q, k, v)
-        return vjp(do)
+        return _flash_bwd(q, k, v, do, o, lse, causal, block_q, block_k,
+                          heads)
 
 
 flash_attention.defvjp(_fwd, _bwd)

@@ -23,7 +23,9 @@ Forward, backward and recompute need no scope: JAX marks them itself
 Counters (`StepRecord.counters`, noted once while a step is traced, so
 per step and per chip): ``collectives`` the exchange issued,
 ``collective_bytes`` handed to them, ``packed_bytes`` copied into flat
-buffers, ``axis_size``.
+buffers, ``axis_size``; ``attention_calls`` the decoder's default attention
+traced and ``attention_kernel_calls`` of them routed to the fused
+kernels (a share of the two survives retracing under ``jax.checkpoint``).
 """
 
 from __future__ import annotations
@@ -61,6 +63,7 @@ _INSTRUCTION = re.compile(r"\s*(?:ROOT\s+)?%?([^\s=]+) = ")
 _OP_NAME = re.compile(r'\bop_name="((?:[^"\\]|\\.)*)"')
 _COMPUTATION = re.compile(r"(?:ENTRY\s+)?%?([^\s(]+) \(.*\{\s*$")
 _CALLS = re.compile(r"\bcalls=%?([^\s,}]+)")
+_COPY_OF = re.compile(r"\scopy\(%?([^\s,)]+)\)")
 
 
 def phase_of(op_name: Optional[str]) -> str:
@@ -93,8 +96,11 @@ def instruction_scopes(hlo_text: str) -> dict:
     reads ``other`` (on the v5e: AdamW's update fused under
     ``optax.apply_updates``' unscoped add; PERF.md, PR 25) the fusion
     takes the names inside the computation it calls instead, those of
-    the phase most of them have."""
-    table, inside, fusions, computation = {}, {}, {}, None
+    the phase most of them have. A ``copy`` without metadata is the
+    compiler re-tiling a result for its user (on the v5e: the attention
+    weights' gradients ahead of the flat buffer; PERF.md, PR 26): it is
+    filed with the instruction that made the result."""
+    table, inside, fusions, copies, computation = {}, {}, {}, {}, None
     for line in hlo_text.splitlines():
         m = _INSTRUCTION.match(line)
         if m is None:
@@ -109,6 +115,9 @@ def instruction_scopes(hlo_text: str) -> dict:
         called = _CALLS.search(line, m.end())
         if called and phase_of(table[m.group(1)]) == "other":
             fusions[m.group(1)] = called.group(1)
+        copied = None if op else _COPY_OF.search(line, m.end())
+        if copied:
+            copies[m.group(1)] = copied.group(1)
     for name, called in fusions.items():
         names = inside.get(called, ())
         votes = collections.Counter(phase_of(n) for n in names)
@@ -116,6 +125,8 @@ def instruction_scopes(hlo_text: str) -> dict:
             winner = max(PHASES, key=lambda p: votes[p])  # ties: precedence
             table[name] = ";".join(dict.fromkeys(
                 n for n in names if phase_of(n) == winner))
+    for name, source in copies.items():  # in program order: chains resolve
+        table[name] = table.get(source, "")
     return table
 
 
@@ -187,3 +198,18 @@ def note_exchange(buffers, axis_name: str, packed: bool = False) -> None:
     c["collective_bytes"] = c.get("collective_bytes", 0) + nbytes
     c["packed_bytes"] = c.get("packed_bytes", 0) + (nbytes if packed else 0)
     c["axis_size"] = int(jax.lax.axis_size(axis_name))
+
+
+def note_attention(kernel: bool) -> None:
+    """Called where ``models/transformer.py`` routes one default
+    attention call, to the fused kernels or to `causal_attention`. A
+    block under ``jax.checkpoint`` is traced more than once, so read the
+    two counters as a share. A no-op outside a traced
+    ``data_parallel_step``."""
+    record = _tracing.get()
+    if record is None:
+        return
+    c = record.counters
+    c["attention_calls"] = c.get("attention_calls", 0) + 1
+    c["attention_kernel_calls"] = (c.get("attention_kernel_calls", 0)
+                                   + int(kernel))

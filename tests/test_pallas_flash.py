@@ -11,7 +11,9 @@ from horovod_tpu.ops.pallas.flash_attention import (
     _lax_stats,
     _reference_attention,
     attention_stats,
+    block_sizes,
     flash_attention,
+    scan_stats,
 )
 
 
@@ -44,6 +46,181 @@ def test_flash_gradients_match_reference(qkv):
     g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-3)
+
+
+@pytest.mark.parametrize("wrt", [0, 1, 2], ids=["dq", "dk", "dv"])
+@pytest.mark.parametrize("block_q,block_k", [(128, 64), (64, 256)])
+@pytest.mark.parametrize("causal", [True, False])
+def test_backward_kernels_match_autodiff_of_reference(qkv, causal, block_q,
+                                                      block_k, wrt):
+    """``hvd_flash_bwd_dq`` / ``hvd_flash_bwd_dkv`` (interpret mode)
+    against autodiff of the dense oracle, with two heads side by side as
+    the decoder hands them over: blocks wider than tall and taller than
+    wide, so tiles are skipped, cut by the diagonal and left whole."""
+    B, s, d, heads = 2, 256, 32, 2
+    q, k, v = (x.reshape(B, s, heads * d) for x in qkv)
+    w = jnp.cos(jnp.arange(q.size, dtype=jnp.float32)).reshape(q.shape)
+
+    def fold(x):    # [B, s, heads*d] -> the oracle's [B*heads, s, d]
+        return x.reshape(B, s, heads, d).swapaxes(1, 2).reshape(-1, s, d)
+
+    def loss_kernels(*x):
+        return (flash_attention(*x, causal, block_q, block_k, heads)
+                * w).sum()
+
+    def loss_ref(*x):
+        return (_reference_attention(*map(fold, x), causal)
+                * fold(w)).sum()
+
+    got = jax.grad(loss_kernels, argnums=wrt)(q, k, v)
+    want = jax.grad(loss_ref, argnums=wrt)(q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+@pytest.mark.parametrize("blocks", [2, 4])
+@pytest.mark.parametrize("block,heads", [(128, 4), (256, 1), (128, 1),
+                                         (256, 4)])
+@pytest.mark.parametrize("causal", [True, False])
+def test_grad_matches_reference_and_scan_stats_vjp(causal, block, heads,
+                                                   blocks):
+    """``jax.grad`` through `flash_attention` (all three kernels,
+    interpret mode) against autodiff of the dense oracle and against the
+    VJP it replaced, `scan_stats`', over square blocks as `block_sizes`
+    gives them: a sequence of two and of four, heads side by side."""
+    B, s, d = 1, block * blocks, 16
+    rng = np.random.RandomState(block + blocks + heads)
+    q, k, v, w = (jnp.asarray(rng.randn(B, s, heads * d), jnp.float32)
+                  for _ in range(4))
+
+    def fold(x):    # [B, s, heads*d] -> the oracles' [B*heads, s, d]
+        return x.reshape(B, s, heads, d).swapaxes(1, 2).reshape(-1, s, d)
+
+    def grads(attention):
+        return jax.jit(jax.grad(lambda *x: (attention(*x) * w).sum(),
+                                argnums=(0, 1, 2)))(q, k, v)
+
+    def folded(attention):
+        return lambda *x: attention(*map(fold, x)).reshape(
+            B, heads, s, d).swapaxes(1, 2).reshape(B, s, heads * d)
+
+    got = grads(lambda *x: flash_attention(*x, causal, block, block, heads))
+    dense = grads(folded(lambda *x: _reference_attention(*x, causal)))
+    scanned = grads(folded(lambda *x: scan_stats(*x, causal, 0, block)[0]))
+    for name, g, a, b in zip(("dq", "dk", "dv"), got, dense, scanned):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(a), atol=5e-5,
+                                   err_msg=f"{name} against the oracle")
+        np.testing.assert_allclose(np.asarray(g), np.asarray(b), atol=5e-5,
+                                   err_msg=f"{name} against scan_stats")
+
+
+@pytest.mark.parametrize("s,head_dim,want", [
+    (2048, 128, (1024, 1024)), (1536, 128, (512, 512)),
+    (512, 128, (512, 512)), (768, 256, (768, 768)),   # one whole block
+    (2050, 128, None),            # none of 1024, 512, 256 divides it
+    (2048, 64, None), (2048, 192, None),   # a head that is no lane multiple
+])
+def test_block_sizes_takes_the_largest_square_that_tiles(s, head_dim, want):
+    assert block_sizes(s, head_dim) == want
+
+
+def _inside_shard_map(mesh, axes, ask):
+    """``ask()`` as it answers inside a shard_map manual over ``axes``."""
+    from jax.sharding import PartitionSpec as P
+
+    seen = []
+
+    def body(x):
+        seen.append(ask())
+        return x
+
+    jax.eval_shape(jax.shard_map(
+        body, mesh=mesh, in_specs=P(), out_specs=P(),
+        axis_names=frozenset(axes), check_vma=False), jnp.zeros(()))
+    return seen[0]
+
+
+@pytest.mark.parametrize("on_tpu,devices,manual,s,head_dim,constraints,want", [
+    (True, 1, None, 2048, 128, False, (1024, 1024)),   # the LM cell's check
+    (True, 8, ("dp", "tp"), 2048, 128, False, (1024, 1024)),  # its step
+    (True, 1, None, 512, 128, False, (512, 512)),      # the shortest taken
+    (False, 1, None, 2048, 128, False, None),          # off the TPU
+    (True, 1, None, 2048, 128, True, None),            # GSPMD constraints
+    (True, 1, None, 256, 128, False, None),            # the kernels lose
+    (True, 1, None, 2050, 128, False, None),           # nothing tiles it
+    (True, 1, None, 2048, 64, False, None),            # half a lane a head
+    (True, 8, None, 2048, 128, False, None),   # a bare jit may be GSPMD's
+    (True, 8, ("dp",), 2048, 128, False, None),        # 'tp' left to XLA
+], ids=["one-device", "all-axes-manual", "s512", "cpu", "use-constraints",
+        "s256", "s2050", "head64", "several-devices-no-mesh",
+        "one-axis-manual"])
+def test_fused_attention_blocks_at_each_exit_of_the_rule(
+        monkeypatch, on_tpu, devices, manual, s, head_dim, constraints, want):
+    """``models/transformer.py`` takes the fused kernels exactly where
+    its rule says: a TPU, nothing for XLA to partition (every mesh axis
+    manual, or one device), no GSPMD constraints, a sequence of 512 or
+    more that the blocks tile, heads a lane multiple wide."""
+    from jax.sharding import Mesh
+
+    from horovod_tpu.models import transformer as T
+
+    monkeypatch.setattr(T, "_on_tpu", lambda: on_tpu)
+    monkeypatch.setattr(jax, "device_count", lambda: devices)
+
+    def ask():
+        return T.fused_attention_blocks(s, head_dim, constraints)
+
+    if manual is None:
+        assert ask() == want
+    else:
+        mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ("dp", "tp"))
+        assert _inside_shard_map(mesh, manual, ask) == want
+
+
+def test_decoder_on_the_kernels_agrees_with_causal_attention(monkeypatch):
+    """A toy decoder whose 256 positions take two 128-blocks, as
+    ``data_parallel_step`` runs it (per chip, inside a shard_map): routed
+    to the kernels, loss and gradients are `causal_attention`'s, and the
+    two counters say where each call went. A caller's own ``attn_fn`` is
+    the caller's: the rule stays out of it."""
+    import importlib
+
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from horovod_tpu.models import transformer as T
+    from horovod_tpu.utils import scopes
+
+    F = importlib.import_module("horovod_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(T, "FUSED_ATTENTION_MIN_SEQ", 256)
+    monkeypatch.setattr(F, "BLOCKS", (128,))
+    cfg = T.TransformerConfig(vocab_size=64, d_model=256, n_heads=2,
+                              n_layers=2, d_ff=64, max_seq=256, remat=True,
+                              dtype=jnp.float32)
+    params = T.init(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (1, 257), 0, 64)
+    one_chip = Mesh(np.array(jax.devices()[:1]), ("hvd",))
+
+    def loss_and_grads(on_tpu, run=lambda f, *x: jax.jit(f)(*x), **kw):
+        monkeypatch.setattr(T, "_on_tpu", lambda: on_tpu)
+        record = scopes.StepRecord()
+        with scopes.recording(record):
+            out = run(jax.shard_map(
+                lambda p, t: jax.value_and_grad(T.lm_loss)(
+                    p, t, cfg, use_constraints=False, **kw),
+                mesh=one_chip, in_specs=P(), out_specs=P(),
+                check_vma=False), params, tokens)
+        calls = record.counters.get("attention_calls", 0)
+        return out, calls and record.counters["attention_kernel_calls"] / calls
+
+    (loss, grads), share = loss_and_grads(True)
+    (want, want_grads), want_share = loss_and_grads(False)
+    assert (share, want_share) == (1.0, 0.0)
+    np.testing.assert_allclose(float(loss), float(want), rtol=1e-6)
+    for got, ref in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=1e-6, rtol=1e-4)
+    _, share = loss_and_grads(True, jax.eval_shape,
+                              attn_fn=T.causal_attention)
+    assert share == 0
 
 
 def test_attention_stats_contract(qkv):
@@ -157,17 +334,19 @@ def test_scan_stats_matches_lax_stats(qkv):
 
 
 def test_flash_backward_is_blockwise_in_memory():
-    """The VJP's compiled temp memory shrinks with the block size — the
-    [B, sq, sk] score matrix is gone from the backward executable (it
-    was the dense VJP's dominant buffer). Needs a length where the
-    score matrix dominates the scan bookkeeping."""
+    """The blockwise VJP's compiled temp memory shrinks with the block
+    size — the [B, sq, sk] score matrix is gone from the backward
+    executable (it was the dense VJP's dominant buffer). Held on
+    `attention_stats`, whose VJP `scan_stats` still is (ring attention).
+    Needs a length where the score matrix dominates the scan
+    bookkeeping."""
     rng = np.random.RandomState(7)
     B, s, d = 1, 1024, 32
     q = jnp.asarray(rng.randn(B, s, d), jnp.float32)
 
     def temp_mb(bk):
         f = jax.jit(jax.grad(
-            lambda q, k, v: (flash_attention(q, k, v, True, 256, bk)
+            lambda q, k, v: (attention_stats(q, k, v, True, 256, bk)[0]
                              .astype(jnp.float32) ** 2).sum(),
             argnums=(0, 1, 2)))
         c = f.lower(q, q, q).compile()
@@ -175,3 +354,25 @@ def test_flash_backward_is_blockwise_in_memory():
 
     small, full = temp_mb(64), temp_mb(1024)
     assert small < full * 0.6, (small, full)
+
+
+def test_fused_backward_stays_under_the_dense_oracle_in_memory():
+    """`flash_attention`'s backward kernels keep the compiled temp
+    memory far under the dense oracle's at every block size: the score
+    matrix is in neither direction's executable."""
+    rng = np.random.RandomState(7)
+    B, s, d = 1, 1024, 32
+    q = jnp.asarray(rng.randn(B, s, d), jnp.float32)
+
+    def temp_mb(attention):
+        f = jax.jit(jax.grad(
+            lambda q, k, v: (attention(q, k, v).astype(jnp.float32) ** 2)
+            .sum(), argnums=(0, 1, 2)))
+        c = f.lower(q, q, q).compile()
+        return c.memory_analysis().temp_size_in_bytes / 2**20
+
+    dense = temp_mb(lambda q, k, v: _reference_attention(q, k, v, True))
+    for bk in (64, 1024):
+        fused = temp_mb(lambda q, k, v: flash_attention(q, k, v, True, 256,
+                                                        bk))
+        assert fused < dense * 0.4, (bk, fused, dense)

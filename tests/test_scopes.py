@@ -84,6 +84,12 @@ def test_instruction_scopes_and_seconds_by_phase():
         "  %update_fusion.4 = f32[4]{0} fusion(%a), kind=kLoop, "
         "calls=%fused_computation.2, backend_config={}",
         "  %copy.5 = f32[4]{0} copy(%update_fusion.4)",
+        # the compiler's own re-tiling of a result: filed with what made
+        # it, through a chain of them; an asynchronous copy is not
+        "  %copy.10 = f32[4]{0:T(8)} copy(%fusion.1), backend_config={}",
+        "  %copy.11 = f32[4]{0} copy(%copy.10)",
+        "  %copy-start.12 = (f32[4]{0}, f32[4]{0}, u32[]) "
+        "copy-start(%fusion.1)",
         # a root with a phase keeps it, whatever is fused in
         "  %fusion.7 = f32[4]{0} fusion(%a), kind=kOutput, "
         "calls=%fused_computation.1, "
@@ -98,6 +104,9 @@ def test_instruction_scopes_and_seconds_by_phase():
         "add.5": PRE + "add", "dynamic-update-slice.6": "", "a": "args[0]",
         "fusion.1": PRE + "hvd.optimizer/mul;" + PRE + "hvd.optimizer/sub",
         "update_fusion.4": "", "copy.5": "",
+        "copy.10": PRE + "hvd.optimizer/mul;" + PRE + "hvd.optimizer/sub",
+        "copy.11": PRE + "hvd.optimizer/mul;" + PRE + "hvd.optimizer/sub",
+        "copy-start.12": "",
         "fusion.7": PRE + "transpose(jvp(hvd.model/mlp))/dot_general",
         "dot.2": PRE + "jvp(hvd.model/mlp)/dot_general"}
     instructions = {
@@ -204,7 +213,18 @@ def test_a_traced_step_describes_itself(build, compiles):
 
     # one fused float32 buffer, one collective, by hand from the tree
     nbytes = sum(p.size * 4 for p in jax.tree.leaves(params))
-    assert dp.step_counters(step) == {
+    counters = dp.step_counters(step)
+    # the decoder notes where its attention went: off the TPU, never to
+    # the fused kernels (jax.checkpoint traces a block once or several times, so
+    # only the share of the two means anything)
+    attention = {k: counters.pop(k) for k in list(counters)
+                 if k.startswith("attention")}
+    if build is _lm_step:
+        assert attention["attention_calls"] >= 1
+        assert attention["attention_kernel_calls"] == 0
+    else:
+        assert attention == {}
+    assert counters == {
         "collectives": 1, "collective_bytes": nbytes,
         "packed_bytes": nbytes, "axis_size": mesh.devices.size}
 
@@ -232,3 +252,22 @@ def test_unfused_exchange_counts_a_collective_per_leaf():
         "packed_bytes": 0, "axis_size": mesh.devices.size}
     phases = {scopes.phase_of(v) for v in dp.scope_table(step).values()}
     assert {"grad_exchange", "optimizer"} <= phases
+
+
+@pytest.mark.parametrize("routed,want", [
+    ([True, True, True], {"attention_calls": 3, "attention_kernel_calls": 3}),
+    ([False, False], {"attention_calls": 2, "attention_kernel_calls": 0}),
+    ([True, False], {"attention_calls": 2, "attention_kernel_calls": 1}),
+    ([], {}),
+], ids=["kernels", "einsum", "mixed", "no-decoder"])
+def test_attention_counters_note_where_each_call_went(routed, want):
+    """`note_attention` counts into the step being traced, the calls and
+    those of them the fused kernels took (``attention_kernel_pct`` is
+    their share), and is a no-op with no step being traced."""
+    record = scopes.StepRecord()
+    with scopes.recording(record):
+        for kernel in routed:
+            scopes.note_attention(kernel=kernel)
+    assert record.counters == want
+    scopes.note_attention(kernel=True)   # outside a traced step
+    assert record.counters == want

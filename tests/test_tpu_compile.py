@@ -73,6 +73,23 @@ def test_flash_fwd_compiles(topo, s, causal, offset):
     assert "hvd_flash_fwd" in text  # the kernel's stable name in a trace
 
 
+@pytest.mark.parametrize("b,s", [(4, 2048), (16, 512)],
+                         ids=["64x2048x128", "256x512x128"])
+def test_flash_bwd_kernels_compile(topo, b, s):
+    """The decoder's backward at the LM cells' shapes, sixteen heads of
+    128 side by side as its projections write them, at the blocks
+    `block_sizes` gives: both kernels, under their names."""
+    one = SingleDeviceSharding(topo.devices[0])
+    heads = 16
+    x = jax.ShapeDtypeStruct((b, s, heads * D), jnp.bfloat16, sharding=one)
+    lse = jax.ShapeDtypeStruct((b * heads, 1, s), jnp.float32, sharding=one)
+    bq, bk = F.block_sizes(s, D)
+    text = F._flash_bwd.lower(x, x, x, x, x, lse, causal=True, block_q=bq,
+                              block_k=bk, heads=heads).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "hvd_flash_bwd_dq" in text and "hvd_flash_bwd_dkv" in text
+
+
 @pytest.mark.parametrize("offset", [0, 1])
 def test_attention_stats_vjp_compiles(topo, offset):
     """Kernel forward + the blockwise scan_stats backward, with all three
@@ -134,12 +151,15 @@ def test_fused_chunk_plan_compiles_for_64mib_over_four_processes(topo):
     assert [o.shape for o in compiled.out_info] == [(n,), (2, n // 2)]
 
 
-def test_toy_lm_step_carries_every_phase_on_four_chips(topo):
+@pytest.mark.parametrize("seq", [128, 512], ids=["einsum", "kernels"])
+def test_toy_lm_step_carries_every_phase_on_four_chips(topo, seq):
     """A remat'd decoder step through ``DistributedOptimizer`` +
     ``data_parallel_step`` as the chip's compiler leaves it: the scopes
     of utils/scopes.py survive into the entry computation's fusions (the
     instructions a trace's ``XLA Ops`` events are named by), and an
-    instruction's name identifies it within the module."""
+    instruction's name identifies it within the module. At 512
+    positions the decoder's rule takes the fused kernels: their calls
+    carry the attention scope in all three phases."""
     import optax
 
     import horovod_tpu as hvd
@@ -149,7 +169,7 @@ def test_toy_lm_step_carries_every_phase_on_four_chips(topo):
 
     mesh = Mesh(np.array(topo.devices), ("hvd",))
     cfg = T.TransformerConfig(vocab_size=512, d_model=256, n_heads=2,
-                              n_layers=2, d_ff=512, max_seq=128, remat=True)
+                              n_layers=2, d_ff=512, max_seq=seq, remat=True)
     opt = hvd.DistributedOptimizer(optax.adamw(1e-3))
 
     def step(params, opt_state, tokens):
@@ -167,7 +187,7 @@ def test_toy_lm_step_carries_every_phase_on_four_chips(topo):
     step = data_parallel_step(step, mesh=mesh)
     text = step.lower(
         placed(params, P()), placed(jax.eval_shape(opt.init, params), P()),
-        placed(jax.ShapeDtypeStruct((8, 129), jnp.int32), P("hvd"))
+        placed(jax.ShapeDtypeStruct((8, seq + 1), jnp.int32), P("hvd"))
     ).compile().as_text()
     # what the step remembered of its arguments lowers to the same module
     assert dp.scope_table(step) == scopes.instruction_scopes(text)
@@ -180,3 +200,109 @@ def test_toy_lm_step_carries_every_phase_on_four_chips(topo):
               if "fusion" in name}
     assert phases >= {"forward", "backward", "recompute", "optimizer",
                       "grad_exchange"}
+
+    kernels = {(name.split(".")[0], scopes.phase_of(op), scopes.part_of(op))
+               for name, op in entry.items() if name.startswith("hvd_flash")}
+    counters = dp.step_counters(step)
+    assert counters["attention_kernel_calls"] == (
+        counters["attention_calls"] if seq >= T.FUSED_ATTENTION_MIN_SEQ
+        else 0)
+    assert kernels == ({
+        ("hvd_flash_fwd", "forward", scopes.ATTENTION),
+        ("hvd_flash_fwd", "recompute", scopes.ATTENTION),
+        ("hvd_flash_bwd_dq", "backward", scopes.ATTENTION),
+        ("hvd_flash_bwd_dkv", "backward", scopes.ATTENTION),
+    } if seq >= T.FUSED_ATTENTION_MIN_SEQ else set())
+
+
+def test_checkpointed_decoder_traces_and_lowers_each_kernel_once(
+        topo, monkeypatch):
+    """Eight ``jax.checkpoint``-ed blocks under ``value_and_grad``
+    through ``apply``, as the LM cell's reference check runs the loss (a
+    bare ``jit`` on one device): each kernel's body is traced once, and
+    the module carries exactly three distinct ``tpu_custom_call`` bodies
+    under the three kernel names. Every block, the recomputation and both
+    directions call the jitted entries of ops/pallas/flash_attention.py,
+    primal and VJP forward the same one. (The forward's body stands
+    under two ``jit`` wrappers in the text, because JAX's dead-code pass
+    drops ``lse`` from the forward pass's instance; it is one lowering.)
+    Compiled, the kernels' instructions carry the attention scope."""
+    import collections
+    import functools
+    import re
+
+    from horovod_tpu.models import transformer as T
+    from horovod_tpu.utils import scopes
+
+    traced = collections.Counter()
+    for name in ("_flash_fwd_kernel", "_flash_bwd_dq_kernel",
+                 "_flash_bwd_dkv_kernel"):
+        def counting(*args, _body=getattr(F, name), _name=name, **kwargs):
+            traced[_name] += 1
+            return _body(*args, **kwargs)
+
+        monkeypatch.setattr(F, name, counting)
+    monkeypatch.setattr(jax, "device_count", lambda: 1)  # as on the chip
+
+    cfg = T.TransformerConfig(vocab_size=512, d_model=256, n_heads=2,
+                              n_layers=8, d_ff=512, max_seq=512, remat=True)
+    one = SingleDeviceSharding(topo.devices[0])
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+        jax.eval_shape(functools.partial(T.init, cfg=cfg),
+                       jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((2, 513), jnp.int32, sharding=one)
+    lowered = jax.jit(lambda p, t: jax.value_and_grad(T.lm_loss)(
+        p, t, cfg, use_constraints=False)).lower(params, tokens)
+    assert traced == {"_flash_fwd_kernel": 1, "_flash_bwd_dq_kernel": 1,
+                      "_flash_bwd_dkv_kernel": 1}
+
+    calls = re.findall(r"stablehlo\.custom_call @tpu_custom_call\(.*",
+                       lowered.as_text())
+    bodies = {re.search(r'backend_config = "?(.*?)"?, ', c + ", ").group(1)
+              for c in calls}
+    names = collections.Counter(
+        re.search(r'kernel_name = "(\w+)"', c).group(1) for c in calls)
+    assert len(bodies) == 3, (len(bodies), names)
+    assert set(names) == {"hvd_flash_fwd", "hvd_flash_bwd_dq",
+                          "hvd_flash_bwd_dkv"}
+    assert names["hvd_flash_bwd_dq"] == names["hvd_flash_bwd_dkv"] == 1
+
+    kernels = {name: op for name, op in scopes.instruction_scopes(
+        lowered.compile().as_text()).items() if name.startswith("hvd_flash")}
+    assert len(kernels) == 4 * cfg.n_layers   # forward, recomputed, dq, dkv
+    assert all(scopes.part_of(op) == scopes.ATTENTION
+               for op in kernels.values()), kernels
+
+
+def test_fsdp_step_keeps_the_einsum_path_on_four_chips(topo, monkeypatch):
+    """``fsdp_train_step`` is a bare GSPMD ``jit`` over sharded
+    parameters and batch, and its callers pass ``use_constraints=False``:
+    at widths the fused kernels would take (512 positions, heads of 128)
+    the decoder must leave the attention to XLA there — a Mosaic call is
+    not partitioned, and lowering one in that jit raises."""
+    import optax
+
+    from horovod_tpu.models import transformer as T
+    from horovod_tpu.parallel import fsdp_train_step
+
+    mesh = Mesh(np.array(topo.devices), ("dp",))
+    cfg = T.TransformerConfig(vocab_size=512, d_model=256, n_heads=2,
+                              n_layers=2, d_ff=512, max_seq=512, remat=True,
+                              dp_axis=None, tp_axis=None, sp_axis=None)
+    assert T.fused_attention_blocks(512, cfg.head_dim, False) is None
+    opt = optax.adamw(1e-3)
+    # no chip to put the state on: describe it where the factory places it
+    monkeypatch.setattr(jax, "device_put", lambda tree, shardings: jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        tree, shardings))
+    params = jax.eval_shape(lambda key: T.init(key, cfg), jax.random.PRNGKey(0))
+    make = fsdp_train_step(
+        lambda p, batch: T.lm_loss(p, batch, cfg, use_constraints=False),
+        opt, mesh, axis="dp", min_shard_elems=256, batch_spec=P("dp", None))
+    params, opt_state, step = make(params, jax.eval_shape(opt.init, params))
+    assert params["embed"].sharding.spec == P("dp", None)
+    tokens = jax.ShapeDtypeStruct((8, 513), jnp.int32,
+                                  sharding=NamedSharding(mesh, P("dp", None)))
+    text = step.lower(params, opt_state, tokens).compile().as_text()
+    assert "hvd_flash" not in text and "all-gather" in text
