@@ -18,7 +18,7 @@ import numpy as np
 
 import horovod_tpu as hvd
 from horovod_tpu.parallel import create_mesh
-from horovod_tpu.parallel.moe import moe_layer
+from horovod_tpu.parallel.moe import expert_layer, route
 from jax.sharding import PartitionSpec as P
 
 
@@ -29,37 +29,35 @@ def bench_moe_layer(tokens_per_chip: int, d_model: int, n_experts: int,
     rng = np.random.RandomState(0)
     x = jnp.asarray(rng.randn(tokens_per_chip * n, d_model), jnp.bfloat16)
     gate_w = jnp.asarray(rng.randn(d_model, n_experts), jnp.float32)
-    e_local = n_experts // n
-    w1 = jnp.asarray(rng.randn(n_experts, d_model, 4 * d_model) * 0.02,
-                     jnp.bfloat16)
-    w2 = jnp.asarray(rng.randn(n_experts, 4 * d_model, d_model) * 0.02,
-                     jnp.bfloat16)
+    experts = {
+        "gate": jnp.asarray(rng.randn(n_experts, d_model, 2 * d_model) * 0.02,
+                            jnp.bfloat16),
+        "up": jnp.asarray(rng.randn(n_experts, d_model, 2 * d_model) * 0.02,
+                          jnp.bfloat16),
+        "down": jnp.asarray(rng.randn(n_experts, 2 * d_model, d_model) * 0.02,
+                            jnp.bfloat16),
+    }
 
-    def expert_fn(params, xe):
-        a, b = params
-        return jax.nn.gelu(xe @ a) @ b
-
-    def step(x, gate_w, w1, w2):
-        def per_chip(xl, gw, w1l, w2l):
-            y, aux = moe_layer(xl, gw, expert_fn, (w1l, w2l),
-                               axis_name="ep")
-            return y
+    def step(x, gate_w, experts):
+        def per_chip(xl, gw, held):
+            # dropless: two experts a token, wherever on the axis they live
+            chosen, weights = route(xl.astype(jnp.float32) @ gw, 2)
+            return expert_layer(xl, chosen, weights, held, axis_name="ep")
 
         return jax.shard_map(
-            per_chip, mesh=mesh,
-            in_specs=(P("ep"), P(), P("ep"), P("ep")),
-            out_specs=P("ep"), check_vma=False)(x, gate_w, w1, w2)
+            per_chip, mesh=mesh, in_specs=(P("ep"), P(), P("ep")),
+            out_specs=P("ep"), check_vma=False)(x, gate_w, experts)
 
     compiled = jax.jit(step)
-    y = compiled(x, gate_w, w1, w2)
+    y = compiled(x, gate_w, experts)
     jax.block_until_ready(y)
     t0 = time.perf_counter()
     for _ in range(iters):
-        y = compiled(x, gate_w, w1, w2)
+        y = compiled(x, gate_w, experts)
     float(jnp.sum(y))  # value fetch = true sync
     dt = (time.perf_counter() - t0) / iters
     toks = tokens_per_chip * n
-    print(f"moe_layer: {toks / dt:,.0f} tokens/s  ({dt * 1e3:.2f} ms/step, "
+    print(f"expert_layer: {toks / dt:,.0f} tokens/s  ({dt * 1e3:.2f} ms/step, "
           f"{n} chips, {n_experts} experts)")
     return toks / dt
 

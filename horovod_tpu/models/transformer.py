@@ -18,11 +18,27 @@ psum over 'tp'):
   w1:       (d_model, d_ff)               d_ff sharded over 'tp'
   w2:       (d_ff, d_model)               d_ff sharded over 'tp'
   activations: (batch, seq, d_model) — batch over 'dp', seq over 'sp'
+
+One decoder, composed per layer from the configuration. The defaults are
+the GPT-2-style block (learned positions, as many key/value heads as
+query heads, full causal attention, a GELU MLP, the classifier tied to
+the embedding). A configuration may instead give: grouped-query heads
+(``n_kv_heads``) of a width of their own (``d_head``); per layer, by
+patterns that repeat with their period, a sliding ``window`` over the
+keys (``window_layout``) and rotary positions or none at all
+(``rope_layout``; ``positions="layout"`` drops the learned table); a
+sparse gated feed-forward (``n_experts``, ``experts_per_token``,
+``d_expert``: a softmax router over all the experts that reads the
+block's *input*, ReGLU experts, of which this model holds
+``experts_held``: ``parallel/moe.py``); and an untied classifier
+(``tie_embeddings=False``). ``vocab_size`` is the rows held: a sliced
+vocabulary is a smaller vocabulary.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import jax
@@ -54,10 +70,40 @@ class TransformerConfig:
     # (ops/xent.py) instead of materializing float32 logits [tokens,
     # vocab] — the biggest tensor in long-context training. None = dense.
     xent_chunk: Optional[int] = None
+    # -- the block's composition (module docstring); defaults: GPT-2's ----
+    n_kv_heads: Optional[int] = None       # None: n_heads
+    d_head: Optional[int] = None           # None: d_model // n_heads
+    positions: str = "learned"             # or "layout": rope_layout says
+    rope_layout: tuple = ()                # per layer, periodic: 1 = rotary
+    rope_theta: float = 10000.0
+    window: Optional[int] = None           # keys a windowed layer sees
+    window_layout: tuple = ()              # per layer, periodic: 1 = windowed
+    n_experts: int = 0                     # router width; 0: the dense MLP
+    experts_per_token: int = 0
+    d_expert: int = 0
+    experts_held: Optional[tuple] = None   # (first, count); None: all
+    tie_embeddings: bool = True
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.d_head or self.d_model // self.n_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def held(self) -> tuple:
+        """``(first, count)`` of the experts this model holds."""
+        return self.experts_held or (0, self.n_experts)
+
+    def layer_kind(self, i: int) -> tuple:
+        """Layer ``i``'s ``(window or None, rotary positions?)``."""
+        def at(layout):
+            return bool(layout) and bool(layout[i % len(layout)])
+
+        return (self.window if at(self.window_layout) else None,
+                self.positions == "layout" and at(self.rope_layout))
 
 
 def init(rng, cfg: TransformerConfig):
@@ -65,22 +111,35 @@ def init(rng, cfg: TransformerConfig):
     s = 0.02
     params = {
         "embed": s * jax.random.normal(keys[0], (cfg.vocab_size, cfg.d_model), jnp.float32),
-        "pos": s * jax.random.normal(keys[1], (cfg.max_seq, cfg.d_model), jnp.float32),
         "ln_f": {"scale": jnp.ones((cfg.d_model,), jnp.float32)},
         "blocks": [],
     }
+    if cfg.positions == "learned":
+        params["pos"] = s * jax.random.normal(keys[1], (cfg.max_seq, cfg.d_model), jnp.float32)
+    if not cfg.tie_embeddings:
+        params["head"] = s * jax.random.normal(keys[2], (cfg.d_model, cfg.vocab_size), jnp.float32)
+    held = cfg.held[1]
     for i in range(cfg.n_layers):
-        k = jax.random.split(keys[4 + i], 6)
-        params["blocks"].append({
+        k = jax.random.split(keys[4 + i], 10 if cfg.n_experts else 6)
+        blk = {
             "ln1": {"scale": jnp.ones((cfg.d_model,), jnp.float32)},
             "ln2": {"scale": jnp.ones((cfg.d_model,), jnp.float32)},
             "wq": s * jax.random.normal(k[0], (cfg.d_model, cfg.n_heads, cfg.head_dim), jnp.float32),
-            "wk": s * jax.random.normal(k[1], (cfg.d_model, cfg.n_heads, cfg.head_dim), jnp.float32),
-            "wv": s * jax.random.normal(k[2], (cfg.d_model, cfg.n_heads, cfg.head_dim), jnp.float32),
+            "wk": s * jax.random.normal(k[1], (cfg.d_model, cfg.kv_heads, cfg.head_dim), jnp.float32),
+            "wv": s * jax.random.normal(k[2], (cfg.d_model, cfg.kv_heads, cfg.head_dim), jnp.float32),
             "wo": s * jax.random.normal(k[3], (cfg.n_heads, cfg.head_dim, cfg.d_model), jnp.float32),
-            "w1": s * jax.random.normal(k[4], (cfg.d_model, cfg.d_ff), jnp.float32),
-            "w2": s * jax.random.normal(k[5], (cfg.d_ff, cfg.d_model), jnp.float32),
-        })
+        }
+        if cfg.n_experts:
+            blk["router"] = s * jax.random.normal(k[6], (cfg.d_model, cfg.n_experts), jnp.float32)
+            blk["experts"] = {
+                "gate": s * jax.random.normal(k[7], (held, cfg.d_model, cfg.d_expert), jnp.float32),
+                "up": s * jax.random.normal(k[8], (held, cfg.d_model, cfg.d_expert), jnp.float32),
+                "down": s * jax.random.normal(k[9], (held, cfg.d_expert, cfg.d_model), jnp.float32),
+            }
+        else:
+            blk["w1"] = s * jax.random.normal(k[4], (cfg.d_model, cfg.d_ff), jnp.float32)
+            blk["w2"] = s * jax.random.normal(k[5], (cfg.d_ff, cfg.d_model), jnp.float32)
+        params["blocks"].append(blk)
     return params
 
 
@@ -94,15 +153,23 @@ def param_specs(cfg: TransformerConfig):
         "wk": P(None, tp, None),
         "wv": P(None, tp, None),
         "wo": P(tp, None, None),
-        "w1": P(None, tp),
-        "w2": P(tp, None),
     }
-    return {
+    if cfg.n_experts:  # the experts held are replicated: no GSPMD split
+        block["router"] = P(None, None)
+        block["experts"] = {w: P(None, None, None)
+                            for w in ("gate", "up", "down")}
+    else:
+        block.update(w1=P(None, tp), w2=P(tp, None))
+    specs = {
         "embed": P(None, None),
-        "pos": P(None, None),
         "ln_f": {"scale": P()},
         "blocks": [dict(block) for _ in range(cfg.n_layers)],
     }
+    if cfg.positions == "learned":
+        specs["pos"] = P(None, None)
+    if not cfg.tie_embeddings:
+        specs["head"] = P(None, None)
+    return specs
 
 
 def act_spec(cfg: TransformerConfig) -> P:
@@ -123,7 +190,7 @@ def _constrain(x, spec, use_constraints):
 
 def apply(params, tokens, cfg: TransformerConfig, *, use_constraints: bool = True,
           attn_fn=None, positions=None,
-          return_hidden: bool = False):
+          return_hidden: bool = False, return_routing: bool = False):
     """Forward pass → logits (float32), or — with ``return_hidden=True``
     — the pre-projection hidden states [b, s, d] in ``cfg.dtype`` for
     the chunked LM loss (lm_loss with cfg.xent_chunk).
@@ -138,35 +205,65 @@ def apply(params, tokens, cfg: TransformerConfig, *, use_constraints: bool = Tru
     ``positions`` ([s] global position ids) must be supplied when running
     inside a shard_map with the sequence sharded (ring attention): each
     chip's block starts at ``axis_index * s_local``, not 0.
+
+    ``return_routing=True`` (a sparse-expert decoder) returns ``(result,
+    [chosen experts [b*s, k] of each layer])``: what a comparison with a
+    reference needs to tell a tie in the router from a fault.
     """
     aspec = act_spec(cfg)
     if positions is None:
         positions = jnp.arange(tokens.shape[1])
     with jax.named_scope(scopes.EMBED):
         x = params["embed"][tokens].astype(cfg.dtype)
-        x = x + params["pos"][positions].astype(cfg.dtype)[None]
+        if cfg.positions == "learned":
+            x = x + params["pos"][positions].astype(cfg.dtype)[None]
     x = _constrain(x, aspec, use_constraints)
 
     # the fused kernels' tile shape, where the default attention takes them
     blocks = None if attn_fn else fused_attention_blocks(
         tokens.shape[1], cfg.head_dim, use_constraints)
+    kinds = [cfg.layer_kind(i) for i in range(cfg.n_layers)]
+    rotary = (_rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+              if any(rope for _, rope in kinds) else None)
+    if attn_fn is not None and any(window for window, _ in kinds):
+        raise ValueError("attn_fn takes no window: a windowed layer runs "
+                         "the default attention")
 
     # the scopes sit inside the block, so they survive jax.checkpoint
-    def _block(x, blk):
+    def _block(x, blk, window=None, rope=False):
+        if cfg.n_experts:  # the router reads the block's input
+            with jax.named_scope(scopes.ROUTER):
+                routed = _route(x, blk["router"], cfg)
         with jax.named_scope(scopes.ATTENTION):
             h = _rmsnorm(x, blk["ln1"]["scale"])
             if attn_fn is None:
-                scopes.note_attention(kernel=blocks is not None)
+                scopes.note_attention(kernel=blocks is not None,
+                                      window=window is not None)
             if blocks is not None:
-                o = _fused_attention(h, blk, cfg, blocks)
+                o = _fused_attention(h, blk, cfg, blocks, window,
+                                     rotary if rope else None)
             else:
                 q, k, v = (jnp.einsum("bsd,dhk->bshk", h,
                                       blk[w].astype(cfg.dtype))
                            for w in ("wq", "wk", "wv"))
-                o = (attn_fn or causal_attention)(q, k, v)
+                if rope:
+                    q, k = _rope(q, rotary), _rope(k, rotary)
+                if attn_fn is None:
+                    o = causal_attention(q, k, v, window)
+                else:
+                    o = attn_fn(q, *(_repeat_kv(a, cfg) for a in (k, v)))
                 o = jnp.einsum("bshk,hkd->bsd", o, blk["wo"].astype(cfg.dtype))
             x = x + o
         x = _constrain(x, aspec, use_constraints)
+        if cfg.n_experts:
+            with jax.named_scope(scopes.MOE):
+                from ..parallel import moe
+
+                h = _rmsnorm(x, blk["ln2"]["scale"])
+                ff = moe.expert_layer(h.reshape(-1, h.shape[-1]), *routed,
+                                      blk["experts"], cfg.held)
+                x = x + ff.reshape(h.shape)
+            return _constrain(x, aspec, use_constraints), routed[0]
         with jax.named_scope(scopes.MLP):
             h = _rmsnorm(x, blk["ln2"]["scale"])
             ff = jax.nn.gelu(
@@ -175,15 +272,88 @@ def apply(params, tokens, cfg: TransformerConfig, *, use_constraints: bool = Tru
             x = x + ff
         return _constrain(x, aspec, use_constraints)
 
-    block_fn = jax.checkpoint(_block) if cfg.remat else _block
-    for blk in params["blocks"]:
-        x = block_fn(x, blk)
+    # one (checkpointed) function per kind of layer the pattern has
+    block_fns, routing = {}, []
+    for kind, blk in zip(kinds, params["blocks"]):
+        if kind not in block_fns:
+            fn = _block if kind == (None, False) else functools.partial(
+                _block, window=kind[0], rope=kind[1])
+            block_fns[kind] = jax.checkpoint(fn) if cfg.remat else fn
+        x = block_fns[kind](x, blk)
+        if cfg.n_experts:
+            x, chosen = x
+            routing.append(chosen)
+            scopes.note_moe(
+                cfg.held[1], cfg.n_experts, cfg.experts_per_token,
+                x.shape[0] * x.shape[1]
+                * min(cfg.experts_per_token, cfg.held[1]))
     with jax.named_scope(scopes.HEAD):
         x = _rmsnorm(x, params["ln_f"]["scale"])
         if return_hidden:
-            return x  # pre-projection activations for the chunked LM loss
-        return jnp.einsum("bsd,vd->bsv", x.astype(jnp.float32),
-                          params["embed"])
+            out = x  # pre-projection activations for the chunked LM loss
+        elif not cfg.tie_embeddings:
+            out = jnp.einsum("bsd,dv->bsv", x.astype(jnp.float32),
+                             params["head"])
+        else:
+            out = jnp.einsum("bsd,vd->bsv", x.astype(jnp.float32),
+                             params["embed"])
+    return (out, routing) if return_routing else out
+
+
+def _route(x, router, cfg: TransformerConfig):
+    """A sparse-expert block's router on its input [b, s, d]: scores in
+    float32 at the highest matmul precision (on a TPU a float32 product
+    is bfloat16 passes unless told otherwise, and a choice between two
+    near-equal scores should hang on as little rounding as it can), then
+    ``parallel.moe.route``: (chosen, weights), [b*s, k] each."""
+    from ..parallel import moe
+
+    scores = jnp.einsum("td,de->te",
+                        x.reshape(-1, x.shape[-1]).astype(jnp.float32),
+                        router, precision=jax.lax.Precision.HIGHEST)
+    return moe.route(scores, cfg.experts_per_token)
+
+
+def _rope_tables(positions, head_dim: int, theta: float):
+    """(cos, sin), [s, head_dim] float32 each, of rotary positions over
+    the whole head: frequency ``theta ** (-2i / head_dim)`` for the pair
+    ``(i, i + head_dim/2)`` (the rotate-half pairing)."""
+    inv_freq = theta ** (-jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                         / head_dim)
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq[None]
+    angle = jnp.concatenate([angle, angle], axis=-1)
+    return jnp.cos(angle), jnp.sin(angle)
+
+
+def _rope(x, tables):
+    """Rotary positions on [b, s, h, hd] (in float32, back in x's type)."""
+    cos, sin = (t[None, :, None, :] for t in tables)
+    x32 = x.astype(jnp.float32)
+    half = x.shape[-1] // 2
+    turned = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
+    return (x32 * cos + turned * sin).astype(x.dtype)
+
+
+def _rope_side_by_side(x, tables, head_dim: int):
+    """`_rope` on [b, s, h*hd], the heads side by side as the fused
+    kernels take them, without going through [b, s, h, hd]: on a TPU
+    that reshape puts the heads on a tiled dimension of the layout and
+    is a copy of the array each way. A pair's partner is ``hd/2``
+    columns on, or back, within its head: two rolls and a select."""
+    cos, sin = (jnp.tile(t, x.shape[-1] // head_dim)[None] for t in tables)
+    x32 = x.astype(jnp.float32)
+    half = head_dim // 2
+    first = (jnp.arange(x.shape[-1]) % head_dim) < half
+    turned = jnp.where(first, -jnp.roll(x32, -half, axis=-1),
+                       jnp.roll(x32, half, axis=-1))
+    return (x32 * cos + turned * sin).astype(x.dtype)
+
+
+def _repeat_kv(a, cfg: TransformerConfig):
+    """Key/value heads [b, s, kv, hd] as one per query head, for an
+    ``attn_fn`` that knows no grouped heads."""
+    group = cfg.n_heads // cfg.kv_heads
+    return a if group == 1 else jnp.repeat(a, group, axis=2)
 
 
 #: the shortest sequence at which the fused kernels beat the einsum path
@@ -224,7 +394,8 @@ def fused_attention_blocks(s: int, head_dim: int, use_constraints: bool):
     return block_sizes(s, head_dim)
 
 
-def _fused_attention(h, blk, cfg: TransformerConfig, blocks):
+def _fused_attention(h, blk, cfg: TransformerConfig, blocks, window=None,
+                     rotary=None):
     """`causal_attention` between its projections, through the Pallas
     kernels (ops/pallas/flash_attention.py: the scores stay in VMEM,
     forward and backward). The kernels take the heads side by side,
@@ -240,44 +411,65 @@ def _fused_attention(h, blk, cfg: TransformerConfig, blocks):
         jnp.einsum("bsd,de->bse", h,
                    blk[w].astype(cfg.dtype).reshape(cfg.d_model, -1))
         for w in ("wq", "wk", "wv"))
-    o = flash_attention(q, k, v, True, *blocks, blk["wq"].shape[1])
+    if rotary is not None:
+        q, k = (_rope_side_by_side(a, rotary, cfg.head_dim) for a in (q, k))
+    o = flash_attention(q, k, v, True, *blocks, blk["wq"].shape[1], window,
+                        blk["wk"].shape[1])
     return jnp.einsum("bse,ed->bsd", o,
                       blk["wo"].astype(cfg.dtype).reshape(-1, cfg.d_model))
 
 
-def causal_attention(q, k, v):
+def causal_attention(q, k, v, window=None, kv_heads=None):
     """Plain causal attention, [b, s, h, hd] layout, f32 softmax: an
     einsum writes the [b, h, s, s] float32 logits and XLA's softmax
     passes over them. What ``apply`` runs wherever
     `fused_attention_blocks` says None (short or untileable sequences,
     off the TPU, under GSPMD), and the numerics the fused kernels are
-    held to."""
+    held to. With a ``window`` query ``i`` sees the keys ``i - window <
+    j <= i``; ``k`` and ``v`` may have fewer heads than ``q``
+    (``kv_heads``, default: as many as they come with): query head ``h``
+    reads key/value head ``h // (heads // kv_heads)``."""
+    kv_heads = kv_heads or k.shape[2]
+    if kv_heads != k.shape[2] or q.shape[2] % kv_heads:
+        raise ValueError(f"{q.shape[2]} query heads over {kv_heads} "
+                         f"key/value heads, but k has {k.shape[2]}")
+    if kv_heads != q.shape[2]:
+        k, v = (jnp.repeat(a, q.shape[2] // kv_heads, axis=2)
+                for a in (k, v))
     scale = q.shape[-1] ** -0.5
     logits = jnp.einsum("bshk,bthk->bhst", q, k).astype(jnp.float32) * scale
     s, t = logits.shape[-2], logits.shape[-1]
     mask = jnp.tril(jnp.ones((s, t), bool))
+    if window is not None:
+        mask = mask & ~jnp.tril(jnp.ones((s, t), bool), k=-window)
     logits = jnp.where(mask, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhst,bthk->bshk", probs, v)
 
 
-def lm_loss(params, tokens, cfg: TransformerConfig, **kw):
+def lm_loss(params, tokens, cfg: TransformerConfig, *,
+            return_routing: bool = False, **kw):
     """Next-token cross-entropy (mean over tokens).
 
     With ``cfg.xent_chunk`` set, the classifier streams over vocab
     chunks (ops/xent.py chunked_softmax_xent) and float32 logits
-    [tokens, vocab] are never materialized."""
+    [tokens, vocab] are never materialized. ``return_routing=True``
+    returns ``(loss, routing)`` with `apply`'s routing (``has_aux``)."""
     targets = tokens[:, 1:]
-    if cfg.xent_chunk:
-        from ..ops.xent import chunked_softmax_xent
-
-        h = apply(params, tokens[:, :-1], cfg, return_hidden=True, **kw)
-        b, s, d = h.shape
-        with jax.named_scope(scopes.HEAD):
-            return chunked_softmax_xent(h.reshape(b * s, d), params["embed"],
-                                        targets.reshape(-1), cfg.xent_chunk)
-    logits = apply(params, tokens[:, :-1], cfg, **kw)
+    out = apply(params, tokens[:, :-1], cfg, return_hidden=bool(cfg.xent_chunk),
+                return_routing=return_routing, **kw)
+    out, routing = out if return_routing else (out, None)
     with jax.named_scope(scopes.HEAD):
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        return -jnp.mean(ll)
+        if cfg.xent_chunk:
+            from ..ops.xent import chunked_softmax_xent
+
+            b, s, d = out.shape
+            w = params["embed"] if cfg.tie_embeddings else params["head"].T
+            loss = chunked_softmax_xent(out.reshape(b * s, d), w,
+                                        targets.reshape(-1), cfg.xent_chunk)
+        else:
+            logp = jax.nn.log_softmax(out, axis=-1)
+            ll = jnp.take_along_axis(logp, targets[..., None],
+                                     axis=-1)[..., 0]
+            loss = -jnp.mean(ll)
+    return (loss, routing) if return_routing else loss

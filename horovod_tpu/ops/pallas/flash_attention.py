@@ -119,11 +119,12 @@ _NN = (((1,), (0,)), ((), ()))   # a @ b
 
 
 def _on_visible_tiles(block, causal: bool, q_block, k_block, block_q: int,
-                      block_k: int, causal_offset: int = 0):
+                      block_k: int, causal_offset: int = 0, window=None):
     """Run ``block(masked)`` on the score tile of Q block ``q_block`` and
-    K block ``k_block`` as the mask ``row >= col + causal_offset`` leaves
-    it: not at all where the mask empties it, with the mask where the
-    diagonal cuts it, else plain."""
+    K block ``k_block`` as the mask ``row >= col + causal_offset`` (and,
+    with a ``window``, ``row - col < window``) leaves it: not at all
+    where the mask empties it, with the mask where the diagonal or the
+    window's far edge cuts it, else plain."""
     if not causal:
         block(False)
         return
@@ -131,21 +132,32 @@ def _on_visible_tiles(block, causal: bool, q_block, k_block, block_q: int,
     visible = lax.lt(first_col, lax.mul(lax.add(q_block, 1), block_q))
     cut = lax.lt(lax.mul(q_block, block_q),
                  lax.add(first_col, block_k - 1))
+    if window is not None:
+        # the tile's smallest and largest ``row - col``
+        nearest = lax.sub(lax.mul(q_block, block_q),
+                          lax.add(first_col, block_k - 1))
+        visible = lax.bitwise_and(visible, lax.lt(nearest, window))
+        cut = lax.bitwise_or(cut, lax.ge(
+            lax.add(nearest, block_q + block_k - 2), window))
     pl.when(lax.bitwise_and(visible, cut))(lambda: block(True))
     pl.when(lax.bitwise_and(visible, lax.bitwise_not(cut)))(
         lambda: block(False))
 
 
 def _causal(s, q_block, k_block, block_q: int, block_k: int,
-            causal_offset: int = 0, q_axis: int = 0):
+            causal_offset: int = 0, q_axis: int = 0, window=None):
     """The score tile ``s`` of Q block ``q_block`` and K block
-    ``k_block`` with NEG_INF wherever ``row < col + causal_offset``; Q
-    rows run along ``q_axis`` of the tile."""
+    ``k_block`` with NEG_INF wherever ``row < col + causal_offset`` or,
+    with a ``window``, ``row - col >= window``; Q rows run along
+    ``q_axis`` of the tile."""
     rows = lax.add(lax.mul(q_block, block_q),
                    lax.broadcasted_iota(jnp.int32, s.shape, q_axis))
     cols = lax.add(lax.add(lax.mul(k_block, block_k), causal_offset),
                    lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis))
-    return lax.select(lax.ge(rows, cols), s, lax.full_like(s, NEG_INF))
+    keep = lax.ge(rows, cols)
+    if window is not None:
+        keep = lax.bitwise_and(keep, lax.lt(lax.sub(rows, cols), window))
+    return lax.select(keep, s, lax.full_like(s, NEG_INF))
 
 
 def _zeros(ref):
@@ -188,7 +200,7 @@ def _as_col(row):
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                       acc_scr, m_scr, l_scr, *, scale: float, causal: bool,
                       causal_offset: int, block_q: int, block_k: int,
-                      num_k_blocks: int):
+                      num_k_blocks: int, window=None):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -204,7 +216,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         if masked:
             # causal_offset=0: standard (row >= col); =1: STRICT (row > col)
             # — striped ring attention's j>i rounds exclude the diagonal
-            s = _causal(s, qi, ki, block_q, block_k, causal_offset)
+            s = _causal(s, qi, ki, block_q, block_k, causal_offset,
+                        window=window)
         # running stats stay [bq, 1] columns (one per score row): the
         # TPU has no 1-D vector layout
         m_prev = m_scr[...]
@@ -219,7 +232,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         m_scr[...] = m_new
 
     _on_visible_tiles(_block, causal, qi, ki, block_q, block_k,
-                      causal_offset)
+                      causal_offset, window)
 
     @pl.when(lax.eq(ki, num_k_blocks - 1))
     def _finalize():
@@ -267,6 +280,17 @@ def block_sizes(s: int, head_dim: int):
     return None
 
 
+def _kv_heads(heads: int, kv_heads, q_width: int, k_width: int) -> int:
+    """``kv_heads`` (None: as many as ``heads``), checked against the
+    arrays' widths."""
+    kv_heads = heads if kv_heads is None else kv_heads
+    if heads % kv_heads or q_width * kv_heads != k_width * heads:
+        raise ValueError(
+            f"{heads} query heads over {kv_heads} key/value heads need q "
+            f"and k/v widths in that ratio, not {q_width} and {k_width}")
+    return kv_heads
+
+
 def _blocks(sq: int, sk: int, block_q: int, block_k: int, d: int,
             heads: int):
     """The blocks as the kernels take them (no longer than the
@@ -294,32 +318,62 @@ _GRID_SEMANTICS = pltpu.CompilerParams(dimension_semantics=(
     "parallel", "parallel", "parallel", "arbitrary"))
 
 
-def _specs(bq: int, bk: int, d: int, heads: int, q_block, k_block):
+def _specs(bq: int, bk: int, d: int, heads: int, q_block, k_block,
+           q_head=None, kv_head=None):
     """BlockSpecs over a grid ``(b, head, x, y)``: one head's ``d``
     columns of a block of rows of a [b, s, heads*d] array, for Q-like and
     K-like arrays, and a block of a [b*heads, 1, sq] row statistic.
-    ``q_block(x, y)`` and ``k_block(x, y)`` give the row blocks.
+    ``q_block(x, y)`` and ``k_block(x, y)`` give the row blocks. With
+    grouped-query heads the grid's head is not every array's:
+    ``q_head(h, x, y)`` and ``kv_head(h, x, y)`` give the Q-like and the
+    K-like arrays' (default: ``h`` for both).
 
     The statistics are [.., 1, sq] because a (1, 1, bq) block is legal
     on TPU (second-to-last dim = the whole array's, last a lane
     multiple) and a (1, bq) block of [.., sq] is not."""
+    q_head = q_head or (lambda h, x, y: h)
+    kv_head = kv_head or (lambda h, x, y: h)
     return (
-        pl.BlockSpec((1, bq, d), lambda b, h, x, y: (b, q_block(x, y), h)),
-        pl.BlockSpec((1, bk, d), lambda b, h, x, y: (b, k_block(x, y), h)),
+        pl.BlockSpec((1, bq, d), lambda b, h, x, y: (
+            b, q_block(x, y), q_head(h, x, y))),
+        pl.BlockSpec((1, bk, d), lambda b, h, x, y: (
+            b, k_block(x, y), kv_head(h, x, y))),
         pl.BlockSpec((1, 1, bq), lambda b, h, x, y: (
-            lax.add(lax.mul(b, heads), h), 0, q_block(x, y))))
+            lax.add(lax.mul(b, heads), q_head(h, x, y)), 0, q_block(x, y))))
 
 
 def _visible_k_block(causal: bool, bq: int, bk: int, causal_offset: int,
-                     i, j):
+                     i, j, window=None):
     """K block ``j`` of Q block ``i``'s row of tiles, or, where the mask
-    empties that tile, the last one it leaves: a tile that does nothing
-    holds its neighbour's block index and asks for no DMA either."""
+    empties that tile, the nearest one it leaves: a tile that does
+    nothing holds its neighbour's block index and asks for no DMA
+    either."""
     if causal:
         last = lax.div(lax.sub(lax.mul(lax.add(i, 1), bq),
                                causal_offset + 1), bk)
         j = lax.min(j, lax.max(last, 0))
+    if window is not None:
+        # the block of the oldest key the block's first row still sees
+        first = lax.div(lax.max(lax.sub(lax.mul(i, bq), window - 1), 0), bk)
+        j = lax.max(j, first)
     return j
+
+
+def _kv_head_of(heads: int, kv_heads: int):
+    """`_specs`' ``kv_head`` for ``heads`` query heads over ``kv_heads``
+    key/value heads: query head ``h`` reads head ``h // (heads //
+    kv_heads)``; None (the identity) where they are as many."""
+    if kv_heads == heads:
+        return None
+    group = heads // kv_heads
+    return lambda h, x, y: lax.div(h, group)
+
+
+def _kernel_name(stem: str, window) -> str:
+    """A trace's kernel events are found by it; the windowed kernels
+    carry the window in theirs, so that a reader tells them from the
+    global ones and knows which tiles they computed."""
+    return stem if window is None else f"{stem}_w{window}"
 
 
 def _vma(*arrays):
@@ -329,22 +383,25 @@ def _vma(*arrays):
 
 
 def _fwd_call(q, k, v, causal: bool, block_q: int, block_k: int,
-              causal_offset: int, heads: int):
+              causal_offset: int, heads: int, window=None, kv_heads=None):
     """The forward kernel's call: q [B, sq, heads*d], k/v [B, sk,
-    heads*d] -> (o [B, sq, heads*d], m, l [B*heads, 1, sq] float32)."""
+    kv_heads*d] -> (o [B, sq, heads*d], m, l [B*heads, 1, sq] float32)."""
     B, sq, width = q.shape
     sk, d = k.shape[1], width // heads
+    kv_heads = _kv_heads(heads, kv_heads, width, k.shape[2])
     bq, bk, interpret = _blocks(sq, sk, block_q, block_k, d, heads)
     nq, nk = sq // bq, sk // bk
+    static = {} if window is None else {"window": window}
     kernel = functools.partial(
         _flash_fwd_kernel, scale=d ** -0.5, causal=causal,
         causal_offset=causal_offset, block_q=bq, block_k=bk,
-        num_k_blocks=nk)
+        num_k_blocks=nk, **static)
     vma = _vma(q, k, v)
     k_block = functools.partial(_visible_k_block, causal, bq, bk,
-                                causal_offset)
+                                causal_offset, **static)
     q_spec, k_spec, row_spec = _specs(bq, bk, d, heads, lambda i, j: i,
-                                      k_block)
+                                      k_block,
+                                      kv_head=_kv_head_of(heads, kv_heads))
     row = jax.ShapeDtypeStruct((B * heads, 1, sq), jnp.float32, vma=vma)
     return pl.pallas_call(
         kernel,
@@ -358,7 +415,7 @@ def _fwd_call(q, k, v, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         compiler_params=_GRID_SEMANTICS, interpret=interpret,
-        name="hvd_flash_fwd",  # a trace's kernel events are found by it
+        name=_kernel_name("hvd_flash_fwd", window),
     )(q, k, v)
 
 
@@ -381,16 +438,17 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
-                                             "heads"))
+                                             "heads", "window", "kv_heads"))
 def _flash_fwd_lse(q, k, v, causal: bool, block_q: int, block_k: int,
-                   heads: int = 1):
+                   heads: int = 1, window=None, kv_heads=None):
     """`flash_attention`'s forward, for its primal and for its VJP alike:
     (o, lse [B*heads, 1, sq]) with ``lse = m + log l``, all the backward
     kernels need of the softmax (a row the mask empties, l == 0, cannot
     occur at causal_offset 0). One jitted entry for both, so a program
     traces and lowers the forward kernel once however many blocks call
     it, differentiated, recomputed or plain."""
-    o, m, l = _fwd_call(q, k, v, causal, block_q, block_k, 0, heads)
+    o, m, l = _fwd_call(q, k, v, causal, block_q, block_k, 0, heads, window,
+                        kv_heads)
     return o, lax.add(m, lax.log(l))
 
 
@@ -416,13 +474,23 @@ def _pinned_mesh():
     return jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh())
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
-                    block_k: int = 512, heads: int = 1):
-    """Fused attention: q [B, sq, heads*d] × k/v [B, sk, heads*d] →
-    [B, sq, heads*d], each head ``d`` adjacent columns."""
+                    block_k: int = 512, heads: int = 1, window=None,
+                    kv_heads=None):
+    """Fused attention: q [B, sq, heads*d] × k/v [B, sk, kv_heads*d] →
+    [B, sq, heads*d], each head ``d`` adjacent columns.
+
+    ``window`` (causal only): query ``i`` sees the keys ``i - window < j
+    <= i``; the tiles wholly older than that are skipped like those
+    above the diagonal. ``kv_heads`` (default ``heads``): grouped-query
+    heads, query head ``h`` reads key/value head ``h // (heads //
+    kv_heads)``, and the dK/dV kernel sums over a head's queries."""
+    if window is not None and not causal:
+        raise ValueError("a window needs causal=True")
     with _pinned_mesh():
-        return _flash_fwd_lse(q, k, v, causal, block_q, block_k, heads)[0]
+        return _flash_fwd_lse(q, k, v, causal, block_q, block_k, heads,
+                              window, kv_heads)[0]
 
 
 def flash_attention_stats(q, k, v, causal: bool = True, block_q: int = 512,
@@ -532,7 +600,7 @@ attention_stats.defvjp(_stats_fwd, _stats_bwd)
 
 
 def _p_and_ds(q, k, v, do, lse, delta, qi, ki, *, scale: float, masked: bool,
-              block_q: int, block_k: int, transposed: bool):
+              block_q: int, block_k: int, transposed: bool, window=None):
     """One tile of the backward pass, recomputed from the forward's
     ``lse``: ``p = exp(s - lse)`` and ``ds = p * (dp - delta)`` (without
     the ``scale`` factor, which the caller applies once to its sum), both
@@ -542,7 +610,8 @@ def _p_and_ds(q, k, v, do, lse, delta, qi, ki, *, scale: float, masked: bool,
     a, b, c, e = (k, q, v, do) if transposed else (q, k, do, v)
     s = lax.mul(_dot(a, b, _NT), scale)
     if masked:
-        s = _causal(s, qi, ki, block_q, block_k, q_axis=int(transposed))
+        s = _causal(s, qi, ki, block_q, block_k, q_axis=int(transposed),
+                    window=window)
     p = lax.exp(lax.sub(s, lse))
     return p, lax.mul(p, lax.sub(_dot(c, e, _NT), delta))
 
@@ -550,11 +619,14 @@ def _p_and_ds(q, k, v, do, lse, delta, qi, ki, *, scale: float, masked: bool,
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_scr, dv_scr, *, scale: float,
                           causal: bool, block_q: int, block_k: int,
-                          num_q_blocks: int):
+                          num_q_blocks: int, window=None, group: int = 1):
     ki = pl.program_id(2)
-    qi = pl.program_id(3)
+    # the inner axis walks the Q blocks of each of the ``group`` query
+    # heads that read this key/value head, one head after the other
+    step = pl.program_id(3)
+    qi = step if group == 1 else lax.rem(step, num_q_blocks)
 
-    @pl.when(lax.eq(qi, 0))
+    @pl.when(lax.eq(step, 0))
     def _init():
         dk_scr[...] = _zeros(dk_scr)
         dv_scr[...] = _zeros(dv_scr)
@@ -567,15 +639,16 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         pt, dst = _p_and_ds(
             q, k_ref[0], v_ref[0], do, lse_ref[0], delta_ref[0], qi, ki,
             scale=scale, masked=masked, block_q=block_q, block_k=block_k,
-            transposed=True)
+            transposed=True, **({} if window is None else {"window": window}))
         dv_scr[...] = lax.add(dv_scr[...], _dot(
             lax.convert_element_type(pt, do.dtype), do, _NN))
         dk_scr[...] = lax.add(dk_scr[...], _dot(
             lax.convert_element_type(dst, q.dtype), q, _NN))
 
-    _on_visible_tiles(_block, causal, qi, ki, block_q, block_k)
+    _on_visible_tiles(_block, causal, qi, ki, block_q, block_k,
+                      window=window)
 
-    @pl.when(lax.eq(qi, num_q_blocks - 1))
+    @pl.when(lax.eq(step, group * num_q_blocks - 1))
     def _finalize():
         dk_ref[0] = lax.convert_element_type(lax.mul(dk_scr[...], scale),
                                              dk_ref.dtype)
@@ -585,7 +658,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                          dq_ref, delta_ref, dq_scr, lse_scr, delta_scr, *,
                          scale: float, causal: bool, block_q: int,
-                         block_k: int, num_k_blocks: int):
+                         block_k: int, num_k_blocks: int, window=None):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -606,11 +679,13 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
         _, ds = _p_and_ds(
             q_ref[0], k, v_ref[0], do_ref[0], lse_scr[...], delta_scr[...],
             qi, ki, scale=scale, masked=masked, block_q=block_q,
-            block_k=block_k, transposed=False)
+            block_k=block_k, transposed=False,
+            **({} if window is None else {"window": window}))
         dq_scr[...] = lax.add(dq_scr[...], _dot(
             lax.convert_element_type(ds, k.dtype), k, _NN))
 
-    _on_visible_tiles(_block, causal, qi, ki, block_q, block_k)
+    _on_visible_tiles(_block, causal, qi, ki, block_q, block_k,
+                      window=window)
 
     @pl.when(lax.eq(ki, num_k_blocks - 1))
     def _finalize():
@@ -619,32 +694,46 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
-                                             "heads"))
+                                             "heads", "window", "kv_heads"))
 def _flash_bwd(q, k, v, do, o, lse, causal: bool, block_q: int,
-               block_k: int, heads: int = 1):
-    """q/do/o: [B, sq, heads*d], k/v: [B, sk, heads*d], lse: [B*heads,
-    1, sq] float32 → (dq, dk, dv): the VJP of `flash_attention` as two
-    kernels. The dQ kernel runs first: it has ``do`` and ``o`` of a Q
-    block together, so ``delta = rowsum(do * o)`` is made there and
-    handed to the dK/dV kernel."""
+               block_k: int, heads: int = 1, window=None, kv_heads=None):
+    """q/do/o: [B, sq, heads*d], k/v: [B, sk, kv_heads*d], lse:
+    [B*heads, 1, sq] float32 → (dq, dk, dv): the VJP of
+    `flash_attention` as two kernels. The dQ kernel runs first: it has
+    ``do`` and ``o`` of a Q block together, so ``delta = rowsum(do * o)``
+    is made there and handed to the dK/dV kernel. With grouped-query
+    heads the dK/dV kernel's grid runs over the key/value heads, and
+    its inner axis over every Q block of every query head of the group:
+    the sum over a head's queries is made in its scratch."""
     B, sq, width = q.shape
     sk, d = k.shape[1], width // heads
+    kv_heads = _kv_heads(heads, kv_heads, width, k.shape[2])
+    group = heads // kv_heads
     bq, bk, interpret = _blocks(sq, sk, block_q, block_k, d, heads)
     nq, nk = sq // bq, sk // bk
-    static = dict(scale=d ** -0.5, causal=causal, block_q=bq, block_k=bk)
+    windowed = {} if window is None else {"window": window}
+    static = dict(scale=d ** -0.5, causal=causal, block_q=bq, block_k=bk,
+                  **windowed)
     vma = _vma(q, k, v, do)
     params = dict(compiler_params=_GRID_SEMANTICS, interpret=interpret)
 
     # as in the forward, a tile the mask empties holds the block index
     # of the nearest visible one
     def q_block(j, i):   # dkv: Q blocks above K block j's diagonal
+        if group > 1:
+            i = lax.rem(i, nq)
         if causal:
             i = lax.min(lax.max(i, lax.div(lax.mul(j, bk), bq)), nq - 1)
+        if window is not None:
+            # the block of the last query that still sees this K block
+            i = lax.min(i, lax.div(
+                lax.add(lax.mul(lax.add(j, 1), bk), window - 2), bq))
         return i
 
     q_spec, k_spec, row_spec = _specs(
         bq, bk, d, heads, lambda i, j: i,
-        functools.partial(_visible_k_block, causal, bq, bk, 0))
+        functools.partial(_visible_k_block, causal, bq, bk, 0, **windowed),
+        kv_head=_kv_head_of(heads, kv_heads))
     row = jax.ShapeDtypeStruct(lse.shape, jnp.float32, vma=vma)
     dq, delta = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, num_k_blocks=nk, **static),
@@ -657,13 +746,19 @@ def _flash_bwd(q, k, v, do, o, lse, causal: bool, block_q: int,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        name="hvd_flash_bwd_dq", **params,
+        name=_kernel_name("hvd_flash_bwd_dq", window), **params,
     )(q, k, v, do, o, lse)
+    grouped = {} if group == 1 else dict(
+        group=group,
+        # grid head = the key/value head; the inner step's query head
+        q_head=lambda h, j, i: lax.add(lax.mul(h, group), lax.div(i, nq)))
     q_spec, k_spec, row_spec = _specs(bq, bk, d, heads, q_block,
-                                      lambda j, i: j)
+                                      lambda j, i: j,
+                                      q_head=grouped.pop("q_head", None))
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, num_q_blocks=nq, **static),
-        grid=(B, heads, nk, nq),
+        functools.partial(_flash_bwd_dkv_kernel, num_q_blocks=nq, **static,
+                          **grouped),
+        grid=(B, kv_heads, nk, group * nq),
         in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
         out_specs=[k_spec, k_spec],
         out_shape=[
@@ -674,22 +769,23 @@ def _flash_bwd(q, k, v, do, o, lse, causal: bool, block_q: int,
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
-        name="hvd_flash_bwd_dkv", **params,
+        name=_kernel_name("hvd_flash_bwd_dkv", window), **params,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
-def _fwd(q, k, v, causal, block_q, block_k, heads):
+def _fwd(q, k, v, causal, block_q, block_k, heads, window, kv_heads):
     with _pinned_mesh():
-        o, lse = _flash_fwd_lse(q, k, v, causal, block_q, block_k, heads)
+        o, lse = _flash_fwd_lse(q, k, v, causal, block_q, block_k, heads,
+                                window, kv_heads)
     return o, (q, k, v, o, lse)
 
 
-def _bwd(causal, block_q, block_k, heads, res, do):
+def _bwd(causal, block_q, block_k, heads, window, kv_heads, res, do):
     q, k, v, o, lse = res
     with jax.named_scope(scopes.ATTENTION):
         return _flash_bwd(q, k, v, do, o, lse, causal, block_q, block_k,
-                          heads)
+                          heads, window, kv_heads)
 
 
 flash_attention.defvjp(_fwd, _bwd)

@@ -10,5 +10,21 @@ from .sp import (  # noqa: F401
     unstripe_tokens,
 )
 from .pp import pipeline_apply, pipeline_loss  # noqa: F401
-from .moe import moe_layer, top1_gating  # noqa: F401
 from .fsdp import fsdp_specs, opt_state_specs, fsdp_train_step  # noqa: F401
+
+#: the sparse-expert layer resolves on first access (PEP 562): a job
+#: without experts does not import it (tests/test_lazy_imports.py)
+_LAZY = ("moe", "route", "expert_layer")
+
+
+def __getattr__(name: str):
+    if name not in _LAZY:
+        raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+    import importlib
+
+    module = importlib.import_module(f"{__name__}.moe")
+    return module if name == "moe" else getattr(module, name)
+
+
+def __dir__():
+    return sorted({*globals(), *_LAZY})

@@ -1,143 +1,243 @@
-"""Expert parallelism (MoE) over the 'ep' mesh axis.
+"""Sparse experts: one dropless router and an expert layer that is told
+which experts it holds.
 
 The reference supports this only as a primitive — alltoall with uneven
 splits + received_splits (SURVEY.md §2.3, operations.cc:1131-1193). Here
-the full layer is provided: top-k gating with capacity, a dual
-``all_to_all`` dispatch/combine (the MoE hot path on ICI), and the uneven
-split problem solved the XLA way — capacity padding, since compiled
-programs need static shapes (SURVEY.md §7 hard part 6).
+the layer is whole, around two functions:
 
-Layout: inside shard_map over 'ep', each chip hosts
-``n_experts_total / ep`` experts and a token shard [t_local, d].
+- `route` picks each token's ``k`` experts of all the router scores and
+  normalises their weights over the ``k`` chosen.
+- `expert_layer` computes, for the experts it ``held``, their part of
+  ``sum_e w_e * E_e(u)``. No token is dropped: the (token, expert) pairs
+  are sorted by expert, the pairs of held experts come first, and one
+  grouped matmul per weight (``jax.lax.ragged_dot``: on a TPU XLA's own
+  grouped-matmul kernel, whose work follows the rows really routed)
+  runs over a buffer sized for the bound ``tokens * min(k, held)``. What
+  the experts held elsewhere would add is left out, here and in whatever
+  this is compared with.
+
+On one chip nothing is exchanged. With ``axis_name`` (inside a
+``shard_map`` over the expert-parallel axis, each chip holding
+``experts / axis_size`` of them and a shard of the tokens) the pairs go
+to their experts' chips and back through two ``all_to_all``s sized for
+the same bound; compiled programs need static shapes, so the uneven
+split is padding, never a dropped token (SURVEY.md §7 hard part 6).
+
+Both directions of every row movement are gathers: the transpose of a
+gather is a scatter-add, which a TPU serialises row by row, but the
+sort that made the gather's indices also gives the indices of its
+inverse (`_take_rows`).
 """
 
 from __future__ import annotations
-
-from typing import Callable
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-
-def top1_gating(gate_logits, n_experts: int, capacity: int):
-    """Switch-style top-1 gating with per-expert capacity.
-
-    Returns (dispatch [t, e, c] one-hot, combine [t, e, c] weights,
-    aux_loss) — the standard load-balancing auxiliary loss.
-    """
-    probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
-    expert = jnp.argmax(probs, axis=-1)  # [t]
-    gate = jnp.take_along_axis(probs, expert[:, None], axis=-1)[:, 0]
-    onehot = jax.nn.one_hot(expert, n_experts, dtype=jnp.float32)  # [t, e]
-    # position of each token within its expert's queue
-    pos = jnp.cumsum(onehot, axis=0) * onehot - 1.0  # [t, e], -1 where not routed
-    in_cap = (pos < capacity) & (pos >= 0)
-    pos = jnp.where(in_cap, pos, 0.0)
-    cap_onehot = jax.nn.one_hot(pos.astype(jnp.int32), capacity,
-                                dtype=jnp.float32) * in_cap[..., None]
-    dispatch = onehot[..., None] * cap_onehot  # [t, e, c]
-    combine = dispatch * gate[:, None, None]
-    # load-balancing loss (Switch Transformer eq. 4)
-    density = jnp.mean(onehot, axis=0)
-    density_proxy = jnp.mean(probs, axis=0)
-    aux = jnp.sum(density * density_proxy) * n_experts
-    return dispatch, combine, aux
+from ..utils import scopes
 
 
-def topk_gating(gate_logits, n_experts: int, capacity: int, k: int = 2,
-                normalize: bool = True):
-    """GShard-style top-k gating with per-expert capacity.
-
-    Picks experts greedily (k rounds of masked argmax); each pick's queue
-    position accounts for slots consumed by earlier picks. With
-    ``normalize`` the k gate values are renormalized to sum to 1 per
-    token (GShard top-2 convention). Returns (dispatch [t,e,c],
-    combine [t,e,c], aux_loss) like ``top1_gating``.
-    """
-    probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
-    t = probs.shape[0]
-    remaining = probs
-    used = jnp.zeros((1, n_experts), jnp.float32)
-    dispatch = jnp.zeros((t, n_experts, capacity), jnp.float32)
-    gates_raw = jnp.zeros((t, n_experts, capacity), jnp.float32)
-    first_onehot = None
-    for _ in range(k):
-        expert = jnp.argmax(remaining, axis=-1)
-        onehot = jax.nn.one_hot(expert, n_experts, dtype=jnp.float32)
-        if first_onehot is None:
-            first_onehot = onehot
-        gate = jnp.take_along_axis(probs, expert[:, None], axis=-1)[:, 0]
-        pos = jnp.cumsum(onehot, axis=0) * onehot - 1.0 + used * onehot
-        in_cap = (pos < capacity) & (pos >= 0) & (onehot > 0)
-        pos = jnp.where(in_cap, pos, 0.0)
-        cap_onehot = jax.nn.one_hot(pos.astype(jnp.int32), capacity,
-                                    dtype=jnp.float32) * in_cap[..., None]
-        d_i = onehot[..., None] * cap_onehot
-        dispatch = dispatch + d_i
-        gates_raw = gates_raw + d_i * gate[:, None, None]
-        used = used + jnp.sum(onehot, axis=0, keepdims=True)
-        remaining = remaining * (1.0 - onehot)
-    if normalize:
-        # renormalize over the *dispatched* picks only (GShard top-2)
-        denom = jnp.sum(gates_raw, axis=(1, 2), keepdims=True)
-        combine = gates_raw / jnp.maximum(denom, 1e-9)
-    else:
-        combine = gates_raw
-    # load-balancing aux on the first pick (Switch eq. 4 over top-1 routes)
-    density = jnp.mean(first_onehot, axis=0)
-    density_proxy = jnp.mean(probs, axis=0)
-    aux = jnp.sum(density * density_proxy) * n_experts
-    return dispatch, combine, aux
+def route(logits, k: int):
+    """The ``k`` largest of each token's router scores and their
+    weights: ``logits`` [t, e] → (``chosen`` [t, k] int32, ``weights``
+    [t, k] float32), ``weights = exp(r) / sum over the k chosen of
+    exp(r)``, which is a softmax over all ``e`` renormalised over the
+    chosen. In float32 whatever comes in: a choice between near-equal
+    scores should not hang on the activations' precision more than it
+    must."""
+    top, chosen = lax.top_k(logits.astype(jnp.float32), k)
+    return chosen.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
 
 
-def moe_layer(x, gate_w, expert_fn: Callable, expert_params, *,
-              axis_name: str = "ep", capacity_factor: float = 1.25,
-              k: int = 1):
-    """Expert-parallel MoE layer (per-chip view inside shard_map).
+@jax.custom_vjp
+def _take_rows(x, idx, back_idx, back_mask):
+    """``x[idx]`` ([n, d] → [len(idx), d]) whose transpose is a gather
+    too: row ``i`` of ``x`` is read by the result's rows ``back_idx[i]``
+    where ``back_mask[i]`` ([n, m] each), and by no other."""
+    return x.at[idx].get(mode="promise_in_bounds")
+
+
+def _take_rows_fwd(x, idx, back_idx, back_mask):
+    return _take_rows(x, idx, back_idx, back_mask), (back_idx, back_mask)
+
+
+def _take_rows_bwd(res, g):
+    back_idx, back_mask = res
+    n, m = back_idx.shape
+    # a custom VJP's backward pass is traced outside the scope its call
+    # stood in: name it again, or a trace files it under nothing
+    with jax.named_scope(scopes.MOE):
+        # one gather, reader-major, then reader by reader in slices (no
+        # reshape to [m, n, d]: see `_choice`)
+        rows = g.at[back_idx.T.reshape(-1)].get(mode="promise_in_bounds")
+        dx = sum(jnp.where(back_mask[:, j, None],
+                           _choice(rows, n, j).astype(jnp.float32), 0.0)
+                 for j in range(m))
+        return dx.astype(g.dtype), None, None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def _experts_on_pairs(x, source, key, readers, params, rows: int):
+    """Each pair's expert applied to its row: ``x`` [n, d]; pair ``p``
+    takes row ``source[p]`` to local expert ``key[p]`` (``count`` =
+    none held here); ``readers`` [n, m] lists the pairs that read each
+    row of ``x``. → [pairs, d], junk where ``key == count``. ``rows`` is
+    the bound on the pairs with a held expert: the buffers' size."""
+    count = params["gate"].shape[0]
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)  # held first
+    place = jnp.argsort(order).astype(jnp.int32)   # pair -> sorted position
+    order, last = order[:rows], rows - 1
+    valid = key[order] < count
+    group_sizes = jnp.sum(
+        key[:, None] == jnp.arange(count, dtype=key.dtype)[None],
+        axis=0, dtype=jnp.int32)
+    xs = _take_rows(x, source[order], jnp.minimum(place[readers], last),
+                    key[readers] < count)
+    # gate and up as one matmul: the rows are read once, and their
+    # gradient is one array, not the sum of two at the buffers' size
+    width = params["gate"].shape[-1]
+    gate_up = jnp.concatenate([params["gate"], params["up"]],
+                              axis=-1).astype(x.dtype)
+    h = lax.ragged_dot(xs, gate_up, group_sizes)
+    h = jax.nn.relu(h[:, :width]) * h[:, width:]
+    out = lax.ragged_dot(h, params["down"].astype(x.dtype), group_sizes)
+    # rows past the last group are no expert's, and whatever the kernel
+    # left there: only pairs without a held expert read them (clipped),
+    # and `_combine` selects those away; no pass over the buffer here
+    return _take_rows(out, jnp.minimum(place, last), order[:, None],
+                      valid[:, None])
+
+
+def _pair_ids(t: int, k: int):
+    """The (token, choice) pairs are numbered choice by choice: pair
+    ``j*t + token`` is token's ``j``-th choice. [t, k] of them. (Token
+    by token, ``[pairs, d] -> [t, k, d]`` would put ``k`` on a tiled
+    dimension of the TPU's layout: a copy of the whole buffer, padded.)"""
+    return (jnp.arange(k, dtype=jnp.int32)[None] * t
+            + jnp.arange(t, dtype=jnp.int32)[:, None])
+
+
+def _choice(out_pairs, t: int, j: int):
+    """The ``t`` rows of every token's ``j``-th choice. Slices, never a
+    reshape to [k, t, d]: the TPU compiler moves a reshape ahead of the
+    arithmetic around it and then writes the operands out at the
+    buffer's size."""
+    return out_pairs[j * t:(j + 1) * t]
+
+
+@jax.custom_vjp
+def _combine(out_pairs, weights, mask):
+    """``sum_j weights[tok, j] * out_pairs[j*t + tok]`` over the pairs
+    ``mask`` keeps, accumulated in float32 → [t, d] float32. A masked
+    pair's row is junk: selected away, not multiplied by zero. Its own
+    VJP, so that what is kept for the backward pass is ``out_pairs`` as
+    it came and not a float32 copy of a buffer sized for a bound."""
+    t, k = weights.shape
+    return sum(jnp.where(mask[:, j, None], weights[:, j, None]
+                         * _choice(out_pairs, t, j).astype(jnp.float32), 0.0)
+               for j in range(k))
+
+
+def _combine_fwd(out_pairs, weights, mask):
+    return _combine(out_pairs, weights, mask), (out_pairs, weights, mask)
+
+
+def _combine_bwd(res, dy):
+    out_pairs, weights, mask = res
+    t, k = weights.shape
+    with jax.named_scope(scopes.MOE):  # as in `_take_rows_bwd`
+        d_rows = jnp.concatenate([
+            jnp.where(mask[:, j, None], weights[:, j, None] * dy,
+                      0.0).astype(out_pairs.dtype) for j in range(k)])
+        d_weights = jnp.stack([
+            jnp.sum(jnp.where(mask[:, j, None], _choice(out_pairs, t, j)
+                              .astype(jnp.float32) * dy, 0.0), axis=-1)
+            for j in range(k)], axis=1)
+        return d_rows, d_weights, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def expert_layer(u, chosen, weights, expert_params, held=None, *,
+                 axis_name=None):
+    """The held experts' part of a gated sparse feed-forward.
 
     Args:
-      x: [t_local, d] local token shard.
-      gate_w: [d, n_experts_total] router weights (replicated).
-      expert_fn: ``expert_fn(expert_params, x) -> y`` applied to this
-        chip's local experts; ``expert_params`` leaves have leading dim
-        n_local_experts.
-      capacity_factor: capacity = factor * t_local / n_experts_total.
+      u: [t, d] tokens (the local shard under ``axis_name``).
+      chosen, weights: `route`'s, [t, k]; ``chosen`` counts over all the
+        router's experts.
+      expert_params: ``gate``, ``up`` [h, d, f] and ``down`` [h, f, d] of
+        the ``h`` experts held here; expert ``e`` computes
+        ``(relu(u @ gate_e) * (u @ up_e)) @ down_e``.
+      held: ``(first, count)``: this call holds experts ``first ..
+        first + count - 1`` (default: ``0 .. h - 1``). Not with
+        ``axis_name``, where chip ``i`` of the axis holds ``i*h ..``.
+      axis_name: exchange the pairs over this mesh axis, so that every
+        chosen expert is somebody's.
 
-    Returns (y [t_local, d], aux_loss).
+    Returns ``sum over the chosen e that are held of w_e * E_e(u)``,
+    [t, d] in ``u``'s dtype. The weights stay normalised over all the
+    chosen, held or not.
     """
+    count = expert_params["gate"].shape[0]
+    t, _ = u.shape
+    k = chosen.shape[1]
+    per_token = min(k, count)   # a token's chosen experts are distinct
+    if axis_name is not None:
+        if held is not None:
+            raise ValueError("under axis_name a chip's experts follow from "
+                             "its place on the axis, not from held=")
+        return _exchanged(u, chosen, weights, expert_params, axis_name,
+                          t * per_token)
+    first = 0 if held is None else held[0]
+    if held is not None and held[1] != count:
+        raise ValueError(f"held={held} but the parameters are of {count} "
+                         "experts")
+    local = chosen - first
+    is_held = (local >= 0) & (local < count)
+    key = jnp.where(is_held, local, count).T.reshape(-1)
+    token = jnp.tile(jnp.arange(t, dtype=jnp.int32), k)
+    out_pairs = _experts_on_pairs(u, token, key, _pair_ids(t, k),
+                                  expert_params, t * per_token)
+    return _combine(out_pairs, weights, is_held).astype(u.dtype)
+
+
+def _exchanged(u, chosen, weights, params, axis_name, bound: int):
+    """`expert_layer` over the expert-parallel axis. A chip sends each
+    peer at most ``bound`` rows (all of its pairs may go to one chip), so
+    the exchange is ``[axis_size, bound, d]`` each way, padded."""
     n = lax.axis_size(axis_name)
-    t_local, d = x.shape
-    n_experts = gate_w.shape[-1]
-    if n_experts % n:
-        raise ValueError(f"experts ({n_experts}) must divide by ep={n}")
-    e_local = n_experts // n
-    capacity = max(1, int(capacity_factor * t_local / n_experts))
-
-    gate_logits = x.astype(jnp.float32) @ gate_w.astype(jnp.float32)
-    if k <= 1:
-        dispatch, combine, aux = top1_gating(gate_logits, n_experts, capacity)
-    else:
-        dispatch, combine, aux = topk_gating(gate_logits, n_experts,
-                                             capacity, k=k)
-
-    # gather expert inputs: [e, c, d] then alltoall over experts' owner axis
-    expert_in = jnp.einsum("tec,td->ecd", dispatch, x.astype(jnp.float32))
-    # [e, c, d] -> regroup as [n, e_local, c, d] and exchange: after
-    # all_to_all chip p holds, for each source chip, the slots of its local
-    # experts: [n (src chip), e_local, c, d]
-    expert_in = expert_in.reshape(n, e_local, capacity, d)
-    expert_in = lax.all_to_all(expert_in, axis_name, split_axis=0,
-                               concat_axis=0, tiled=False)  # [n, e_local, c, d]
-    # fold source-chip dim into the capacity dim and run local experts
-    expert_in = expert_in.transpose(1, 0, 2, 3).reshape(e_local, n * capacity, d)
-    expert_in = expert_in.astype(x.dtype)
-    expert_out = jax.vmap(expert_fn)(expert_params, expert_in)  # [e_local, n*c, d]
-    # reverse the exchange
-    expert_out = expert_out.reshape(e_local, n, capacity, d).transpose(1, 0, 2, 3)
-    expert_out = lax.all_to_all(expert_out, axis_name, split_axis=0,
-                                concat_axis=0, tiled=False)  # [n, e_local, c, d]
-    expert_out = expert_out.reshape(n_experts, capacity, d)
-    y = jnp.einsum("tec,ecd->td", combine, expert_out.astype(jnp.float32))
-    aux = lax.pmean(aux, axis_name)
-    return y.astype(x.dtype), aux
+    count = params["gate"].shape[0]
+    t, d = u.shape
+    k = chosen.shape[1]
+    pairs = t * k
+    expert = chosen.T.reshape(-1)
+    order = jnp.argsort(expert, stable=True).astype(jnp.int32)
+    place = jnp.argsort(order).astype(jnp.int32)
+    dest = expert // count                                   # [pairs]
+    to_peer = jnp.sum(dest[:, None] == jnp.arange(n)[None], axis=0,
+                      dtype=jnp.int32)
+    starts = jnp.cumsum(to_peer) - to_peer
+    slot = dest * bound + (place - starts[dest])             # pair -> slot
+    at = jnp.arange(bound, dtype=jnp.int32)
+    filled = at[None] < to_peer[:, None]                     # [n, bound]
+    sent = order[jnp.minimum(starts[:, None] + at[None], pairs - 1)]
+    send_x = _take_rows(u, (sent % t).reshape(-1), slot[_pair_ids(t, k)],
+                        jnp.ones((t, k), bool))
+    send_key = jnp.where(filled, expert[sent] % count, count)
+    recv_x = lax.all_to_all(send_x.reshape(n, bound, d), axis_name, 0, 0)
+    recv_key = lax.all_to_all(send_key.astype(jnp.int32), axis_name, 0, 0)
+    rows = n * bound
+    row_ids = jnp.arange(rows, dtype=jnp.int32)
+    out = _experts_on_pairs(recv_x.reshape(rows, d), row_ids,
+                            recv_key.reshape(-1), row_ids[:, None], params,
+                            rows)
+    back = lax.all_to_all(out.reshape(n, bound, d), axis_name, 0, 0)
+    out_pairs = _take_rows(back.reshape(rows, d), slot,
+                           sent.reshape(-1, 1), filled.reshape(-1, 1))
+    return _combine(out_pairs, weights,
+                    jnp.ones((t, k), bool)).astype(u.dtype)

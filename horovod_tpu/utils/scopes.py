@@ -16,6 +16,11 @@ Scopes the program sets (the only place their names are written):
 - ``hvd.grad_exchange/unpack``  slices of the buffer back into leaves
 - ``hvd.optimizer``             the inner optax update
 - ``hvd.model/embed|attention|mlp|head``  ``models/transformer.py``
+- ``hvd.model/router``          a sparse-expert block's router: scores,
+                                the top k, their weights
+- ``hvd.model/moe``             its expert layer (``parallel/moe.py``): sort,
+                                gathers, grouped matmuls, the weighted sum,
+                                and the all-to-all where there is one
 
 Forward, backward and recompute need no scope: JAX marks them itself
 (``jvp(``, ``transpose(``, ``rematted_computation``).
@@ -25,7 +30,11 @@ per step and per chip): ``collectives`` the exchange issued,
 ``collective_bytes`` handed to them, ``packed_bytes`` copied into flat
 buffers, ``axis_size``; ``attention_calls`` the decoder's default attention
 traced and ``attention_kernel_calls`` of them routed to the fused
-kernels (a share of the two survives retracing under ``jax.checkpoint``).
+kernels (a share of the two survives retracing under ``jax.checkpoint``),
+``attention_window_calls`` of them with a window; of a sparse-expert
+decoder ``moe_layers``, ``experts_held`` of ``experts_total`` in each,
+``experts_per_token`` chosen, and ``moe_buffer_rows``, the bound its expert layer's buffers are sized for
+(per layer: tokens times the most experts one token can have here).
 """
 
 from __future__ import annotations
@@ -50,6 +59,8 @@ EMBED = MODEL + "/embed"
 ATTENTION = MODEL + "/attention"
 MLP = MODEL + "/mlp"
 HEAD = MODEL + "/head"
+ROUTER = MODEL + "/router"
+MOE = MODEL + "/moe"
 
 #: in `phase_of`'s order of precedence
 PHASES = ("grad_exchange", "optimizer", "recompute", "backward", "forward",
@@ -64,6 +75,9 @@ _OP_NAME = re.compile(r'\bop_name="((?:[^"\\]|\\.)*)"')
 _COMPUTATION = re.compile(r"(?:ENTRY\s+)?%?([^\s(]+) \(.*\{\s*$")
 _CALLS = re.compile(r"\bcalls=%?([^\s,}]+)")
 _COPY_OF = re.compile(r"\scopy\(%?([^\s,)]+)\)")
+#: XLA's grouped matmul, as the TPU compiler rewrites ``ragged_dot``
+_GROUPED_MATMUL = re.compile(r"ragged-dot(?!-metadata)")
+_OPERANDS = re.compile(r"\s[a-z\-]+\(([^)]*)\)")
 
 
 def phase_of(op_name: Optional[str]) -> str:
@@ -99,8 +113,13 @@ def instruction_scopes(hlo_text: str) -> dict:
     the phase most of them have. A ``copy`` without metadata is the
     compiler re-tiling a result for its user (on the v5e: the attention
     weights' gradients ahead of the flat buffer; PERF.md, PR 26): it is
-    filed with the instruction that made the result."""
+    filed with the instruction that made the result. The TPU compiler's
+    grouped matmul (``ragged-dot*``, what it makes of
+    ``jax.lax.ragged_dot``) carries its own name for metadata and none
+    of the program's: it is filed with the first of its operands that
+    has a phase (the rows it multiplies; PERF.md, PR 28)."""
     table, inside, fusions, copies, computation = {}, {}, {}, {}, None
+    grouped = {}
     for line in hlo_text.splitlines():
         m = _INSTRUCTION.match(line)
         if m is None:
@@ -118,6 +137,11 @@ def instruction_scopes(hlo_text: str) -> dict:
         copied = None if op else _COPY_OF.search(line, m.end())
         if copied:
             copies[m.group(1)] = copied.group(1)
+        elif (_GROUPED_MATMUL.match(m.group(1))
+              and phase_of(table[m.group(1)]) == "other"):
+            operands = _OPERANDS.search(line, m.end())
+            grouped[m.group(1)] = re.findall(
+                r"%([^\s,)]+)", operands.group(1)) if operands else []
     for name, called in fusions.items():
         names = inside.get(called, ())
         votes = collections.Counter(phase_of(n) for n in names)
@@ -127,6 +151,10 @@ def instruction_scopes(hlo_text: str) -> dict:
                 n for n in names if phase_of(n) == winner))
     for name, source in copies.items():  # in program order: chains resolve
         table[name] = table.get(source, "")
+    for name, operands in grouped.items():
+        table[name] = next((table[o] for o in operands
+                            if phase_of(table.get(o)) != "other"),
+                           table[name])
     return table
 
 
@@ -200,12 +228,12 @@ def note_exchange(buffers, axis_name: str, packed: bool = False) -> None:
     c["axis_size"] = int(jax.lax.axis_size(axis_name))
 
 
-def note_attention(kernel: bool) -> None:
+def note_attention(kernel: bool, window: bool = False) -> None:
     """Called where ``models/transformer.py`` routes one default
-    attention call, to the fused kernels or to `causal_attention`. A
-    block under ``jax.checkpoint`` is traced more than once, so read the
-    two counters as a share. A no-op outside a traced
-    ``data_parallel_step``."""
+    attention call, to the fused kernels or to `causal_attention`, with
+    a window or without. A block under ``jax.checkpoint`` is traced more
+    than once, so read the counters as shares of ``attention_calls``. A
+    no-op outside a traced ``data_parallel_step``."""
     record = _tracing.get()
     if record is None:
         return
@@ -213,3 +241,20 @@ def note_attention(kernel: bool) -> None:
     c["attention_calls"] = c.get("attention_calls", 0) + 1
     c["attention_kernel_calls"] = (c.get("attention_kernel_calls", 0)
                                    + int(kernel))
+    if window:  # a decoder without windows keeps the counters it had
+        c["attention_window_calls"] = c.get("attention_window_calls", 0) + 1
+
+
+def note_moe(held: int, total: int, per_token: int,
+             buffer_rows: int) -> None:
+    """Called once per sparse-expert layer where ``models/transformer.py``
+    lays its blocks out (outside ``jax.checkpoint``, so ``moe_layers`` is
+    a count). A no-op outside a traced ``data_parallel_step``."""
+    record = _tracing.get()
+    if record is None:
+        return
+    c = record.counters
+    c["moe_layers"] = c.get("moe_layers", 0) + 1
+    c["experts_held"], c["experts_total"] = held, total
+    c["experts_per_token"] = per_token
+    c["moe_buffer_rows"] = buffer_rows

@@ -1,7 +1,8 @@
 """What a job pays at its start for what it does not use: the decoder
 imports no flax, nothing imports JAX's Pallas until a kernel is taken
-(about a second, PERF.md PR 27), and the model zoo's names still resolve
-in every spelling."""
+(about a second, PERF.md PR 27), a decoder without experts does not
+import the expert layer, and the model zoo's names still resolve in
+every spelling."""
 
 import json
 import os
@@ -84,3 +85,41 @@ def test_an_unknown_name_is_an_attribute_error():
         models.ResNet51
     with pytest.raises(ImportError):
         from horovod_tpu.models import ResNet51  # noqa: F401
+
+
+def test_a_dense_decoder_never_imports_the_expert_layer():
+    """In a process of its own: a dense decoder's loss and gradient are
+    traced with ``parallel/moe.py`` nowhere in ``sys.modules`` (the
+    dense and ResNet cells of the benchmark pay nothing for it); its
+    names still resolve from ``horovod_tpu.parallel``, and a
+    sparse-expert decoder's first trace is what imports it."""
+    code = """
+import json, sys
+import jax, jax.numpy as jnp
+import horovod_tpu, horovod_tpu.parallel
+from horovod_tpu.models import transformer as T
+MOE = "horovod_tpu.parallel.moe"
+tokens = jnp.zeros((1, 9), jnp.int32)
+dense = T.TransformerConfig(vocab_size=32, d_model=16, n_heads=2, n_layers=1,
+                            d_ff=32, max_seq=8)
+jax.eval_shape(jax.grad(lambda p: T.lm_loss(p, tokens, dense,
+                                            use_constraints=False)),
+               T.init(jax.random.PRNGKey(0), dense))
+after_dense = MOE in sys.modules
+sparse = T.TransformerConfig(vocab_size=32, d_model=16, n_heads=2, n_layers=1,
+                             d_ff=0, max_seq=8, n_experts=4,
+                             experts_per_token=2, d_expert=8)
+jax.eval_shape(lambda p: T.lm_loss(p, tokens, sparse, use_constraints=False),
+               T.init(jax.random.PRNGKey(0), sparse))
+after_sparse = MOE in sys.modules
+from horovod_tpu.parallel import expert_layer, route
+print(json.dumps([after_dense, after_sparse,
+                  expert_layer is sys.modules[MOE].expert_layer,
+                  "route" in dir(horovod_tpu.parallel)]))
+"""
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=120, env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert json.loads(out.stdout.splitlines()[-1]) == [False, True, True,
+                                                       True]

@@ -376,3 +376,77 @@ def test_fused_backward_stays_under_the_dense_oracle_in_memory():
         fused = temp_mb(lambda q, k, v: flash_attention(q, k, v, True, 256,
                                                         bk))
         assert fused < dense * 0.4, (bk, fused, dense)
+
+
+# -- a sliding window and grouped-query heads --------------------------------
+
+@pytest.mark.parametrize("s,window,wrt", [
+    (512, 200, None), (512, 200, 0), (512, 200, 1), (512, 200, 2),
+    (256, 256, None), (256, 256, 1), (512, 128, None), (512, 128, 0)],
+    ids=["cuts-tiles-o", "cuts-tiles-dq", "cuts-tiles-dk", "cuts-tiles-dv",
+         "cuts-none-o", "cuts-none-dk", "whole-tiles-o", "whole-tiles-dq"])
+def test_window_and_grouped_heads_match_the_einsum_path(s, window, wrt):
+    """The three kernels (interpret mode) with a ``window`` and four
+    query heads over two key/value heads, against `causal_attention`
+    with the same two arguments: at 512 positions and 128-blocks a
+    window of 200 empties whole tiles and cuts two per row, one of 128
+    cuts exactly at tile edges, and at 256 = the length it cuts none
+    (the mask is there and changes nothing). Forward, and each of the
+    three gradients through the custom VJP: the dK/dV kernel sums over
+    the two query heads of a group."""
+    from horovod_tpu.models.transformer import causal_attention
+
+    b, heads, kv, d, block = 1, 4, 2, 32, 128
+    rng = np.random.RandomState(7)
+    q = jnp.asarray(rng.randn(b, s, heads * d), jnp.float32)
+    k = jnp.asarray(rng.randn(b, s, kv * d), jnp.float32)
+    v = jnp.asarray(rng.randn(b, s, kv * d), jnp.float32)
+    t = jnp.asarray(rng.randn(b, s, heads * d), jnp.float32)
+
+    def kernels(q, k, v):
+        return flash_attention(q, k, v, True, block, block, heads, window,
+                               kv)
+
+    def einsum(q, k, v):
+        o = causal_attention(q.reshape(b, s, heads, d),
+                             k.reshape(b, s, kv, d), v.reshape(b, s, kv, d),
+                             window, kv)
+        return o.reshape(b, s, heads * d)
+
+    if wrt is None:
+        got, want = kernels(q, k, v), einsum(q, k, v)
+    else:
+        got, want = (jax.grad(lambda *a: jnp.sum(f(*a) * t), argnums=wrt)(
+            q, k, v) for f in (kernels, einsum))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4,
+                               rtol=2e-4)
+
+
+def test_window_against_scan_stats_oracle_by_hand_mask():
+    """One head, a window of 96 at 64-blocks (not a multiple of the
+    block: both edge tiles are cut inside), against a dense softmax with
+    the mask written out: ``j <= i`` and ``i - j < window``."""
+    s, d, window = 256, 32, 96
+    rng = np.random.RandomState(3)
+    q, k, v = (jnp.asarray(rng.randn(1, s, d), jnp.float32)
+               for _ in range(3))
+    i, j = np.arange(s)[:, None], np.arange(s)[None, :]
+    scores = np.einsum("bqd,bkd->bqk", q, k) * d ** -0.5
+    scores = np.where((j <= i) & (i - j < window), scores, -np.inf)
+    p = np.exp(scores - scores.max(-1, keepdims=True))
+    want = np.einsum("bqk,bkd->bqd", p / p.sum(-1, keepdims=True), v)
+    got = flash_attention(q, k, v, True, 64, 64, 1, window)
+    np.testing.assert_allclose(np.asarray(got), want, atol=1e-4)
+    # no window: the global kernel, and the same entry as before
+    full = flash_attention(q, k, v, True, 64, 64)
+    ref = _reference_attention(q, k, v, True)
+    np.testing.assert_allclose(np.asarray(full), np.asarray(ref), atol=1e-4)
+
+
+def test_window_and_head_arguments_are_checked():
+    q = jnp.zeros((1, 128, 4 * 32))
+    kv3 = jnp.zeros((1, 128, 3 * 32))
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, q, q, False, 64, 64, 4, 32)
+    with pytest.raises(ValueError, match="key/value heads"):
+        flash_attention(q, kv3, kv3, True, 64, 64, 4, None, 3)

@@ -122,6 +122,35 @@ def test_instruction_scopes_and_seconds_by_phase():
     assert by_part == {"hvd.model/mlp": 1.0} and found == 0.75
 
 
+def test_grouped_matmul_is_filed_with_the_rows_it_multiplies():
+    """The TPU compiler's ``ragged-dot*`` custom calls (what it makes of
+    ``jax.lax.ragged_dot``) carry their own name as ``op_name`` and none
+    of the program's scopes: `instruction_scopes` files each with the
+    first of its operands that has a phase; the metadata kernel beside
+    them, which has no such operand, stays as it is."""
+    moe = PRE + "transpose(jvp(" + scopes.MOE + "))/gather"
+    text = "\n".join([
+        "ENTRY %main (a: s32[8]) -> bf16[64,8] {",
+        '  %a = s32[8]{0} parameter(0), metadata={op_name="sizes"}',
+        "  %ragged-dot-metadata.1 = (s32[9]{0}, s32[1]{0}) custom-call(%a), "
+        'custom_call_target="tpu_custom_call", '
+        'metadata={op_name="ragged-dot-metadata"}',
+        "  %get-tuple-element.3 = s32[9]{0} "
+        "get-tuple-element(%ragged-dot-metadata.1), index=0",
+        '  %fusion.4 = bf16[64,8]{1,0} fusion(%a), kind=kCustom, '
+        'calls=%g, metadata={op_name="' + moe + '"}',
+        "  ROOT %ragged-dot-none.2 = bf16[64,8]{1,0} custom-call("
+        "%get-tuple-element.3, /*index=1*/%fusion.4, %a), "
+        'custom_call_target="tpu_custom_call", '
+        'metadata={op_name="ragged-dot-none"}',
+        "}"])
+    table = scopes.instruction_scopes(text)
+    assert table["ragged-dot-none.2"] == moe
+    assert scopes.phase_of(table["ragged-dot-none.2"]) == "backward"
+    assert scopes.part_of(table["ragged-dot-none.2"]) == scopes.MOE
+    assert table["ragged-dot-metadata.1"] == "ragged-dot-metadata"
+
+
 @pytest.fixture(scope="module")
 def compiles():
     """Every trace and compile request JAX makes from here on, through

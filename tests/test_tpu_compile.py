@@ -90,6 +90,53 @@ def test_flash_bwd_kernels_compile(topo, b, s):
     assert "hvd_flash_bwd_dq" in text and "hvd_flash_bwd_dkv" in text
 
 
+@pytest.mark.parametrize("window", [None, 4096], ids=["global", "window"])
+def test_grouped_head_kernels_compile_at_the_sparse_decoder_shapes(
+        topo, window):
+    """The sparse-expert cell's attention: 28 query heads over 4
+    key/value heads of 128 at 8192 positions, global and with a window
+    of 4096, at the blocks `block_sizes` gives. The TPU compiler takes
+    all three kernels; the windowed ones carry the window in their
+    names, which is how a trace's reader tells them apart."""
+    one = SingleDeviceSharding(topo.devices[0])
+    heads, kv, s = 28, 4, 8192
+    q = jax.ShapeDtypeStruct((1, s, heads * D), jnp.bfloat16, sharding=one)
+    k = jax.ShapeDtypeStruct((1, s, kv * D), jnp.bfloat16, sharding=one)
+    lse = jax.ShapeDtypeStruct((heads, 1, s), jnp.float32, sharding=one)
+    bq, bk = F.block_sizes(s, D)
+    static = dict(causal=True, block_q=bq, block_k=bk, heads=heads,
+                  window=window, kv_heads=kv)
+    suffix = "" if window is None else f"_w{window}"
+    fwd = F._flash_fwd_lse.lower(q, k, k, **static).compile().as_text()
+    assert "tpu_custom_call" in fwd and f"hvd_flash_fwd{suffix}" in fwd
+    bwd = F._flash_bwd.lower(q, k, k, q, q, lse, **static).compile()
+    text = bwd.as_text()
+    assert f"hvd_flash_bwd_dq{suffix}" in text
+    assert f"hvd_flash_bwd_dkv{suffix}" in text
+    assert [o.shape for o in bwd.out_info] == [q.shape, k.shape, k.shape]
+
+
+def test_dense_kernels_trace_as_before_the_window_and_the_groups(topo):
+    """``window=None, kv_heads=heads`` is the dense decoder's call: its
+    jaxpr (grid, index maps and kernel bodies) is, letter for letter,
+    that of the call without the two arguments, under the kernels' plain
+    names. (The lowered modules cannot be compared so: a kernel's MLIR
+    carries the caller's line numbers.)"""
+    one = SingleDeviceSharding(topo.devices[0])
+    heads, s = 16, 2048
+    x = jax.ShapeDtypeStruct((4, s, heads * D), jnp.bfloat16, sharding=one)
+    lse = jax.ShapeDtypeStruct((4 * heads, 1, s), jnp.float32, sharding=one)
+    bq, bk = F.block_sizes(s, D)
+    static = dict(causal=True, block_q=bq, block_k=bk, heads=heads)
+    for entry, args in ((F._flash_fwd_lse, (x, x, x)),
+                        (F._flash_bwd, (x, x, x, x, x, lse))):
+        plain = str(entry.trace(*args, **static).jaxpr)
+        assert plain == str(entry.trace(*args, **static, window=None,
+                                        kv_heads=heads).jaxpr)
+        assert "hvd_flash" in plain and "_w" not in "".join(
+            line for line in plain.splitlines() if "name=hvd_flash" in line)
+
+
 @pytest.mark.parametrize("offset", [0, 1])
 def test_attention_stats_vjp_compiles(topo, offset):
     """Kernel forward + the blockwise scan_stats backward, with all three
