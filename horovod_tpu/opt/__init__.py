@@ -31,7 +31,10 @@ Fusion note: inside jit, per-tensor ``psum`` calls are fused by XLA; with
 ``fuse_buckets=True`` we additionally flatten the gradient pytree into one
 flat buffer per dtype before a single ``psum`` — guaranteeing exactly one
 collective per dtype per step (the tensor-fusion contract,
-fusion_buffer_manager.h:40) regardless of compiler heuristics.
+fusion_buffer_manager.h:40) regardless of compiler heuristics. Where the
+axis has one member (a one-chip run of the same script) there is nothing
+to reduce and no buffer is built: `_tree_allreduce` hands the gradients
+straight to the update.
 """
 
 from __future__ import annotations
@@ -50,6 +53,28 @@ from ..utils import scopes
 
 def _tree_allreduce(grads, op, axis_name, compression, prescale, postscale,
                     fuse_buckets: bool):
+    """The replicated path's gradient exchange (`DistributedOptimizer`,
+    `distributed_grad`, `distributed_value_and_grad`).
+
+    Over an axis of one member the exchange is the identity, whatever
+    ``op`` (the sum, mean, minimum, maximum, product and Adasum of one
+    member are that member) and whatever ``compression`` (a wire format
+    without a wire): traced gradients come back as they are, times
+    ``prescale * postscale``, with no flat buffer, no collective and no
+    rounding (``Compression.fp16`` and the stateless int8 wire round
+    only where something is sent; upstream Horovod likewise skips the
+    allreduce at ``size() == 1``). `scopes.note_exchange` still records
+    the axis, with no collective. Not covered: eager calls (no axis to
+    ask; the negotiated path decides for itself), the error-feedback
+    quantized branch (its residual state is shaped like the flat
+    buffer) and the ZeRO-1 wrappers (``opt/sharded.py``,
+    `cross_replica_sharded_optimizer`)."""
+    if (any(C._is_traced(g) for g in jax.tree.leaves(grads))
+            and jax.lax.axis_size(axis_name) == 1):
+        scopes.note_exchange([], axis_name)
+        scale = prescale * postscale
+        return (grads if scale == 1.0
+                else jax.tree.map(lambda g: g * scale, grads))
     qspec = (getattr(compression, "quant_spec", None)
              if compression is not None else None)
     if qspec is not None:
@@ -257,7 +282,10 @@ def DistributedGradientTransformation(
     ``axis_name`` bound). With ``backward_passes_per_step > 1``, gradients
     are accumulated locally and only every Nth update triggers the
     collective + inner update (reference gradient_aggregation.py:16);
-    intermediate steps return zero updates.
+    intermediate steps return zero updates. Where ``axis_name`` has one
+    member (the same script on one chip) nothing is exchanged, packed or
+    rounded for a wire: the inner update gets the local gradients, times
+    the two scale factors (`_tree_allreduce`; docs/tensor-fusion.md).
 
     ``sharded_update`` (ZeRO-1, docs/sharded_optimizer.md): replace
     allreduce + replicated step with reduce-scatter → sharded step →

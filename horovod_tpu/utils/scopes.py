@@ -14,6 +14,9 @@ Scopes the program sets (the only place their names are written):
 - ``hvd.grad_exchange/pack``    gradients raveled into one flat buffer
 - ``hvd.grad_exchange/reduce``  the collective(s) over the data axis
 - ``hvd.grad_exchange/unpack``  slices of the buffer back into leaves
+                                (none of the three where the axis has one
+                                member: ``opt/`` builds no exchange there
+                                and the counters below read 0)
 - ``hvd.optimizer``             the inner optax update
 - ``hvd.model/embed|attention|mlp|head``  ``models/transformer.py``
 - ``hvd.model/router``          a sparse-expert block's router: scores,

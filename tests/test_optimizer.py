@@ -2,6 +2,8 @@
 tensorflow DistributedGradientTape + torch _DistributedOptimizer tests,
 gradient aggregation with backward_passes_per_step)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -206,3 +208,112 @@ def test_cross_replica_sharded_optimizer_mixed_precision():
     p2, _ = f(params, state)
     assert p2["w"].dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(p2["w"]), 0.9, rtol=1e-6)
+
+
+# -- an axis of one member: the exchange is the identity --------------------
+
+def _toy_loss(p, x):
+    return jnp.mean(jnp.tanh(x @ p["w"] + p["b"]) ** 2) + 0.1 * p["s"] ** 2
+
+
+def _after(opt, grads_of, batches):
+    """The toy parameters after a step of ``opt`` on ``grads_of(params,
+    x)`` for each of ``batches``, through `data_parallel_step` on a
+    one-device mesh."""
+    from jax.sharding import Mesh
+
+    from horovod_tpu.parallel import data_parallel_step
+
+    def step(params, opt_state, x):
+        updates, opt_state = opt.update(grads_of(params, x), opt_state, params)
+        return optax.apply_updates(params, updates), opt_state
+
+    step = data_parallel_step(
+        step, mesh=Mesh(np.array(jax.devices()[:1]), (DEFAULT_AXIS,)),
+        donate_argnums=())
+    k = jax.random.split(jax.random.PRNGKey(0), 3)
+    params = {"w": jax.random.normal(k[0], (4, 3)),
+              "b": jax.random.normal(k[1], (3,)),
+              "s": jax.random.normal(k[2], ())}
+    state = opt.init(params)
+    for x in batches:
+        params, state = step(params, state, x)
+    return params
+
+
+def _batches(micro):
+    return jax.random.normal(jax.random.PRNGKey(1), (2 * micro, 2, 4))
+
+
+@functools.lru_cache(maxsize=None)
+def _plain_optax_after(factor, micro):
+    """Two updates of plain optax on ``factor`` times the mean gradient
+    of ``micro`` batches each."""
+    def grads_of(params, x):
+        grads = [jax.grad(_toy_loss)(params, x[i]) for i in range(micro)]
+        return jax.tree.map(lambda *g: sum(g) * (factor / micro), *grads)
+
+    return _after(optax.adamw(1e-2), grads_of,
+                  _batches(micro).reshape(2, micro, 2, 4))
+
+
+def _wrapped(**kw):
+    """Local gradients into the wrapped optimizer."""
+    return (hvd.DistributedOptimizer(optax.adamw(1e-2), **kw),
+            jax.grad(_toy_loss))
+
+
+_INT8 = hvd.Compression.int8.with_options(error_feedback=False)
+#: id -> (the optimizer and the gradient function under test, the factor
+#: plain optax's gradients take, micro-batches per update)
+ONE_MEMBER = {
+    "average": (lambda: _wrapped(op=hvd.Average), 1.0, 1),
+    "sum": (lambda: _wrapped(op=hvd.Sum), 1.0, 1),
+    "min": (lambda: _wrapped(op=hvd.Min), 1.0, 1),
+    "adasum": (lambda: _wrapped(op=hvd.Adasum), 1.0, 1),
+    "scaled": (lambda: _wrapped(prescale_factor=0.5, postscale_factor=4.0),
+               2.0, 1),
+    "two_passes": (lambda: _wrapped(backward_passes_per_step=2), 1.0, 2),
+    "unfused": (lambda: _wrapped(fuse_buckets=False), 1.0, 1),
+    "unfused_scaled": (lambda: _wrapped(fuse_buckets=False, op=hvd.Sum,
+                                        prescale_factor=0.5,
+                                        postscale_factor=4.0), 2.0, 1),
+    "fp16": (lambda: _wrapped(compression=hvd.Compression.fp16), 1.0, 1),
+    "int8_stateless": (lambda: _wrapped(compression=_INT8), 1.0, 1),
+    "distributed_grad": (
+        lambda: (optax.adamw(1e-2),
+                 distributed_grad(_toy_loss, compression=hvd.Compression.fp16)),
+        1.0, 1),
+    "distributed_value_and_grad": (
+        lambda: (optax.adamw(1e-2),
+                 lambda p, x: distributed_value_and_grad(_toy_loss)(p, x)[1]),
+        1.0, 1),
+}
+
+
+@pytest.mark.parametrize("case", ONE_MEMBER)
+def test_one_member_axis_hands_the_gradients_straight_to_the_update(case):
+    """On a one-device mesh the wrapper's step is plain optax's on the
+    (scaled) local gradients, bit for bit: nothing is rounded for a wire
+    (fp16, int8) and no factor is lost (the scales, the mean over the
+    micro-batches)."""
+    make, factor, micro = ONE_MEMBER[case]
+    got = _after(*make(), _batches(micro))
+    want = _plain_optax_after(factor, micro)
+    for k in want:
+        np.testing.assert_array_equal(np.asarray(got[k]), np.asarray(want[k]))
+
+
+def test_eager_update_never_asks_for_an_axis(monkeypatch):
+    """Concrete gradients take the negotiated path, which has no axis:
+    the one-member rule must not look one up outside a trace."""
+    def no_axis(name):
+        raise AssertionError(f"axis_size({name!r}) asked outside a trace")
+
+    monkeypatch.setattr(jax.lax, "axis_size", no_axis)
+    opt = DistributedOptimizer(optax.sgd(0.1))
+    params = {"w": jnp.array([2.0, -1.0]), "b": jnp.array(0.5)}
+    updates, _ = opt.update(params, opt.init(params), params)
+    np.testing.assert_allclose(np.asarray(updates["w"]), [-0.2, 0.1],
+                               rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(updates["b"]), -0.05, rtol=1e-6)

@@ -283,6 +283,45 @@ def test_unfused_exchange_counts_a_collective_per_leaf():
     assert {"grad_exchange", "optimizer"} <= phases
 
 
+@pytest.mark.parametrize("build", ["dense_lm", "fused", "unfused"])
+def test_one_member_axis_builds_no_exchange(build):
+    """On a one-device mesh the wrapper builds nothing around the
+    gradients: no scope, no flat buffer, no collective; the counters say
+    so with numbers (a reader of a missing key would read None)."""
+    from jax.sharding import Mesh
+
+    mesh = Mesh(jax.devices()[:1], ("hvd",))
+    if build == "dense_lm":
+        step, params, args, phases = _lm_step(mesh)
+    else:
+        opt = hvd.DistributedOptimizer(optax.sgd(0.1),
+                                       fuse_buckets=build == "fused")
+        params = {"a": jnp.ones((3, 5)), "b": jnp.ones((7,))}
+
+        def step(params, opt_state, x):
+            grads = jax.grad(lambda p: jnp.sum(p["a"]) * jnp.sum(x)
+                             + jnp.sum(p["b"]))(params)
+            updates, opt_state = opt.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), opt_state, jnp.sum(x)
+
+        step = data_parallel_step(step, mesh=mesh)
+        args = (params, opt.init(params), jnp.ones((8, 2)))
+        phases = {"optimizer"}
+    text = step.lower(*args).as_text(debug_info=True)
+    counters = {k: v for k, v in dp.step_counters(step).items()
+                if not k.startswith("attention")}
+    assert counters == {"collectives": 0, "collective_bytes": 0,
+                        "packed_bytes": 0, "axis_size": 1}
+    table = dp.scope_table(step)
+    assert {scopes.phase_of(v) for v in table.values()} >= (
+        phases - {"grad_exchange"})
+    assert not any(scopes.GRAD_EXCHANGE in v for v in table.values())
+    assert scopes.GRAD_EXCHANGE not in text
+    if build != "dense_lm":  # the decoder's own step averages its loss
+        for op in ("concatenate", "all_reduce", "all-reduce", "psum"):
+            assert op not in text, op
+
+
 @pytest.mark.parametrize("routed,want", [
     ([True, True, True], {"attention_calls": 3, "attention_kernel_calls": 3}),
     ([False, False], {"attention_calls": 2, "attention_kernel_calls": 0}),

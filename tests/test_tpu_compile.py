@@ -262,6 +262,51 @@ def test_toy_lm_step_carries_every_phase_on_four_chips(topo, seq):
     } if seq >= T.FUSED_ATTENTION_MIN_SEQ else set())
 
 
+def test_toy_lm_step_builds_no_gradient_exchange_on_one_chip(topo):
+    """The same step for one described chip: an exchange over one member
+    is the identity, so the chip's compiler is handed no flat buffer and
+    the module names no ``hvd.grad_exchange`` work; the other phases
+    are all there and the counters read zero, not nothing."""
+    import optax
+
+    import horovod_tpu as hvd
+    from horovod_tpu.models import transformer as T
+    from horovod_tpu.parallel import data_parallel_step, dp
+    from horovod_tpu.utils import scopes
+
+    mesh = Mesh(np.array(topo.devices[:1]), ("hvd",))
+    cfg = T.TransformerConfig(vocab_size=512, d_model=256, n_heads=2,
+                              n_layers=2, d_ff=512, max_seq=512, remat=True)
+    opt = hvd.DistributedOptimizer(optax.adamw(1e-3))
+
+    def step(params, opt_state, tokens):
+        loss, grads = jax.value_and_grad(T.lm_loss)(
+            params, tokens, cfg, use_constraints=False)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return (optax.apply_updates(params, updates), opt_state,
+                jax.lax.pmean(loss, "hvd"))
+
+    def placed(tree, spec):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, spec)), tree)
+
+    params = jax.eval_shape(lambda: T.init(jax.random.PRNGKey(0), cfg))
+    step = data_parallel_step(step, mesh=mesh)
+    text = step.lower(
+        placed(params, P()), placed(jax.eval_shape(opt.init, params), P()),
+        placed(jax.ShapeDtypeStruct((2, 513), jnp.int32), P("hvd"))
+    ).compile().as_text()
+    assert scopes.GRAD_EXCHANGE not in text
+    phases = {scopes.phase_of(op)
+              for op in scopes.instruction_scopes(text).values()}
+    assert phases >= {"forward", "backward", "recompute", "optimizer"}
+    assert "grad_exchange" not in phases
+    counters = dp.step_counters(step)
+    assert (counters["collectives"], counters["collective_bytes"],
+            counters["packed_bytes"], counters["axis_size"]) == (0, 0, 0, 1)
+    assert counters["attention_kernel_calls"] == counters["attention_calls"]
+
+
 def test_checkpointed_decoder_traces_and_lowers_each_kernel_once(
         topo, monkeypatch):
     """Eight ``jax.checkpoint``-ed blocks under ``value_and_grad``
