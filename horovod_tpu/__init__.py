@@ -356,7 +356,6 @@ from .opt import (  # noqa: E402,F401
     DistributedGradientTransformation,
     ShardedDistributedOptimizer,
     ShardedUpdateEngine,
-    cross_replica_sharded_optimizer,
     distributed_grad,
     plan_shard_layout,
 )

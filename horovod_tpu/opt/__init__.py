@@ -33,8 +33,10 @@ flat buffer per dtype before a single ``psum`` — guaranteeing exactly one
 collective per dtype per step (the tensor-fusion contract,
 fusion_buffer_manager.h:40) regardless of compiler heuristics. Where the
 axis has one member (a one-chip run of the same script) there is nothing
-to reduce and no buffer is built: `_tree_allreduce` hands the gradients
-straight to the update.
+to reduce and no buffer is built (`_one_member`): the gradients go
+straight to the update. Every traced exchange of this package is made
+of `_pack`, `_reduce` (the one place a collective is issued and noted)
+and `_unpack`; `_exchange_by_dtype` is the three in a row.
 """
 
 from __future__ import annotations
@@ -51,32 +53,40 @@ from ..ops.collectives import ReduceOp
 from ..utils import scopes
 
 
+def _one_member(leaves, axis_name) -> bool:
+    """The one-member rule: traced ``leaves`` over an axis of one member.
+
+    There an exchange is the identity, whatever the op (the sum, mean,
+    minimum, maximum, product and Adasum of one member are that member)
+    and whatever the wire format (without a wire nothing is rounded;
+    upstream Horovod likewise skips the allreduce at ``size() == 1``).
+    Eager leaves have no axis to ask: the negotiated path decides.
+
+    Takes the shortcut: `_tree_allreduce`, that is `DistributedOptimizer`
+    with and without accumulation under any ``compression`` (the
+    stateless quantized wire included), `distributed_grad` and
+    `distributed_value_and_grad`. Does not, and builds its exchange over
+    one member as over many: the error-feedback quantized branch (its
+    residuals are shaped like the flat buffer, and checkpoints hold
+    them) and `ShardedDistributedOptimizer` (its state is shaped like
+    the shard). Whether they should is a speed question (ROADMAP D14)."""
+    return (any(C._is_traced(l) for l in leaves)
+            and jax.lax.axis_size(axis_name) == 1)
+
+
 def _tree_allreduce(grads, op, axis_name, compression, prescale, postscale,
                     fuse_buckets: bool):
-    """The replicated path's gradient exchange (`DistributedOptimizer`,
-    `distributed_grad`, `distributed_value_and_grad`).
-
-    Over an axis of one member the exchange is the identity, whatever
-    ``op`` (the sum, mean, minimum, maximum, product and Adasum of one
-    member are that member) and whatever ``compression`` (a wire format
-    without a wire): traced gradients come back as they are, times
-    ``prescale * postscale``, with no flat buffer, no collective and no
-    rounding (``Compression.fp16`` and the stateless int8 wire round
-    only where something is sent; upstream Horovod likewise skips the
-    allreduce at ``size() == 1``). `scopes.note_exchange` still records
-    the axis, with no collective. Not covered: eager calls (no axis to
-    ask; the negotiated path decides for itself), the error-feedback
-    quantized branch (its residual state is shaped like the flat
-    buffer) and the ZeRO-1 wrappers (``opt/sharded.py``,
-    `cross_replica_sharded_optimizer`)."""
-    if (any(C._is_traced(g) for g in jax.tree.leaves(grads))
-            and jax.lax.axis_size(axis_name) == 1):
+    """The replicated path's gradient exchange. Over one member
+    (`_one_member`) the gradients come back as they are, times
+    ``prescale * postscale``: no flat buffer, no collective, no
+    rounding; `scopes.note_exchange` still records the axis."""
+    leaves = jax.tree.leaves(grads)
+    if _one_member(leaves, axis_name):
         scopes.note_exchange([], axis_name)
         scale = prescale * postscale
         return (grads if scale == 1.0
                 else jax.tree.map(lambda g: g * scale, grads))
-    qspec = (getattr(compression, "quant_spec", None)
-             if compression is not None else None)
+    qspec = getattr(compression, "quant_spec", None)
     if qspec is not None:
         # stateless quantized reduce (no error-feedback carry across
         # calls — persistent EF lives in the optimizer wrapper's state)
@@ -89,33 +99,61 @@ def _tree_allreduce(grads, op, axis_name, compression, prescale, postscale,
                                     compression=compression,
                                     prescale_factor=prescale,
                                     postscale_factor=postscale)
-    scopes.note_exchange(jax.tree.leaves(grads), axis_name)
-    with jax.named_scope(scopes.REDUCE):
-        return jax.tree.map(
-            lambda g: C.allreduce(g, op=op, axis_name=axis_name,
-                                  compression=compression,
-                                  prescale_factor=prescale,
-                                  postscale_factor=postscale),
-            grads)
+    return jax.tree.unflatten(jax.tree.structure(grads), _reduce(
+        leaves, axis_name,
+        lambda g: C.allreduce(g, op=op, axis_name=axis_name,
+                              compression=compression,
+                              prescale_factor=prescale,
+                              postscale_factor=postscale)))
 
 
-def _pack(leaves, idxs, axis_name):
-    """The leaves ``idxs`` as the one flat buffer a collective takes."""
+def _pack(leaves, idxs, *, dtype=None, padded=None):
+    """The leaves ``idxs`` as the one flat buffer a collective takes:
+    each cast to ``dtype`` where one is given, the buffer zero-padded to
+    ``padded`` elements where it is shorter."""
     with jax.named_scope(scopes.PACK):
         flats = [jnp.ravel(leaves[i]) for i in idxs]
+        if dtype is not None:
+            flats = [f.astype(dtype) for f in flats]
         fused = jnp.concatenate(flats) if len(flats) > 1 else flats[0]
-    scopes.note_exchange([fused], axis_name, packed=len(flats) > 1)
+        if padded is not None and padded > fused.size:
+            fused = jnp.pad(fused, (0, padded - fused.size))
     return fused
 
 
-def _unpack(red, leaves, idxs, out) -> None:
-    """`_pack`'s inverse on the reduced buffer, into ``out[i]``."""
+def _reduce(buffers, axis_name, collective, packed: bool = False) -> list:
+    """The one reduce step: ``collective(buffer)`` for each of
+    ``buffers`` under the ``reduce`` scope, noted as that many
+    collectives over ``axis_name`` (``packed``: the buffers are copies
+    `_pack` made of more than one leaf)."""
+    scopes.note_exchange(buffers, axis_name, packed=packed)
+    with jax.named_scope(scopes.REDUCE):
+        return [collective(b) for b in buffers]
+
+
+def _unpack(red, leaves, idxs, out, *, like=None) -> None:
+    """`_pack`'s inverse on the reduced buffer, into ``out[i]``, each
+    slice cast to the dtype of ``like[i]`` where ``like`` is given."""
     off = 0
     with jax.named_scope(scopes.UNPACK):
         for i in idxs:
             n = jnp.size(leaves[i])
-            out[i] = jnp.reshape(red[off:off + n], jnp.shape(leaves[i]))
+            part = jnp.reshape(red[off:off + n], jnp.shape(leaves[i]))
+            out[i] = part if like is None else part.astype(like[i].dtype)
             off += n
+
+
+def _exchange_by_dtype(leaves, idxs, axis_name, collective, out) -> None:
+    """Pack → ``collective`` → unpack of the leaves ``idxs``, one flat
+    buffer per dtype in the order the dtypes first appear, into
+    ``out[i]``: tensor fusion on the compiled path."""
+    by_dtype: dict = {}
+    for i in idxs:
+        by_dtype.setdefault(jnp.asarray(leaves[i]).dtype, []).append(i)
+    for group in by_dtype.values():
+        red, = _reduce([_pack(leaves, group)], axis_name, collective,
+                       packed=len(group) > 1)
+        _unpack(red, leaves, group, out)
 
 
 def _quant_partition(tree):
@@ -161,48 +199,32 @@ def quantized_tree_allreduce(tree, spec, *, op=ReduceOp.AVERAGE,
     from ..ops import compression as compression_mod
 
     leaves, treedef, elig, plain = _quant_partition(tree)
-    if not leaves:
-        return tree, {}
     out = [None] * len(leaves)
     new_res: dict = {}
     traced = any(C._is_traced(l) for l in leaves)
+    scale = dict(prescale_factor=prescale_factor,
+                 postscale_factor=postscale_factor)
+    for i, red in zip(plain, fused_tree_allreduce(
+            [leaves[i] for i in plain], op=op, axis_name=axis_name, **scale)):
+        out[i] = red
 
-    def _by_dtype(idxs):
-        groups: dict = {}
-        for i in idxs:
-            groups.setdefault(str(jnp.asarray(leaves[i]).dtype), []).append(i)
-        return dict(sorted(groups.items()))
-
-    for dt, idxs in _by_dtype(plain).items():
-        fused = _pack(leaves, idxs, axis_name)
-        with jax.named_scope(scopes.REDUCE):
-            red = C.allreduce(fused, op=op, axis_name=axis_name,
-                              prescale_factor=prescale_factor,
-                              postscale_factor=postscale_factor)
-        _unpack(red, leaves, idxs, out)
-    for dt, idxs in _by_dtype(elig).items():
-        fused = _pack(leaves, idxs, axis_name)
-        if traced:
-            res = residuals.get(dt) if residuals else None
-            if res is not None and res.shape != fused.shape:
-                res = None  # layout moved (resize/re-trace): clean reset
-            with jax.named_scope(scopes.REDUCE):
-                red, err = C.quantized_allreduce(
-                    fused, axis_name, spec, op=op,
-                    prescale_factor=prescale_factor,
-                    postscale_factor=postscale_factor, residual=res)
-            new_res[dt] = err
-        else:
+    def quantized(fused):
+        if not traced:
             # eager call (no axis in scope): the quant marker routes the
             # fused buffer through the eager quantized chunk plan;
             # stateless — the queue runtime owns eager error feedback
             marker = compression_mod.QuantCompressor(
                 spec.bits, spec.block, spec.error_feedback)
-            red = C.allreduce(fused, op=op,
-                              prescale_factor=prescale_factor,
-                              postscale_factor=postscale_factor,
-                              compression=marker)
-        _unpack(red, leaves, idxs, out)
+            return C.allreduce(fused, op=op, compression=marker, **scale)
+        dt = str(fused.dtype)
+        res = residuals.get(dt) if residuals else None
+        if res is not None and res.shape != fused.shape:
+            res = None  # layout moved (resize/re-trace): clean reset
+        red, new_res[dt] = C.quantized_allreduce(
+            fused, axis_name, spec, op=op, residual=res, **scale)
+        return red
+
+    _exchange_by_dtype(leaves, elig, axis_name, quantized, out)
     return jax.tree.unflatten(treedef, out), new_res
 
 
@@ -225,26 +247,25 @@ def fused_tree_allreduce(tree, *, op=ReduceOp.AVERAGE, axis_name=DEFAULT_AXIS,
     with a single collective, then unflatten. This is tensor fusion on the
     compiled path."""
     leaves, treedef = jax.tree.flatten(tree)
-    if not leaves:
-        return tree
     if compression is not None:
         comp = [compression.compress(l) for l in leaves]
         leaves = [c[0] for c in comp]
         dectxs = [c[1] for c in comp]
-    by_dtype: dict = {}
-    for i, l in enumerate(leaves):
-        by_dtype.setdefault(jnp.asarray(l).dtype, []).append(i)
     out = [None] * len(leaves)
-    for dt, idxs in by_dtype.items():
-        fused = _pack(leaves, idxs, axis_name)
-        with jax.named_scope(scopes.REDUCE):
-            red = C.allreduce(fused, op=op, axis_name=axis_name,
-                              prescale_factor=prescale_factor,
-                              postscale_factor=postscale_factor)
-        _unpack(red, leaves, idxs, out)
+    _exchange_by_dtype(
+        leaves, range(len(leaves)), axis_name,
+        lambda fused: C.allreduce(fused, op=op, axis_name=axis_name,
+                                  prescale_factor=prescale_factor,
+                                  postscale_factor=postscale_factor), out)
     if compression is not None:
         out = [compression.decompress(o, c) for o, c in zip(out, dectxs)]
     return jax.tree.unflatten(treedef, out)
+
+
+def _update(optimizer, reduced, inner, params):
+    """The inner optax update, under the ``hvd.optimizer`` scope."""
+    with jax.named_scope(scopes.OPTIMIZER):
+        return optimizer.update(reduced, inner, params)
 
 
 class _AggState(NamedTuple):
@@ -285,7 +306,7 @@ def DistributedGradientTransformation(
     intermediate steps return zero updates. Where ``axis_name`` has one
     member (the same script on one chip) nothing is exchanged, packed or
     rounded for a wire: the inner update gets the local gradients, times
-    the two scale factors (`_tree_allreduce`; docs/tensor-fusion.md).
+    the two scale factors (`_one_member`; docs/tensor-fusion.md).
 
     ``sharded_update`` (ZeRO-1, docs/sharded_optimizer.md): replace
     allreduce + replicated step with reduce-scatter → sharded step →
@@ -314,8 +335,7 @@ def DistributedGradientTransformation(
             prescale_factor=prescale_factor,
             postscale_factor=postscale_factor)
     n = backward_passes_per_step
-    qspec = (getattr(compression, "quant_spec", None)
-             if compression is not None else None)
+    qspec = getattr(compression, "quant_spec", None)
     if qspec is not None and qspec.error_feedback:
         # persistent error feedback: the residual carry lives in the
         # optimizer state so it survives across steps and checkpoints —
@@ -337,9 +357,7 @@ def DistributedGradientTransformation(
                 prescale_factor=prescale_factor,
                 postscale_factor=postscale_factor,
                 residuals=state.residuals)
-            with jax.named_scope(scopes.OPTIMIZER):
-                updates, inner = optimizer.update(reduced, state.inner,
-                                                  params)
+            updates, inner = _update(optimizer, reduced, state.inner, params)
             if not new_res:
                 new_res = state.residuals  # eager call: carry unchanged
             return updates, _QuantEFState(inner, new_res)
@@ -353,25 +371,21 @@ def DistributedGradientTransformation(
         acc = jax.tree.map(jnp.zeros_like, params)
         return _AggState(inner, acc, jnp.zeros((), jnp.int32))
 
-    def _reduce(grads):
+    def _exchange(grads):
         return _tree_allreduce(grads, op, axis_name, compression,
                                prescale_factor, postscale_factor, fuse_buckets)
 
-    def _update(reduced, inner, params):
-        with jax.named_scope(scopes.OPTIMIZER):
-            return optimizer.update(reduced, inner, params)
-
     def update_fn(grads, state, params=None):
         if n <= 1:
-            return _update(_reduce(grads), state, params)
+            return _update(optimizer, _exchange(grads), state, params)
         acc = jax.tree.map(lambda a, g: a + g, state.acc, grads)
         counter = state.counter + 1
         is_step = counter >= n
 
         def do_step(_):
             scale = 1.0 / n if average_aggregated_gradients else 1.0
-            reduced = _reduce(jax.tree.map(lambda a: a * scale, acc))
-            updates, inner = _update(reduced, state.inner, params)
+            reduced = _exchange(jax.tree.map(lambda a: a * scale, acc))
+            updates, inner = _update(optimizer, reduced, state.inner, params)
             zeroed = jax.tree.map(jnp.zeros_like, acc)
             return updates, _AggState(inner, zeroed, jnp.zeros((), jnp.int32))
 
@@ -388,32 +402,6 @@ def DistributedGradientTransformation(
 DistributedOptimizer = DistributedGradientTransformation
 
 
-def distributed_grad(
-    fun: Callable,
-    *,
-    op: ReduceOp = ReduceOp.AVERAGE,
-    axis_name: str = DEFAULT_AXIS,
-    compression=None,
-    fuse_buckets: bool = True,
-    has_aux: bool = False,
-    argnums=0,
-):
-    """`jax.grad` whose gradients come back already allreduced — the JAX
-    equivalent of DistributedGradientTape (tensorflow/__init__.py:743)."""
-    gfun = jax.grad(fun, argnums=argnums, has_aux=has_aux)
-
-    def wrapped(*args, **kwargs):
-        if has_aux:
-            g, aux = gfun(*args, **kwargs)
-            return _tree_allreduce(g, op, axis_name, compression, 1.0, 1.0,
-                                   fuse_buckets), aux
-        g = gfun(*args, **kwargs)
-        return _tree_allreduce(g, op, axis_name, compression, 1.0, 1.0,
-                               fuse_buckets)
-
-    return wrapped
-
-
 def distributed_value_and_grad(
     fun: Callable,
     *,
@@ -425,6 +413,8 @@ def distributed_value_and_grad(
     average_loss: bool = True,
     argnums=0,
 ):
+    """`jax.value_and_grad` whose gradients come back already allreduced
+    and, with ``average_loss``, whose loss is averaged over the axis."""
     vgfun = jax.value_and_grad(fun, argnums=argnums, has_aux=has_aux)
 
     def wrapped(*args, **kwargs):
@@ -441,122 +431,29 @@ def distributed_value_and_grad(
     return wrapped
 
 
-class _ShardedUpdate(NamedTuple):
-    inner: object
+def distributed_grad(
+    fun: Callable,
+    *,
+    op: ReduceOp = ReduceOp.AVERAGE,
+    axis_name: str = DEFAULT_AXIS,
+    compression=None,
+    fuse_buckets: bool = True,
+    has_aux: bool = False,
+    argnums=0,
+):
+    """`jax.grad` whose gradients come back already allreduced — the JAX
+    equivalent of DistributedGradientTape (tensorflow/__init__.py:743):
+    `distributed_value_and_grad` without the value."""
+    vgfun = distributed_value_and_grad(
+        fun, op=op, axis_name=axis_name, compression=compression,
+        fuse_buckets=fuse_buckets, has_aux=has_aux, average_loss=False,
+        argnums=argnums)
 
+    def wrapped(*args, **kwargs):
+        val, g = vgfun(*args, **kwargs)
+        return (g, val[1]) if has_aux else g
 
-def cross_replica_sharded_optimizer(inner: optax.GradientTransformation,
-                                    num_shards: int,
-                                    axis_name: str = DEFAULT_AXIS
-                                    ) -> optax.GradientTransformation:
-    """Shard the weight update across data-parallel replicas (ZeRO-1).
-
-    The XLA "automatic cross-replica sharding of weight update"
-    optimization (arXiv:2004.13336) as an explicit optax wrapper —
-    greenfield vs the reference, which always runs the full update on
-    every worker.
-
-    Inside a ``shard_map`` DP region, each chip:
-
-      1. reduce-scatters the gradients (``psum_scatter``) — same bytes on
-         the wire as allreduce, split as RS+AG around the update;
-      2. runs ``inner.update`` on its 1/num_shards slice of every leaf —
-         optimizer state (e.g. Adam's m/v) is **num_shards× smaller per
-         chip**, the classic ZeRO-1 memory win;
-      3. all-gathers the update slices back to full updates for
-         ``optax.apply_updates``.
-
-    Exact for elementwise optimizers (SGD/momentum/Adam/AdamW/...): the
-    sharded update equals the replicated update slice-for-slice. Not for
-    optimizers whose update couples elements across a leaf or reads the
-    tree structure (per-layer norms like LARS, Adafactor row factors,
-    ``optax.masked``/``multi_transform``) — use the plain wrapper for
-    those: the fused shard hands the inner optimizer ONE flat leaf per
-    dtype (the module's tensor-fusion contract — exactly one
-    reduce-scatter + all-gather pair per dtype per step).
-
-    Use under ``data_parallel_step`` / shard_map with ``axis_name`` in
-    scope; ``num_shards`` must equal the axis size (validated at trace
-    time).
-    """
-
-    def _chunk(total: int) -> int:
-        return -(-total // num_shards)
-
-    def _dtype_totals(tree) -> dict:
-        totals: dict = {}
-        for l in jax.tree.leaves(tree):
-            k = str(jnp.asarray(l).dtype)
-            totals[k] = totals.get(k, 0) + l.size
-        return dict(sorted(totals.items()))
-
-    def init(params):
-        shard_shaped = {dt: jnp.zeros((_chunk(total),), dtype=dt)
-                        for dt, total in _dtype_totals(params).items()}
-        return _ShardedUpdate(inner.init(shard_shaped))
-
-    def update(grads, state, params=None):
-        axis_n = jax.lax.axis_size(axis_name)
-        if axis_n != num_shards:
-            raise ValueError(
-                f"cross_replica_sharded_optimizer(num_shards={num_shards}) "
-                f"used under a {axis_n}-wide '{axis_name}' axis — gradient "
-                "scaling would be silently wrong")
-        idx = jax.lax.axis_index(axis_name)
-        leaves, treedef = jax.tree.flatten(grads)
-        p_leaves = (jax.tree.leaves(params) if params is not None else None)
-        # group by the PARAM dtype when params are given (init keyed state
-        # the same way): bf16 grads under fp32 params cast up before the
-        # sharded update — master-weight semantics, and the state dict
-        # keys always match init's
-        ref_leaves = p_leaves if p_leaves is not None else leaves
-        groups = {}  # dtype -> leaf indices, in flatten order
-        for i, l in enumerate(ref_leaves):
-            groups.setdefault(str(l.dtype), []).append(i)
-        groups = dict(sorted(groups.items()))
-
-        def fuse(ls, dt):
-            with jax.named_scope(scopes.PACK):
-                flats = [jnp.ravel(x).astype(dt) for x in ls]
-                flat = (flats[0] if len(flats) == 1
-                        else jnp.concatenate(flats))
-                c = _chunk(flat.size)
-                return jnp.pad(flat, (0, c * num_shards - flat.size)), c
-
-        g_shard, p_shard = {}, {}
-        for dt, idxs in groups.items():
-            fused_g, c = fuse([leaves[i] for i in idxs], dt)
-            scopes.note_exchange([fused_g], axis_name, packed=True)
-            with jax.named_scope(scopes.REDUCE):
-                g_shard[dt] = jax.lax.psum_scatter(
-                    fused_g, axis_name, tiled=True) / num_shards
-            if p_leaves is not None:
-                fused_p, _ = fuse([p_leaves[i] for i in idxs], dt)
-                p_shard[dt] = jax.lax.dynamic_slice(fused_p, (idx * c,), (c,))
-        with jax.named_scope(scopes.OPTIMIZER):
-            u_shard, new_inner = inner.update(
-                g_shard, state.inner,
-                p_shard if p_leaves is not None else None)
-
-        out = list(leaves)
-        for dt, idxs in groups.items():
-            scopes.note_exchange([u_shard[dt]], axis_name)
-            with jax.named_scope(scopes.REDUCE):
-                full = jax.lax.all_gather(u_shard[dt], axis_name, tiled=True)
-            off = 0
-            for i in idxs:
-                # dtype ref: the param leaf when given — casting updates to
-                # a bf16 GRAD dtype under fp32 params would drift from the
-                # replicated trajectory
-                ref = p_leaves[i] if p_leaves is not None else leaves[i]
-                n_el = leaves[i].size
-                with jax.named_scope(scopes.UNPACK):
-                    out[i] = jax.lax.slice(full, (off,), (off + n_el,)) \
-                        .reshape(leaves[i].shape).astype(ref.dtype)
-                off += n_el
-        return jax.tree.unflatten(treedef, out), _ShardedUpdate(new_inner)
-
-    return optax.GradientTransformation(init, update)
+    return wrapped
 
 
 # ZeRO-1 sharded-update subsystem (docs/sharded_optimizer.md)

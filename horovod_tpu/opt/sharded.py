@@ -42,9 +42,9 @@ Two execution flavors share the planner and the compiled plans:
   single process can drive N virtual ranks in lockstep through
   :func:`simulated_step` (tests, CPU microbench).
 
-Exact for elementwise optimizers (SGD/momentum/Adam/AdamW/...); see
-``cross_replica_sharded_optimizer`` for the caveat on optimizers that
-couple elements across a leaf (LARS, Adafactor) — same caveat here.
+Exact for elementwise optimizers (SGD/momentum/Adam/AdamW/...); not for
+optimizers that couple elements across a leaf (LARS, Adafactor): see
+:func:`ShardedDistributedOptimizer`.
 """
 
 from __future__ import annotations
@@ -67,7 +67,8 @@ from ..ops.collectives import ReduceOp
 from ..parallel.sharding_policy import DEFAULT_MIN_SHARD_ELEMS, should_shard
 from ..utils import flightrec
 from ..utils import memledger as memledger_mod
-from ..utils import scopes
+# the package imports this module at its foot, below these definitions
+from . import _pack, _reduce, _unpack, _update
 
 _SUPPORTED_OPS = (ReduceOp.AVERAGE, ReduceOp.SUM)
 
@@ -137,22 +138,15 @@ class ShardLayout:
     digest: str
 
     @property
-    def sharded_elems(self) -> int:
-        return sum(g.total for g in self.groups)
-
-    @property
     def shard_elems(self) -> int:
         """This layout's per-rank owned elements (across groups)."""
         return sum(g.shard_elems for g in self.groups)
 
     @property
-    def total_elems(self) -> int:
-        return self.sharded_elems + self.replicated_elems
-
-    @property
     def shard_fraction(self) -> float:
-        total = self.total_elems
-        return (self.sharded_elems / total) if total else 0.0
+        sharded = sum(g.total for g in self.groups)
+        total = sharded + self.replicated_elems
+        return (sharded / total) if total else 0.0
 
     def group_padded(self, group: ShardGroup) -> int:
         return group.shard_elems * self.world_size
@@ -206,30 +200,15 @@ def plan_shard_layout(tree, world_size: int, *,
         digest=hashlib.sha1(payload.encode()).hexdigest())
 
 
-def _axis_size(axis_name: str) -> int:
-    """Static size of a bound named axis (compat: jax.lax.axis_size is
-    newer than some supported jax versions; psum of a literal 1 is the
-    classic spelling and is equally static at trace time)."""
-    ax = getattr(jax.lax, "axis_size", None)
-    if ax is not None:
-        return int(ax(axis_name))
-    return int(jax.lax.psum(1, axis_name))
-
-
 def _rep_key(i: int) -> str:
     return f"{i:05d}"
 
 
-def _combined_zeros(layout: ShardLayout, leaves) -> dict:
-    """The combined param structure the inner optimizer sees: replicated
-    leaves verbatim plus one zero flat shard per dtype group (init only
-    needs shapes — mirrors cross_replica_sharded_optimizer.init, which
-    must work outside any trace where the rank is unknown)."""
-    return {
-        "rep": {_rep_key(i): leaves[i] for i in layout.replicated},
-        "shard": {g.dtype: jnp.zeros((g.shard_elems,), g.dtype)
-                  for g in layout.groups},
-    }
+def _combined(layout: ShardLayout, leaves, shard: dict) -> dict:
+    """The structure the inner optimizer sees: the replicated leaves
+    (``leaves[i]``) verbatim plus one flat shard per dtype group."""
+    return {"rep": {_rep_key(i): leaves[i] for i in layout.replicated},
+            "shard": shard}
 
 
 # ===========================================================================
@@ -249,12 +228,24 @@ def ShardedDistributedOptimizer(
 ) -> optax.GradientTransformation:
     """ZeRO-1 drop-in for ``DistributedGradientTransformation`` (traced).
 
+    ``hvd.DistributedOptimizer(inner, sharded_update=True)`` builds it.
+
     Inside a shard_map/pjit region with ``axis_name`` bound: sub-threshold
-    leaves take the classic allreduce; everything else is fused per dtype,
-    ``psum_scatter``'d, stepped on the owned shard (inner optimizer state
-    1/N per chip), and the update shards ``all_gather``'d back. Exact for
-    elementwise optimizers. ``num_shards`` may be omitted — the axis size
-    is static at trace time.
+    leaves take the classic allreduce; everything else is fused per dtype
+    of the parameter (bf16 gradients under float32 parameters are cast up:
+    master-weight semantics), ``psum_scatter``'d, stepped on the owned
+    shard (inner optimizer state 1/N per chip), and the update shards
+    ``all_gather``'d back: one reduce-scatter and one all-gather per dtype
+    per step. ``min_shard_elems=0`` shards every leaf but the scalars.
+    ``num_shards`` may be omitted — the axis size is static at trace time
+    — except for an ``init`` outside a traced region.
+
+    Exact for elementwise optimizers (SGD/momentum/Adam/AdamW/...): the
+    sharded update equals the replicated update slice for slice. Not for
+    optimizers whose update couples elements across a leaf or reads the
+    tree structure (per-layer norms like LARS, Adafactor row factors,
+    ``optax.masked``/``multi_transform``): the inner optimizer sees ONE
+    flat leaf per dtype, so use the replicated wrapper for those.
     """
     if op not in _SUPPORTED_OPS:
         raise ValueError(
@@ -267,8 +258,8 @@ def ShardedDistributedOptimizer(
         if num_shards is not None:
             return int(num_shards)
         try:
-            return _axis_size(axis_name)
-        except Exception as e:
+            return jax.lax.axis_size(axis_name)
+        except NameError as e:
             raise ValueError(
                 "ShardedDistributedOptimizer: pass num_shards= when "
                 f"calling init() outside a traced '{axis_name}' region"
@@ -277,18 +268,15 @@ def ShardedDistributedOptimizer(
     def init_fn(params):
         layout = plan_shard_layout(params, _world(), min_shard_elems=mse,
                                    generation=0)
-        return optimizer.init(_combined_zeros(layout, jax.tree.leaves(params)))
-
-    def _fuse(ls, dt, padded):
-        with jax.named_scope(scopes.PACK):
-            flats = [jnp.ravel(x).astype(dt) for x in ls]
-            flat = flats[0] if len(flats) == 1 else jnp.concatenate(flats)
-            if padded > flat.size:
-                flat = jnp.pad(flat, (0, padded - flat.size))
-            return flat
+        # init only needs shapes, and must work outside any trace, where
+        # the rank is unknown: zeros stand for the shard
+        return optimizer.init(_combined(
+            layout, jax.tree.leaves(params),
+            {g.dtype: jnp.zeros((g.shard_elems,), g.dtype)
+             for g in layout.groups}))
 
     def update_fn(grads, state, params=None):
-        world = _axis_size(axis_name)
+        world = jax.lax.axis_size(axis_name)
         if num_shards is not None and num_shards != world:
             raise ValueError(
                 f"ShardedDistributedOptimizer(num_shards={num_shards}) used "
@@ -298,60 +286,50 @@ def ShardedDistributedOptimizer(
         p_leaves = jax.tree.leaves(params) if params is not None else None
         # layout from the PARAM dtypes when params are given (master-weight
         # semantics: bf16 grads under fp32 params cast up before the
-        # sharded step, matching cross_replica_sharded_optimizer)
+        # sharded step)
         layout = plan_shard_layout(params if params is not None else grads,
                                    world, min_shard_elems=mse, generation=0)
 
-        g_rep = {}
-        scopes.note_exchange([leaves[i] for i in layout.replicated],
-                             axis_name)
-        for i in layout.replicated:
-            with jax.named_scope(scopes.REDUCE):
-                g_rep[_rep_key(i)] = C.allreduce(
-                    leaves[i], op=op, axis_name=axis_name,
-                    prescale_factor=pre, postscale_factor=post)
+        g_rep = dict(zip(layout.replicated, _reduce(
+            [leaves[i] for i in layout.replicated], axis_name,
+            lambda g: C.allreduce(g, op=op, axis_name=axis_name,
+                                  prescale_factor=pre,
+                                  postscale_factor=post))))
         g_shard, p_shard = {}, {}
         for g in layout.groups:
-            padded = layout.group_padded(g)
-            fused = _fuse([leaves[i] for i in g.indices], g.dtype, padded)
+            packing = dict(dtype=g.dtype, padded=layout.group_padded(g))
+            fused = _pack(leaves, g.indices, **packing)
             if pre != 1.0:
                 fused = fused * pre
-            scopes.note_exchange([fused], axis_name, packed=True)
-            with jax.named_scope(scopes.REDUCE):
-                scattered = jax.lax.psum_scatter(fused, axis_name,
-                                                 tiled=True)
+            scattered, = _reduce(
+                [fused], axis_name,
+                lambda b: jax.lax.psum_scatter(b, axis_name, tiled=True),
+                packed=len(g.indices) > 1)
             if op == ReduceOp.AVERAGE:
                 scattered = scattered / world
             if post != 1.0:
                 scattered = scattered * post
             g_shard[g.dtype] = scattered
             if p_leaves is not None:
-                fp = _fuse([p_leaves[i] for i in g.indices], g.dtype, padded)
                 p_shard[g.dtype] = jax.lax.dynamic_slice(
-                    fp, (idx * g.shard_elems,), (g.shard_elems,))
-        combined_g = {"rep": g_rep, "shard": g_shard}
-        combined_p = ({"rep": {_rep_key(i): p_leaves[i]
-                               for i in layout.replicated},
-                       "shard": p_shard}
-                      if p_leaves is not None else None)
-        with jax.named_scope(scopes.OPTIMIZER):
-            u, new_state = optimizer.update(combined_g, state, combined_p)
+                    _pack(p_leaves, g.indices, **packing),
+                    (idx * g.shard_elems,), (g.shard_elems,))
+        u, new_state = _update(
+            optimizer, _combined(layout, g_rep, g_shard), state,
+            None if p_leaves is None else _combined(layout, p_leaves, p_shard))
 
         out = list(leaves)
         for i in layout.replicated:
             out[i] = u["rep"][_rep_key(i)]
         for g in layout.groups:
-            scopes.note_exchange([u["shard"][g.dtype]], axis_name)
-            with jax.named_scope(scopes.REDUCE):
-                full = jax.lax.all_gather(u["shard"][g.dtype], axis_name,
-                                          tiled=True)
-            off = 0
-            for i, n, shape in zip(g.indices, g.sizes, g.shapes):
-                ref = p_leaves[i] if p_leaves is not None else leaves[i]
-                with jax.named_scope(scopes.UNPACK):
-                    out[i] = jax.lax.slice(full, (off,), (off + n,)) \
-                        .reshape(shape).astype(ref.dtype)
-                off += n
+            full, = _reduce(
+                [u["shard"][g.dtype]], axis_name,
+                lambda b: jax.lax.all_gather(b, axis_name, tiled=True))
+            # cast back to the param leaf's dtype when given: casting
+            # updates to a bf16 GRAD dtype under fp32 params would drift
+            # from the replicated trajectory
+            _unpack(full, leaves, g.indices, out,
+                    like=leaves if p_leaves is None else p_leaves)
         return jax.tree.unflatten(treedef, out), new_state
 
     return optax.GradientTransformation(init_fn, update_fn)
@@ -472,11 +450,8 @@ class ShardedUpdateEngine:
         updates in one ``inner.update`` call."""
         layout = self.ensure_layout(params)
         leaves = jax.tree.leaves(params)
-        combined = {
-            "rep": {_rep_key(i): leaves[i] for i in layout.replicated},
-            "shard": self._param_shards(layout, leaves),
-        }
-        state = self._opt.init(combined)
+        state = self._opt.init(_combined(
+            layout, leaves, self._param_shards(layout, leaves)))
         # the sharded-state bytes are the whole point of ZeRO-1: the
         # ledger's component attribution turns "should be 1/N" into a
         # measured number (tests/test_sharded_update.py asserts it)
@@ -491,6 +466,12 @@ class ShardedUpdateEngine:
                                    group.shard_elems, layout.digest)
         return plan(*[leaves[i] for i in group.indices])
 
+    def _reduce_scatter_plan(self, layout: ShardLayout, group: ShardGroup):
+        return C.sharded_reduce_scatter_plan(
+            self._ps, layout.world_size, self._rank, self._op,
+            group.shard_elems, group.dtype, layout.digest, self._pre,
+            self._post)
+
     def _param_shards(self, layout: ShardLayout, p_leaves) -> dict:
         shards = {}
         for g in layout.groups:
@@ -498,12 +479,6 @@ class ShardedUpdateEngine:
             lo = self._rank * g.shard_elems
             shards[g.dtype] = C._cached_slice(flat, lo, lo + g.shard_elems)
         return shards
-
-    def _fuse(self, layout: ShardLayout, grads) -> dict:
-        """Per-group fused local gradient contributions."""
-        leaves = jax.tree.leaves(grads)
-        return {g.dtype: self._pack(layout, leaves, g)
-                for g in layout.groups}
 
     def _local_update(self, layout: ShardLayout, params, red_shards: dict,
                       red_rep: dict, state):
@@ -513,15 +488,9 @@ class ShardedUpdateEngine:
         index, new inner state)."""
         leaves = jax.tree.leaves(params)
         p_shard = self._param_shards(layout, leaves)
-        combined_p = {
-            "rep": {_rep_key(i): leaves[i] for i in layout.replicated},
-            "shard": p_shard,
-        }
-        combined_g = {
-            "rep": {_rep_key(i): red_rep[i] for i in layout.replicated},
-            "shard": red_shards,
-        }
-        u, new_state = self._opt.update(combined_g, state, combined_p)
+        u, new_state = self._opt.update(
+            _combined(layout, red_rep, red_shards), state,
+            _combined(layout, leaves, p_shard))
         new_rep = {i: optax.apply_updates(leaves[i], u["rep"][_rep_key(i)])
                    for i in layout.replicated}
         new_shards = {dt: optax.apply_updates(p_shard[dt], u["shard"][dt])
@@ -576,10 +545,8 @@ class ShardedUpdateEngine:
         red_shards = {}
         for g in layout.groups:
             flat = self._pack(layout, g_leaves, g)
-            rs = C.sharded_reduce_scatter_plan(
-                self._ps, layout.world_size, self._rank, self._op,
-                g.shard_elems, g.dtype, layout.digest, self._pre, self._post)
-            red_shards[g.dtype] = rs(C._global_row_array(self._ps, flat))
+            red_shards[g.dtype] = self._reduce_scatter_plan(layout, g)(
+                C._global_row_array(self._ps, flat))
         new_shards, new_rep, new_state = self._local_update(
             layout, params, red_shards, red_rep, state)
         gathered = {dt: C._global_row_array(self._ps, sh)
@@ -593,8 +560,9 @@ class ShardedUpdateEngine:
     def full_state(self, state, *, gather=None):
         """Materialize the unsharded inner state (elastic commit payload:
         every rank can restore from it under any future layout). Shard
-        leaves are allgathered and trimmed to their group's true extent;
-        replicated leaves and scalars pass through."""
+        leaves are allgathered (``gather(position, leaf)``) and trimmed
+        to their group's true extent; replicated leaves and scalars pass
+        through."""
         layout = self._layout
         if layout is None:
             raise ValueError("no layout yet — run init()/step() first")
@@ -602,16 +570,13 @@ class ShardedUpdateEngine:
             if self._ps is None:
                 raise ValueError(
                     "simulated engines use simulated_full_state()")
-            gather = lambda leaf: C.allgather(leaf, process_set=self._ps)  # noqa: E731
+            gather = lambda pos, leaf: C.allgather(  # noqa: E731
+                leaf, process_set=self._ps)
         flat, treedef = jtu.tree_flatten_with_path(state)
         out = []
-        for path, leaf in flat:
+        for pos, (path, leaf) in enumerate(flat):
             g = _shard_group_for(layout, path, leaf)
-            if g is not None:
-                full = gather(leaf)
-                out.append(full[:g.total])
-            else:
-                out.append(leaf)
+            out.append(leaf if g is None else gather(pos, leaf)[:g.total])
         return jtu.tree_unflatten(treedef, out)
 
     def load_full_state(self, full, params):
@@ -624,10 +589,8 @@ class ShardedUpdateEngine:
         for path, leaf in flat:
             g = _shard_group_for(layout, path, leaf, full_extent=True)
             if g is not None:
-                padded = layout.group_padded(g)
-                arr = jnp.ravel(jnp.asarray(leaf))
-                if padded > arr.size:
-                    arr = jnp.pad(arr, (0, padded - arr.size))
+                arr = _pack([jnp.asarray(leaf)], [0],
+                            padded=layout.group_padded(g))
                 lo = self._rank * g.shard_elems
                 out.append(arr[lo:lo + g.shard_elems])
             else:
@@ -642,22 +605,15 @@ def _shard_group_for(layout: ShardLayout, path, leaf, *,
     combined structure keys them under ``["shard"][dtype]`` — plus the
     expected extent (shard_elems, or the trimmed group total for
     full-state payloads)."""
-    seen_shard = False
-    dt = None
-    for k in path:
-        if isinstance(k, jtu.DictKey):
-            if seen_shard and dt is None:
-                dt = k.key
-            if k.key == "shard":
-                seen_shard = True
-    if not seen_shard or dt is None:
+    keys = [k.key for k in path if isinstance(k, jtu.DictKey)]
+    if "shard" not in keys[:-1]:
         return None
+    dt = keys[keys.index("shard") + 1]
     for g in layout.groups:
         if g.dtype == dt:
             want = g.total if full_extent else g.shard_elems
-            if jnp.ndim(leaf) == 1 and jnp.shape(leaf)[0] == want:
-                return g
-            return None
+            fits = jnp.ndim(leaf) == 1 and jnp.shape(leaf)[0] == want
+            return g if fits else None
     return None
 
 
@@ -679,10 +635,8 @@ def _sim_reduce(stack, op: ReduceOp, pre: float, post: float):
     key = ("sharded_sim_reduce", tuple(stack.shape), str(stack.dtype),
            int(op), float(pre), float(post))
 
-    def build():
-        return jax.jit(C._allreduce_body(None, op, pre, post, False))
-
-    return C._cached(key, build)(stack)
+    return C._cached(key, lambda: jax.jit(
+        C._allreduce_body(None, op, pre, post, False)))(stack)
 
 
 def simulated_step(engines: Sequence[ShardedUpdateEngine], params,
@@ -703,16 +657,14 @@ def simulated_step(engines: Sequence[ShardedUpdateEngine], params,
         stack = jnp.stack([g_leaves[r][i] for r in range(world)])
         red_rep[i] = _sim_reduce(stack, engines[0]._op, engines[0]._pre,
                                  engines[0]._post)
-    fused = [e._fuse(lay, g) for e, lay, g
-             in zip(engines, layouts, grads_per_rank)]
+    fused = [{g.dtype: e._pack(lay, leaves, g) for g in lay.groups}
+             for e, lay, leaves in zip(engines, layouts, g_leaves)]
     red_shards_per_rank: List[dict] = [{} for _ in range(world)]
     for g in layout.groups:
         G = jnp.stack([fused[r][g.dtype] for r in range(world)])
         for r, e in enumerate(engines):
-            rs = C.sharded_reduce_scatter_plan(
-                None, world, e._rank, e._op, g.shard_elems, g.dtype,
-                layouts[r].digest, e._pre, e._post)
-            red_shards_per_rank[r][g.dtype] = rs(G)
+            red_shards_per_rank[r][g.dtype] = e._reduce_scatter_plan(
+                layouts[r], g)(G)
     locals_ = [e._local_update(lay, params, red_shards_per_rank[r], red_rep,
                                states[r])
                for r, (e, lay) in enumerate(zip(engines, layouts))]
@@ -729,18 +681,7 @@ def simulated_full_state(engines: Sequence[ShardedUpdateEngine],
                          states: Sequence):
     """:meth:`ShardedUpdateEngine.full_state` for a simulated world —
     shard leaves concatenated across the in-process engines."""
-    layout = engines[0]._layout
-    if layout is None:
-        raise ValueError("no layout yet — run init()/step() first")
-    flats = [jtu.tree_flatten_with_path(s) for s in states]
-    treedef = flats[0][1]
-    out = []
-    for pos, (path, leaf) in enumerate(flats[0][0]):
-        g = _shard_group_for(layout, path, leaf)
-        if g is not None:
-            full = jnp.concatenate([flats[r][0][pos][1]
-                                    for r in range(len(engines))])
-            out.append(full[:g.total])
-        else:
-            out.append(leaf)
-    return jtu.tree_unflatten(treedef, out)
+    flats = [jax.tree.leaves(s) for s in states]
+    return engines[0].full_state(
+        states[0], gather=lambda pos, _: jnp.concatenate(
+            [flat[pos] for flat in flats]))

@@ -357,6 +357,9 @@ def test_sigterm_flushes_pending_snapshot_then_dies(tmp_path):
     ckpt_dir = tmp_path / "ckpt"
     env = dict(os.environ)
     env.pop("HOROVOD_FAULT_SPEC", None)
+    # the script lives in tmp_path: the child finds the checkout by path
+    env["PYTHONPATH"] = os.pathsep.join(
+        [REPO] + [p for p in (env.get("PYTHONPATH"),) if p])
     proc = subprocess.run([sys.executable, str(script), str(ckpt_dir)],
                           env=env, capture_output=True, text=True,
                           timeout=300)
@@ -509,20 +512,6 @@ def _load_overhead_bench():
     spec.loader.exec_module(mod)
     return mod
 
-
-def test_overhead_microbench_smoke():
-    """Tier-1 net for the A/A gate: small-cycle run with a loose bound
-    (the 2% gate is the benchmark's own, over best-of-5 full runs)."""
-    mod = _load_overhead_bench()
-    base = mod.measure_async_ckpt(False, cycles=8, warmup=3)
-    off = mod.measure_async_ckpt(False, cycles=8, warmup=3)
-    on = mod.measure_async_ckpt(True, cycles=8, warmup=3)
-    assert async_ckpt.get_checkpointer() is None  # harness restored off
-    assert off["dispatch_ms_median"] < base["dispatch_ms_median"] * 1.3
-    assert on["dispatch_ms_median"] < base["dispatch_ms_median"] * 3.0
-    # the on config reports the snapshot-copy budget it measured
-    assert on["snapshot_copy_s"] > 0.0 and on["shard_bytes"] > 0
-    assert on["shard_write_s"] > 0.0
 
 
 @pytest.mark.slow

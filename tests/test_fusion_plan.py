@@ -303,6 +303,5 @@ def test_cycle_overhead_microbench_smoke():
     spec.loader.exec_module(mod)
     stats = mod.measure(plans_enabled=True, cycles=5, warmup=2)
     assert stats["tensors_per_cycle"] == 20
-    assert stats["dispatch_ms_median"] > 0
     # steady state must be pure replay: every lookup after warmup a hit
     assert stats["plan_hit_rate"] == 1.0

@@ -138,18 +138,6 @@ def _load_health_overhead():
     return mod
 
 
-def test_health_overhead_microbench_smoke():
-    """Tier-1 net for the A/A gate: small-cycle run of
-    benchmarks/health_overhead.py with a loose bound (the 2% gate is
-    the benchmark's own, over best-of-5 full runs)."""
-    mod = _load_health_overhead()
-    base = mod.measure_health(health_on=False, cycles=8, warmup=3)
-    off = mod.measure_health(health_on=False, cycles=8, warmup=3)
-    on = mod.measure_health(health_on=True, cycles=8, warmup=3)
-    assert health.get_engine() is None  # harness restored the default
-    assert off["dispatch_ms_median"] < base["dispatch_ms_median"] * 1.3
-    assert on["dispatch_ms_median"] < base["dispatch_ms_median"] * 3.0
-
 
 @pytest.mark.slow
 def test_health_aa_gate_benchguard():

@@ -421,7 +421,10 @@ METRICS_WORKER = textwrap.dedent("""
     for c in dump["counters"]:
         by_name[c["name"]] = by_name.get(c["name"], 0) + c["value"]
     assert by_name["hvd_allreduce_bytes_total"] == 4 * 512 * 4, by_name
-    assert by_name["hvd_allreduce_ops_total"] == 4, by_name
+    # the four tensors may fuse into one chunk: an op is a dispatched
+    # collective, so only what was enqueued is a fixed count
+    assert by_name["hvd_ops_enqueued_total"] == 4, by_name
+    assert by_name["hvd_allreduce_ops_total"] >= 1, by_name
     print("metrics worker OK", r)
 """)
 

@@ -117,16 +117,89 @@ def test_fused_tree_allreduce_matches_per_leaf():
         np.testing.assert_allclose(np.asarray(out[k]), tree[k] * scale, rtol=1e-5)
 
 
+def test_quantized_tree_allreduce_is_its_two_wires_side_by_side():
+    """The quantized tree exchange is, bit for bit, `fused_tree_allreduce`
+    of its guardrail leaves and `C.quantized_allreduce` of each dtype's
+    packed eligible leaves; its residuals are laid out as
+    `quant_residual_init` lays them out."""
+    from horovod_tpu.ops import collectives as C
+    from horovod_tpu.opt import quant_residual_init, quantized_tree_allreduce
+
+    spec = hvd.Compression.int8.quant_spec
+    rng = np.random.RandomState(7)
+    shapes = {  # eligible: the four kernels of 4096 elements and more
+        "a": {"kernel": ((80, 64), np.float32), "bias": ((64,), np.float32)},
+        "b": {"kernel": ((70, 60), np.float32)},
+        "c": {"kernel": ((96, 48), np.float16)},
+        "d": {"kernel": ((4100,), np.float16)},
+        "norm": {"scale": ((32,), np.float16)},
+        "small": ((10,), np.float32),
+        "count": ((5,), np.int32),
+    }
+    is_leaf = lambda x: isinstance(x, tuple)  # noqa: E731
+    tree = jax.tree.map(
+        lambda sd: (rng.randn(N, *sd[0]) * 3).astype(sd[1]), shapes,
+        is_leaf=is_leaf)
+    plain = ("a", "bias"), ("norm", "scale"), ("small",), ("count",)
+    eligible = {"float32": [("a", "kernel"), ("b", "kernel")],
+                "float16": [("c", "kernel"), ("d", "kernel")]}
+
+    def get(t, path):
+        for k in path:
+            t = t[k]
+        return t
+
+    def both(t):
+        t = jax.tree.map(lambda x: x[0], t)
+        red, res = quantized_tree_allreduce(t, spec, op=hvd.Sum,
+                                            prescale_factor=0.5)
+        want = dict(zip(plain, fused_tree_allreduce(
+            [get(t, path) for path in plain], op=hvd.Sum,
+            prescale_factor=0.5)))
+        want_res = {}
+        for dt, paths in eligible.items():
+            flat, want_res[dt] = C.quantized_allreduce(
+                jnp.concatenate([jnp.ravel(get(t, path)) for path in paths]),
+                DEFAULT_AXIS, spec, op=hvd.Sum, prescale_factor=0.5)
+            off = 0
+            for path in paths:
+                n = get(t, path).size
+                want[path] = flat[off:off + n].reshape(get(t, path).shape)
+                off += n
+        got = {path: get(red, path) for path in want}
+        # residuals are this member's own: leave them on the axis
+        return got, want, jax.tree.map(lambda r: r[None], (res, want_res))
+
+    spec_of = jax.tree.map(lambda _: P(DEFAULT_AXIS), tree)
+    got, want, (res, want_res) = jax.jit(smap(
+        both, in_specs=(spec_of,),
+        out_specs=(P(), P(), P(DEFAULT_AXIS))))(tree)
+    assert sorted(got) == sorted(plain + tuple(sum(eligible.values(), [])))
+    for path in want:
+        assert got[path].dtype == want[path].dtype, path
+        np.testing.assert_array_equal(np.asarray(got[path]),
+                                      np.asarray(want[path]), str(path))
+    init = quant_residual_init(jax.tree.map(lambda x: x[0], tree), spec)
+    assert sorted(res) == sorted(init) == ["float16", "float32"]
+    for dt in init:
+        assert res[dt].shape == (N,) + init[dt].shape
+        assert res[dt].dtype == init[dt].dtype == jnp.float32
+        np.testing.assert_array_equal(np.asarray(res[dt]),
+                                      np.asarray(want_res[dt]))
+    assert float(jnp.abs(res["float32"]).max()) > 0  # the wire does round
+
+
 def test_broadcast_parameters():
     params = {"w": jnp.arange(4.0), "b": jnp.array(1.5)}
     out = hvd.broadcast_parameters(params, root_rank=0)
     np.testing.assert_allclose(np.asarray(out["w"]), np.arange(4.0))
 
 
-def test_cross_replica_sharded_optimizer_matches_replicated():
-    """ZeRO-1 weight-update sharding (arXiv:2004.13336): RS -> shard-local
-    Adam -> AG produces EXACTLY the replicated Adam trajectory for
-    elementwise optimizers, with optimizer state num_shards x smaller."""
+def test_sharded_update_of_every_leaf_matches_replicated():
+    """ZeRO-1 weight-update sharding (arXiv:2004.13336) with every leaf
+    sharded (``min_shard_elems=0``): RS -> shard-local Adam -> AG
+    produces EXACTLY the replicated Adam trajectory for elementwise
+    optimizers, with optimizer state num_shards x smaller."""
     hvd.init()
     mesh = hvd.global_process_set().mesh
     n = hvd.size()
@@ -160,11 +233,12 @@ def test_cross_replica_sharded_optimizer_matches_replicated():
         out_specs=(P(), P()), check_vma=False))
 
     # sharded-update path
-    z1 = hvd.cross_replica_sharded_optimizer(base, num_shards=n)
+    z1 = hvd.DistributedOptimizer(base, sharded_update=True, num_shards=n,
+                                  min_shard_elems=0)
     z_p = params
     z_state = z1.init(params)
     # ZeRO-1 memory win: state is ONE fused leaf per dtype at shard size
-    m_leaves = jax.tree.leaves(z_state.inner[0].mu)
+    m_leaves = jax.tree.leaves(z_state[0].mu)
     assert len(m_leaves) == 1  # one f32 fused buffer for b(5)+w(65)=70
     assert m_leaves[0].shape == (-(-70 // n),), m_leaves[0].shape
 
@@ -187,7 +261,7 @@ def test_cross_replica_sharded_optimizer_matches_replicated():
                                rtol=2e-5, atol=2e-6)
 
 
-def test_cross_replica_sharded_optimizer_mixed_precision():
+def test_sharded_update_of_every_leaf_mixed_precision():
     """bf16 grads under fp32 params: grads cast up to the param dtype
     before the sharded update (master-weight semantics) — must trace and
     step without dtype-key mismatches."""
@@ -195,7 +269,8 @@ def test_cross_replica_sharded_optimizer_mixed_precision():
     mesh = hvd.global_process_set().mesh
     n = hvd.size()
     params = {"w": jnp.ones((9,), jnp.float32)}
-    opt = hvd.cross_replica_sharded_optimizer(optax.sgd(0.1), num_shards=n)
+    opt = hvd.DistributedOptimizer(optax.sgd(0.1), sharded_update=True,
+                                   num_shards=n, min_shard_elems=0)
     state = opt.init(params)
 
     def step(p, s):

@@ -283,6 +283,109 @@ def test_unfused_exchange_counts_a_collective_per_leaf():
     assert {"grad_exchange", "optimizer"} <= phases
 
 
+def _exchange_reading(wrapper):
+    """What one of ``opt/``'s exchange builders puts under
+    ``hvd.grad_exchange`` on a four-member axis, for a tree with two
+    dtypes, leaves the quantized wire's guardrails keep off it and a
+    scalar: the counters, and the instructions of the compiled module
+    by part (``"<stem of the name>:<how many>"``, sorted)."""
+    from jax.sharding import Mesh
+
+    kw = {"fused": {},
+          "int8": {"compression": hvd.Compression.int8.with_options(
+              error_feedback=False)},
+          "int8_ef": {"compression": hvd.Compression.int8},
+          "zero1": {"sharded_update": True, "num_shards": 4,
+                    "min_shard_elems": 100}}[wrapper]
+    opt = hvd.DistributedOptimizer(optax.sgd(0.1), **kw)
+    params = {"dense": {"kernel": jnp.ones((96, 64)), "bias": jnp.ones(64)},
+              "wide": {"kernel": jnp.ones((71, 59))},  # 10,333 with dense's
+              "proj": {"kernel": jnp.ones((80, 64), jnp.bfloat16)},
+              "norm": {"scale": jnp.ones(64, jnp.bfloat16)},
+              "step": jnp.ones(())}
+
+    def step(params, opt_state, x):
+        grads = jax.grad(lambda p: jnp.sum(x) * sum(
+            jnp.sum(l.astype(jnp.float32) ** 2)
+            for l in jax.tree.leaves(p)))(params)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, jnp.sum(x)
+
+    step = data_parallel_step(step, mesh=Mesh(jax.devices()[:4], ("hvd",)))
+    step.lower(params, opt.init(params), jnp.ones((8, 2)))
+    count = collections.defaultdict(collections.Counter)
+    for name, op_name in dp.scope_table(step).items():
+        part = scopes.part_of(op_name)
+        if part and part.startswith(scopes.GRAD_EXCHANGE):
+            count[part.rsplit("/", 1)[1]][name.rstrip("0123456789.")] += 1
+    return dp.step_counters(step), {
+        part: " ".join(f"{k}:{n}" for k, n in sorted(names.items()))
+        for part, names in count.items()}
+
+
+@pytest.mark.parametrize("wrapper", ["fused", "int8", "int8_ef", "zero1"])
+def test_every_exchange_builder_keeps_its_scopes_and_counters(wrapper):
+    """``collectives`` and ``collective_bytes`` are PR 30's tree's (the
+    parent of the PR that gave the builders one packer); so are the
+    instructions, and ``packed_bytes`` but for ZeRO-1's (see the pins)."""
+    counters, instructions = _exchange_reading(wrapper)
+    want_counters, want_instructions = _EXCHANGE_PINS[wrapper]
+    assert counters == dict(zip(
+        ("collectives", "collective_bytes", "packed_bytes", "axis_size"),
+        want_counters))
+    assert instructions == want_instructions
+
+
+#: (collectives, collective_bytes, packed_bytes, axis_size), instructions.
+#: ZeRO-1's ``packed_bytes`` read 51,584 at the parent, which counted its
+#: one-leaf bfloat16 group as packed; `_pack`'s rule (more than one leaf)
+#: is the plain fused path's, the one the benchmark's cells read.
+_EXCHANGE_PINS = {
+    "fused": ((2, 51960, 51960, 4), {
+        "pack": "bitcast_concatenate_fusion:2 concatenate:2",
+        "reduce": "add:1 add_convert_fusion:2 all-reduce:1 "
+                  "bitcast_add_fusion:3 broadcast:6 convert:2 "
+                  "get-tuple-element:2 multiply:6 multiply_add_fusion:1 "
+                  "psum:2",
+        "unpack": "slice:6"}),
+    "int8": ((3, 51960, 51960, 4), {
+        "pack": "bitcast_concatenate_fusion:2 concatenate:3",
+        "reduce": "abs:2 add:1 add_convert_fusion:2 all-reduce:1 all-to-all:2 "
+                  "all_gather:2 bitcast:13 bitcast_abs_fusion:1 "
+                  "bitcast_add_fusion:3 bitcast_slice_fusion:3 broadcast:11 "
+                  "broadcast_divide_fusion:1 broadcast_in_dim:7 clamp:5 "
+                  "concatenate:2 concatenate_pad_fusion:1 convert:4 "
+                  "convert_bitcast_fusion:2 convert_convert_fusion:4 "
+                  "convert_element_type:20 div:4 get-tuple-element:2 gt:12 max:5 "
+                  "min:5 mul:6 multiply:13 multiply_abs_fusion:1 "
+                  "multiply_add_fusion:1 multiply_reduce_fusion:1 pad:1 psum:2 "
+                  "reduce:1 reduce_max:14 reduce_sum:3 round:5 select_n:7 slice:8 "
+                  "slice_bitcast_fusion:1 wrapped_reduce:2",
+        "unpack": "slice:6"}),
+    "int8_ef": ((3, 51960, 51960, 4), {
+        "pack": "bitcast_concatenate_fusion:2 concatenate:3",
+        "reduce": "abs:2 add:2 add_convert_fusion:2 add_pad_fusion:1 all-reduce:1 "
+                  "all-to-all:2 all_gather:2 bitcast:16 bitcast_abs_fusion:1 "
+                  "bitcast_add_fusion:3 bitcast_slice_fusion:4 broadcast:12 "
+                  "broadcast_divide_fusion:1 broadcast_in_dim:8 clamp:6 "
+                  "concatenate:2 convert:4 convert_bitcast_fusion:2 "
+                  "convert_convert_fusion:4 convert_element_type:24 div:5 "
+                  "get-tuple-element:2 gt:14 max:6 min:6 mul:7 multiply:14 "
+                  "multiply_abs_fusion:1 multiply_add_fusion:1 "
+                  "multiply_reduce_fusion:1 pad:1 psum:2 reduce:1 reduce_max:14 "
+                  "reduce_sum:3 round:6 select_n:8 slice:9 slice_bitcast_fusion:1 "
+                  "sub:1 wrapped_reduce:2",
+        "unpack": "slice:6"}),
+    "zero1": ((7, 64868, 41344, 4), {
+        "pack": "bitcast:1 concatenate:1 concatenate_pad_fusion:1 "
+                "convert_bitcast_fusion:1 pad:1",
+        "reduce": "add:2 add_convert_fusion:1 all-reduce:1 all_gather:2 "
+                  "broadcast:1 convert:2 get-tuple-element:3 multiply:1 "
+                  "psum:2 reduce_scatter:4",
+        "unpack": "bitcast:2 bitcast_add_fusion:2 slice:2"}),
+}
+
+
 @pytest.mark.parametrize("build", ["dense_lm", "fused", "unfused"])
 def test_one_member_axis_builds_no_exchange(build):
     """On a one-device mesh the wrapper builds nothing around the

@@ -583,7 +583,19 @@ def test_gt_routing_rejects_incompatible_knobs():
             compression=hvd.Compression.bf16)
 
 
-def test_torch_sharded_matches_plain_world1():
+@pytest.fixture
+def one_torch_thread():
+    """Bitwise comparisons of two torch models: hold torch to one
+    intra-op thread, so that no kernel's reduction order can follow the
+    machine's load (MKL and OpenMP pick their thread count dynamically)."""
+    torch = pytest.importorskip("torch")
+    before = torch.get_num_threads()
+    torch.set_num_threads(1)
+    yield
+    torch.set_num_threads(before)
+
+
+def test_torch_sharded_matches_plain_world1(one_torch_thread):
     torch = pytest.importorskip("torch")
     import horovod_tpu.torch as hvdt
 
