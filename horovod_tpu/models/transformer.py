@@ -222,6 +222,10 @@ def apply(params, tokens, cfg: TransformerConfig, *, use_constraints: bool = Tru
     # the fused kernels' tile shape, where the default attention takes them
     blocks = None if attn_fn else fused_attention_blocks(
         tokens.shape[1], cfg.head_dim, use_constraints)
+    # a checkpointed block keeps what the fused backward kernels read (the
+    # arrays `flash_attention` names) and recomputes the rest; where no
+    # kernel runs nothing carries a name and nothing is kept
+    keeps = cfg.remat and blocks is not None
     kinds = [cfg.layer_kind(i) for i in range(cfg.n_layers)]
     rotary = (_rope_tables(positions, cfg.head_dim, cfg.rope_theta)
               if any(rope for _, rope in kinds) else None)
@@ -238,7 +242,7 @@ def apply(params, tokens, cfg: TransformerConfig, *, use_constraints: bool = Tru
             h = _rmsnorm(x, blk["ln1"]["scale"])
             if attn_fn is None:
                 scopes.note_attention(kernel=blocks is not None,
-                                      window=window is not None)
+                                      window=window is not None, kept=keeps)
             if blocks is not None:
                 o = _fused_attention(h, blk, cfg, blocks, window,
                                      rotary if rope else None)
@@ -278,8 +282,11 @@ def apply(params, tokens, cfg: TransformerConfig, *, use_constraints: bool = Tru
         if kind not in block_fns:
             fn = _block if kind == (None, False) else functools.partial(
                 _block, window=kind[0], rope=kind[1])
-            block_fns[kind] = jax.checkpoint(fn) if cfg.remat else fn
+            block_fns[kind] = jax.checkpoint(
+                fn, policy=_KEEP_KERNEL_RESIDUALS) if cfg.remat else fn
         x = block_fns[kind](x, blk)
+        if keeps:
+            scopes.note_kept(_kept_bytes(tokens.shape, cfg))
         if cfg.n_experts:
             x, chosen = x
             routing.append(chosen)
@@ -392,6 +399,19 @@ def fused_attention_blocks(s: int, head_dim: int, use_constraints: bool):
     from ..ops.pallas.flash_attention import block_sizes
 
     return block_sizes(s, head_dim)
+
+
+_KEEP_KERNEL_RESIDUALS = jax.checkpoint_policies.save_only_these_names(
+    *scopes.KEPT_BY_REMAT)
+
+
+def _kept_bytes(shape, cfg: TransformerConfig) -> int:
+    """Bytes of `flash_attention`'s residuals for one block on ``shape``
+    = (b, s) tokens: q and o [b, s, heads*hd], k and v [b, s, kv*hd] in
+    ``cfg.dtype``, lse [b*heads, 1, s] float32."""
+    b, s = shape
+    wide = 2 * (cfg.n_heads + cfg.kv_heads) * cfg.head_dim
+    return b * s * (wide * jnp.dtype(cfg.dtype).itemsize + 4 * cfg.n_heads)
 
 
 def _fused_attention(h, blk, cfg: TransformerConfig, blocks, window=None,

@@ -16,7 +16,9 @@ Backward has two paths, by what the caller differentiates:
 
 - ``flash_attention`` (cotangent of ``o`` alone; the decoder's path):
   two Pallas kernels. Residuals are ``(q, k, v, o, lse)`` with ``lse = m
-  + log l`` from the forward's own outputs. ``hvd_flash_bwd_dq`` walks
+  + log l`` from the forward's own outputs, each under its name of
+  ``scopes.KEPT_BY_REMAT``: a caller's ``jax.checkpoint`` that saves
+  those names runs the forward kernel once. ``hvd_flash_bwd_dq`` walks
   Q blocks and accumulates ``dq`` over the K/V blocks; it holds ``do``
   and ``o`` of its block, so it also makes ``delta = rowsum(do * o)``
   and hands it on. ``hvd_flash_bwd_dkv`` walks K/V blocks and
@@ -52,7 +54,7 @@ import sys
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import ad_checkpoint, lax
 
 from ...utils import scopes
 
@@ -778,6 +780,13 @@ def _fwd(q, k, v, causal, block_q, block_k, heads, window, kv_heads):
     with _pinned_mesh():
         o, lse = _flash_fwd_lse(q, k, v, causal, block_q, block_k, heads,
                                 window, kv_heads)
+    # named, so that a ``jax.checkpoint`` around the caller can keep what
+    # the backward kernels read (``save_only_these_names``) and not run
+    # this forward a second time; the identity outside a checkpoint. The
+    # caller gets the named ``o``: what it does with the output (its
+    # projection's weight gradient) then reads the kept array too
+    q, k, v, o, lse = map(ad_checkpoint.checkpoint_name, (q, k, v, o, lse),
+                          scopes.KEPT_BY_REMAT)
     return o, (q, k, v, o, lse)
 
 

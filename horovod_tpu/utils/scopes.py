@@ -28,13 +28,22 @@ Scopes the program sets (the only place their names are written):
 Forward, backward and recompute need no scope: JAX marks them itself
 (``jvp(``, ``transpose(``, ``rematted_computation``).
 
+Names the program gives arrays (``jax.ad_checkpoint.checkpoint_name``;
+the identity outside a ``jax.checkpoint``): `KEPT_BY_REMAT`, what the
+fused attention's backward kernels read (``hvd.attention/q|k|v|o|lse``,
+named in ``ops/pallas/flash_attention.py``'s forward rule). A decoder
+block under ``remat`` keeps these and recomputes the rest.
+
 Counters (`StepRecord.counters`, noted once while a step is traced, so
 per step and per chip): ``collectives`` the exchange issued,
 ``collective_bytes`` handed to them, ``packed_bytes`` copied into flat
 buffers, ``axis_size``; ``attention_calls`` the decoder's default attention
 traced and ``attention_kernel_calls`` of them routed to the fused
 kernels (a share of the two survives retracing under ``jax.checkpoint``),
-``attention_window_calls`` of them with a window; of a sparse-expert
+``attention_window_calls`` of them with a window, ``attention_kept_calls``
+of them in a checkpointed block that keeps the kernels' residuals, and
+``remat_kept_mb``, those residuals' bytes / 1e6 over the blocks traced
+(a count: each block's call is noted once); of a sparse-expert
 decoder ``moe_layers``, ``experts_held`` of ``experts_total`` in each,
 ``experts_per_token`` chosen, and ``moe_buffer_rows``, the bound its expert layer's buffers are sized for
 (per layer: tokens times the most experts one token can have here).
@@ -64,6 +73,10 @@ MLP = MODEL + "/mlp"
 HEAD = MODEL + "/head"
 ROUTER = MODEL + "/router"
 MOE = MODEL + "/moe"
+
+#: `flash_attention`'s residuals, by the names its forward rule gives them
+KEPT_BY_REMAT = tuple("hvd.attention/" + a
+                      for a in ("q", "k", "v", "o", "lse"))
 
 #: in `phase_of`'s order of precedence
 PHASES = ("grad_exchange", "optimizer", "recompute", "backward", "forward",
@@ -231,12 +244,15 @@ def note_exchange(buffers, axis_name: str, packed: bool = False) -> None:
     c["axis_size"] = int(jax.lax.axis_size(axis_name))
 
 
-def note_attention(kernel: bool, window: bool = False) -> None:
+def note_attention(kernel: bool, window: bool = False,
+                   kept: bool = False) -> None:
     """Called where ``models/transformer.py`` routes one default
     attention call, to the fused kernels or to `causal_attention`, with
-    a window or without. A block under ``jax.checkpoint`` is traced more
-    than once, so read the counters as shares of ``attention_calls``. A
-    no-op outside a traced ``data_parallel_step``."""
+    a window or without; ``kept``: in a checkpointed block whose policy
+    keeps the kernels' residuals (`KEPT_BY_REMAT`). A block under
+    ``jax.checkpoint`` is traced once or more than once, so read the
+    counters as shares of ``attention_calls``. A no-op outside a traced
+    ``data_parallel_step``."""
     record = _tracing.get()
     if record is None:
         return
@@ -244,8 +260,22 @@ def note_attention(kernel: bool, window: bool = False) -> None:
     c["attention_calls"] = c.get("attention_calls", 0) + 1
     c["attention_kernel_calls"] = (c.get("attention_kernel_calls", 0)
                                    + int(kernel))
+    c["attention_kept_calls"] = c.get("attention_kept_calls", 0) + int(kept)
+    c.setdefault("remat_kept_mb", 0.0)
     if window:  # a decoder without windows keeps the counters it had
         c["attention_window_calls"] = c.get("attention_window_calls", 0) + 1
+
+
+def note_kept(nbytes: int) -> None:
+    """Called once per checkpointed block that keeps `KEPT_BY_REMAT`,
+    where ``models/transformer.py`` lays its blocks out (outside
+    ``jax.checkpoint``, so ``remat_kept_mb`` is a sum over the blocks),
+    with the bytes of the arrays kept. A no-op outside a traced
+    ``data_parallel_step``."""
+    record = _tracing.get()
+    if record is not None:
+        c = record.counters
+        c["remat_kept_mb"] = c.get("remat_kept_mb", 0.0) + nbytes / 1e6
 
 
 def note_moe(held: int, total: int, per_token: int,

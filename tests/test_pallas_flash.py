@@ -223,6 +223,42 @@ def test_decoder_on_the_kernels_agrees_with_causal_attention(monkeypatch):
     assert share == 0
 
 
+def test_checkpoint_that_keeps_the_residuals_runs_the_forward_once():
+    """A decoder-block-shaped function (norm, three projections, the
+    kernels, the output projection, a residual) under ``jax.checkpoint``
+    with the policy that saves what `flash_attention`'s forward rule
+    names: its gradients are, bit for bit, those under a bare checkpoint
+    and under none (the kept ``o`` and ``lse`` are the arrays a second
+    run would make), and its lowered text calls the forward kernel's
+    entry once where the bare checkpoint's calls it twice."""
+    from horovod_tpu.utils import scopes
+
+    rng = np.random.RandomState(0)
+    x = jnp.asarray(rng.randn(1, 256, 256), jnp.float32)
+    w = {n: jnp.asarray(rng.randn(256, 256) * 0.05, jnp.float32)
+         for n in "qkvo"}
+
+    def block(x, w):
+        h = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6)
+        q, k, v = (h @ w[n] for n in "qkv")
+        return x + flash_attention(q, k, v, True, 128, 128, 2) @ w["o"]
+
+    policy = jax.checkpoint_policies.save_only_these_names(
+        *scopes.KEPT_BY_REMAT)
+    grads, forwards = {}, {}
+    for name, fn in (("none", block), ("bare", jax.checkpoint(block)),
+                     ("kept", jax.checkpoint(block, policy=policy))):
+        grad = jax.jit(jax.grad(lambda x, w, fn=fn: jnp.sum(fn(x, w) ** 2),
+                                argnums=(0, 1)))
+        forwards[name] = grad.lower(x, w).as_text().count(
+            "call @_flash_fwd_lse")
+        grads[name] = jax.tree.leaves(grad(x, w))
+    assert forwards == {"none": 1, "bare": 2, "kept": 1}
+    for name in ("bare", "kept"):
+        for got, want in zip(grads[name], grads["none"]):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_attention_stats_contract(qkv):
     """(o, m, l) stats: o normalized, exp-renormalization reconstructs the
     unnormalized accumulator (the ring-combination contract)."""

@@ -247,10 +247,13 @@ def test_a_traced_step_describes_itself(build, compiles):
     # the fused kernels (jax.checkpoint traces a block once or several times, so
     # only the share of the two means anything)
     attention = {k: counters.pop(k) for k in list(counters)
-                 if k.startswith("attention")}
+                 if k.startswith(("attention", "remat"))}
     if build is _lm_step:
         assert attention["attention_calls"] >= 1
         assert attention["attention_kernel_calls"] == 0
+        # no kernel, so nothing named for the blocks' checkpoint to keep
+        assert attention["attention_kept_calls"] == 0
+        assert attention["remat_kept_mb"] == 0.0
     else:
         assert attention == {}
     assert counters == {
@@ -412,7 +415,7 @@ def test_one_member_axis_builds_no_exchange(build):
         phases = {"optimizer"}
     text = step.lower(*args).as_text(debug_info=True)
     counters = {k: v for k, v in dp.step_counters(step).items()
-                if not k.startswith("attention")}
+                if not k.startswith(("attention", "remat"))}
     assert counters == {"collectives": 0, "collective_bytes": 0,
                         "packed_bytes": 0, "axis_size": 1}
     table = dp.scope_table(step)
@@ -429,16 +432,66 @@ def test_one_member_axis_builds_no_exchange(build):
     ([True, True, True], {"attention_calls": 3, "attention_kernel_calls": 3}),
     ([False, False], {"attention_calls": 2, "attention_kernel_calls": 0}),
     ([True, False], {"attention_calls": 2, "attention_kernel_calls": 1}),
-    ([], {}),
+    ([], None),
 ], ids=["kernels", "einsum", "mixed", "no-decoder"])
 def test_attention_counters_note_where_each_call_went(routed, want):
     """`note_attention` counts into the step being traced, the calls and
     those of them the fused kernels took (``attention_kernel_pct`` is
-    their share), and is a no-op with no step being traced."""
+    their share), with zeros for what no checkpoint kept, and is a no-op
+    with no step being traced."""
+    want = {} if want is None else dict(
+        want, attention_kept_calls=0, remat_kept_mb=0.0)
     record = scopes.StepRecord()
     with scopes.recording(record):
         for kernel in routed:
             scopes.note_attention(kernel=kernel)
-    assert record.counters == want
     scopes.note_attention(kernel=True)   # outside a traced step
     assert record.counters == want
+
+
+@pytest.mark.parametrize("path,remat,kept", [
+    ("kernels", True, True), ("einsum", True, False),
+    ("kernels", False, False)], ids=["kept", "einsum", "no-remat"])
+def test_kept_counters_say_what_the_blocks_checkpoint_keeps(
+        path, remat, kept, monkeypatch):
+    """A checkpointed block keeps what the fused backward kernels read,
+    and the step says so: every attention call noted as kept, and
+    ``remat_kept_mb`` the bytes of `flash_attention`'s five residuals
+    over the blocks (here against the arrays its forward rule really
+    hands on). On the einsum path nothing carries a name and without
+    ``remat`` there is no checkpoint: 0 and 0.0, numbers both."""
+    import importlib
+
+    from jax.sharding import Mesh
+
+    F = importlib.import_module("horovod_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(T, "_on_tpu", lambda: path == "kernels")
+    monkeypatch.setattr(T, "FUSED_ATTENTION_MIN_SEQ", 256)
+    monkeypatch.setattr(F, "BLOCKS", (128,))
+    cfg = T.TransformerConfig(vocab_size=64, d_model=256, n_heads=2,
+                              n_kv_heads=1, n_layers=3, d_ff=64, max_seq=256,
+                              remat=remat)
+    step = data_parallel_step(
+        lambda p, t: (p, jax.grad(T.lm_loss)(p, t, cfg,
+                                             use_constraints=False)),
+        mesh=Mesh(jax.devices()[:1], ("hvd",)), batch_argnums=(1,),
+        donate_argnums=())
+    step.lower(jax.eval_shape(lambda: T.init(jax.random.PRNGKey(0), cfg)),
+               jax.ShapeDtypeStruct((2, 257), jnp.int32))
+    counters = dp.step_counters(step)
+    assert counters["attention_kernel_calls"] == (
+        counters["attention_calls"] if path == "kernels" else 0)
+    if not kept:
+        assert (counters["attention_kept_calls"],
+                counters["remat_kept_mb"]) == (0, 0.0)
+        assert isinstance(counters["remat_kept_mb"], float)
+        return
+    assert counters["attention_kept_calls"] == counters["attention_calls"] > 0
+    q, k = (jax.ShapeDtypeStruct((2, 256, heads * 128), cfg.dtype)
+            for heads in (2, 1))
+    residuals = jax.eval_shape(
+        lambda q, k, v: F._fwd(q, k, v, True, 128, 128, 2, None, 1)[1],
+        q, k, k)
+    a_block = sum(r.size * r.dtype.itemsize for r in residuals)
+    assert a_block == 2 * 256 * (2 * (256 + 128) * 2 + 4 * 2)
+    assert counters["remat_kept_mb"] == pytest.approx(3 * a_block / 1e6)

@@ -206,7 +206,8 @@ def test_toy_lm_step_carries_every_phase_on_four_chips(topo, seq):
     instructions a trace's ``XLA Ops`` events are named by), and an
     instruction's name identifies it within the module. At 512
     positions the decoder's rule takes the fused kernels: their calls
-    carry the attention scope in all three phases."""
+    carry the attention scope, forward and backward, and the ``recompute``
+    phase (norms, output projection, MLP) holds none of them."""
     import optax
 
     import horovod_tpu as hvd
@@ -254,12 +255,15 @@ def test_toy_lm_step_carries_every_phase_on_four_chips(topo, seq):
     assert counters["attention_kernel_calls"] == (
         counters["attention_calls"] if seq >= T.FUSED_ATTENTION_MIN_SEQ
         else 0)
+    # what the backward kernels read is kept (the checkpoint's policy),
+    # so no forward kernel stands in the recomputation
     assert kernels == ({
         ("hvd_flash_fwd", "forward", scopes.ATTENTION),
-        ("hvd_flash_fwd", "recompute", scopes.ATTENTION),
         ("hvd_flash_bwd_dq", "backward", scopes.ATTENTION),
         ("hvd_flash_bwd_dkv", "backward", scopes.ATTENTION),
     } if seq >= T.FUSED_ATTENTION_MIN_SEQ else set())
+    assert counters["attention_kept_calls"] == counters[
+        "attention_kernel_calls"]
 
 
 def test_toy_lm_step_builds_no_gradient_exchange_on_one_chip(topo):
@@ -313,12 +317,12 @@ def test_checkpointed_decoder_traces_and_lowers_each_kernel_once(
     through ``apply``, as the LM cell's reference check runs the loss (a
     bare ``jit`` on one device): each kernel's body is traced once, and
     the module carries exactly three distinct ``tpu_custom_call`` bodies
-    under the three kernel names. Every block, the recomputation and both
-    directions call the jitted entries of ops/pallas/flash_attention.py,
-    primal and VJP forward the same one. (The forward's body stands
-    under two ``jit`` wrappers in the text, because JAX's dead-code pass
-    drops ``lse`` from the forward pass's instance; it is one lowering.)
-    Compiled, the kernels' instructions carry the attention scope."""
+    under the three kernel names. Every block and both directions call
+    the jitted entries of ops/pallas/flash_attention.py; the blocks'
+    checkpoint keeps what the backward kernels read, so the forward pass
+    writes ``lse`` and the recomputation holds no kernel. Compiled,
+    there are three kernel instructions a layer, under the attention
+    scope."""
     import collections
     import functools
     import re
@@ -362,7 +366,7 @@ def test_checkpointed_decoder_traces_and_lowers_each_kernel_once(
 
     kernels = {name: op for name, op in scopes.instruction_scopes(
         lowered.compile().as_text()).items() if name.startswith("hvd_flash")}
-    assert len(kernels) == 4 * cfg.n_layers   # forward, recomputed, dq, dkv
+    assert len(kernels) == 3 * cfg.n_layers   # forward, dq, dkv
     assert all(scopes.part_of(op) == scopes.ATTENTION
                for op in kernels.values()), kernels
 
