@@ -33,6 +33,21 @@ block's *input*, ReGLU experts, of which this model holds
 ``experts_held``: ``parallel/moe.py``); and an untied classifier
 (``tie_embeddings=False``). ``vocab_size`` is the rows held: a sliced
 vocabulary is a smaller vocabulary.
+
+Further settings of the same block (a DeepSeek-V3-shaped decoder takes
+them all): **latent attention** (``kv_latent`` > 0: keys and values are
+expanded per head from one normed latent of that width; a head's
+query/key is ``d_head`` columns without positions and ``d_rope`` rotary
+ones, the rotary key being one array all heads share; its value is
+``d_head`` wide); a **gated dense feed-forward** (``mlp="gated"``: SiLU
+gate, up, down, ``d_ff`` wide); **leading dense layers** before the
+expert layers (``n_dense_layers``); **shared experts** (one gated MLP of
+``n_shared_experts * d_expert`` on every token, beside the routed ones);
+and the router's **scoring** (``router_scoring="sigmoid"``: a
+correction bias enters the choice only, the weights are the sigmoids
+normalised over the chosen times ``routed_scale``), its **input**
+(``router_input="normed"``: the expert layer's normed input) and the
+routed experts' **activation** (``expert_activation="silu"``).
 """
 
 from __future__ import annotations
@@ -83,6 +98,15 @@ class TransformerConfig:
     d_expert: int = 0
     experts_held: Optional[tuple] = None   # (first, count); None: all
     tie_embeddings: bool = True
+    kv_latent: int = 0                     # latent heads: the latent's width
+    d_rope: int = 0                        # a latent head's rotary columns
+    mlp: str = "gelu"                      # dense feed-forward; or "gated"
+    n_dense_layers: int = 0                # leading layers without experts
+    n_shared_experts: int = 0              # of d_expert each, as one MLP
+    router_scoring: str = "softmax"        # or "sigmoid" (bias-corrected)
+    router_input: str = "block"            # or "normed": as the experts'
+    routed_scale: float = 1.0              # on the sigmoid scoring's weights
+    expert_activation: str = "relu"        # or "silu"
 
     @property
     def head_dim(self) -> int:
@@ -97,6 +121,10 @@ class TransformerConfig:
         """``(first, count)`` of the experts this model holds."""
         return self.experts_held or (0, self.n_experts)
 
+    def has_experts(self, i: int) -> bool:
+        """Is layer ``i`` an expert layer (after the leading dense ones)?"""
+        return bool(self.n_experts) and i >= self.n_dense_layers
+
     def layer_kind(self, i: int) -> tuple:
         """Layer ``i``'s ``(window or None, rotary positions?)``."""
         def at(layout):
@@ -109,36 +137,68 @@ class TransformerConfig:
 def init(rng, cfg: TransformerConfig):
     keys = jax.random.split(rng, 4 + cfg.n_layers)
     s = 0.02
+
+    def normal(key, *shape):
+        return s * jax.random.normal(key, shape, jnp.float32)
+
+    def gated(k, width):
+        return {"gate": normal(k[0], cfg.d_model, width),
+                "up": normal(k[1], cfg.d_model, width),
+                "down": normal(k[2], width, cfg.d_model)}
+
     params = {
-        "embed": s * jax.random.normal(keys[0], (cfg.vocab_size, cfg.d_model), jnp.float32),
+        "embed": normal(keys[0], cfg.vocab_size, cfg.d_model),
         "ln_f": {"scale": jnp.ones((cfg.d_model,), jnp.float32)},
         "blocks": [],
     }
     if cfg.positions == "learned":
-        params["pos"] = s * jax.random.normal(keys[1], (cfg.max_seq, cfg.d_model), jnp.float32)
+        params["pos"] = normal(keys[1], cfg.max_seq, cfg.d_model)
     if not cfg.tie_embeddings:
-        params["head"] = s * jax.random.normal(keys[2], (cfg.d_model, cfg.vocab_size), jnp.float32)
+        params["head"] = normal(keys[2], cfg.d_model, cfg.vocab_size)
     held = cfg.held[1]
+    # as many keys a layer as the first decoders drew, so that a seed
+    # still gives them the weights it gave; the further settings draw 18
+    further = (cfg.kv_latent or cfg.mlp != "gelu" or cfg.n_dense_layers
+               or cfg.n_shared_experts)
     for i in range(cfg.n_layers):
-        k = jax.random.split(keys[4 + i], 10 if cfg.n_experts else 6)
+        k = jax.random.split(
+            keys[4 + i], 18 if further else 10 if cfg.n_experts else 6)
         blk = {
             "ln1": {"scale": jnp.ones((cfg.d_model,), jnp.float32)},
             "ln2": {"scale": jnp.ones((cfg.d_model,), jnp.float32)},
-            "wq": s * jax.random.normal(k[0], (cfg.d_model, cfg.n_heads, cfg.head_dim), jnp.float32),
-            "wk": s * jax.random.normal(k[1], (cfg.d_model, cfg.kv_heads, cfg.head_dim), jnp.float32),
-            "wv": s * jax.random.normal(k[2], (cfg.d_model, cfg.kv_heads, cfg.head_dim), jnp.float32),
-            "wo": s * jax.random.normal(k[3], (cfg.n_heads, cfg.head_dim, cfg.d_model), jnp.float32),
+            "wo": normal(k[3], cfg.n_heads, cfg.head_dim, cfg.d_model),
         }
-        if cfg.n_experts:
-            blk["router"] = s * jax.random.normal(k[6], (cfg.d_model, cfg.n_experts), jnp.float32)
-            blk["experts"] = {
-                "gate": s * jax.random.normal(k[7], (held, cfg.d_model, cfg.d_expert), jnp.float32),
-                "up": s * jax.random.normal(k[8], (held, cfg.d_model, cfg.d_expert), jnp.float32),
-                "down": s * jax.random.normal(k[9], (held, cfg.d_expert, cfg.d_model), jnp.float32),
-            }
+        if cfg.kv_latent:
+            blk.update(
+                wq=normal(k[0], cfg.d_model, cfg.n_heads,
+                          cfg.head_dim + cfg.d_rope),
+                wkva=normal(k[1], cfg.d_model, cfg.kv_latent + cfg.d_rope),
+                ln_kv={"scale": jnp.ones((cfg.kv_latent,), jnp.float32)},
+                wkvb=normal(k[2], cfg.kv_latent, cfg.n_heads,
+                            2 * cfg.head_dim))
         else:
-            blk["w1"] = s * jax.random.normal(k[4], (cfg.d_model, cfg.d_ff), jnp.float32)
-            blk["w2"] = s * jax.random.normal(k[5], (cfg.d_ff, cfg.d_model), jnp.float32)
+            blk.update(
+                wq=normal(k[0], cfg.d_model, cfg.n_heads, cfg.head_dim),
+                wk=normal(k[1], cfg.d_model, cfg.kv_heads, cfg.head_dim),
+                wv=normal(k[2], cfg.d_model, cfg.kv_heads, cfg.head_dim))
+        if cfg.has_experts(i):
+            blk["router"] = normal(k[6], cfg.d_model, cfg.n_experts)
+            if cfg.router_scoring == "sigmoid":
+                # the choice's correction bias: no gradient reaches it
+                blk["router_bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
+            blk["experts"] = {
+                "gate": normal(k[7], held, cfg.d_model, cfg.d_expert),
+                "up": normal(k[8], held, cfg.d_model, cfg.d_expert),
+                "down": normal(k[9], held, cfg.d_expert, cfg.d_model),
+            }
+            if cfg.n_shared_experts:
+                blk["shared"] = gated(
+                    k[10:13], cfg.n_shared_experts * cfg.d_expert)
+        elif cfg.mlp == "gated":
+            blk["mlp"] = gated(k[13:16], cfg.d_ff)
+        else:
+            blk["w1"] = normal(k[4], cfg.d_model, cfg.d_ff)
+            blk["w2"] = normal(k[5], cfg.d_ff, cfg.d_model)
         params["blocks"].append(blk)
     return params
 
@@ -146,24 +206,38 @@ def init(rng, cfg: TransformerConfig):
 def param_specs(cfg: TransformerConfig):
     """PartitionSpec pytree matching `init` (for jit in_shardings)."""
     tp = cfg.tp_axis
-    block = {
-        "ln1": {"scale": P()},
-        "ln2": {"scale": P()},
-        "wq": P(None, tp, None),
-        "wk": P(None, tp, None),
-        "wv": P(None, tp, None),
-        "wo": P(tp, None, None),
-    }
-    if cfg.n_experts:  # the experts held are replicated: no GSPMD split
-        block["router"] = P(None, None)
-        block["experts"] = {w: P(None, None, None)
-                            for w in ("gate", "up", "down")}
-    else:
-        block.update(w1=P(None, tp), w2=P(tp, None))
+    gated = {"gate": P(None, tp), "up": P(None, tp), "down": P(tp, None)}
+
+    def block(i):
+        blk = {
+            "ln1": {"scale": P()},
+            "ln2": {"scale": P()},
+            "wq": P(None, tp, None),
+            "wo": P(tp, None, None),
+        }
+        if cfg.kv_latent:  # the latent is every head's: replicated
+            blk.update(wkva=P(None, None), ln_kv={"scale": P()},
+                       wkvb=P(None, tp, None))
+        else:
+            blk.update(wk=P(None, tp, None), wv=P(None, tp, None))
+        if cfg.has_experts(i):  # the experts held are replicated: no GSPMD split
+            blk["router"] = P(None, None)
+            if cfg.router_scoring == "sigmoid":
+                blk["router_bias"] = P(None)
+            blk["experts"] = {w: P(None, None, None)
+                              for w in ("gate", "up", "down")}
+            if cfg.n_shared_experts:
+                blk["shared"] = dict(gated)
+        elif cfg.mlp == "gated":
+            blk["mlp"] = dict(gated)
+        else:
+            blk.update(w1=P(None, tp), w2=P(tp, None))
+        return blk
+
     specs = {
         "embed": P(None, None),
         "ln_f": {"scale": P()},
-        "blocks": [dict(block) for _ in range(cfg.n_layers)],
+        "blocks": [block(i) for i in range(cfg.n_layers)],
     }
     if cfg.positions == "learned":
         specs["pos"] = P(None, None)
@@ -223,21 +297,23 @@ def apply(params, tokens, cfg: TransformerConfig, *, use_constraints: bool = Tru
     blocks = None if attn_fn else fused_attention_blocks(
         tokens.shape[1], cfg.head_dim, use_constraints)
     # a checkpointed block keeps what the fused backward kernels read (the
-    # arrays `flash_attention` names) and recomputes the rest; where no
-    # kernel runs nothing carries a name and nothing is kept
+    # arrays `flash_attention` names) and, of a router on the normed input,
+    # the choice (`_route`), and recomputes the rest; where no kernel runs
+    # the choice is all that carries a name
     keeps = cfg.remat and blocks is not None
+    keeps_choice = cfg.remat and cfg.router_input != "block"
     kinds = [cfg.layer_kind(i) for i in range(cfg.n_layers)]
-    rotary = (_rope_tables(positions, cfg.head_dim, cfg.rope_theta)
-              if any(rope for _, rope in kinds) else None)
-    if attn_fn is not None and any(window for window, _ in kinds):
-        raise ValueError("attn_fn takes no window: a windowed layer runs "
-                         "the default attention")
+    if cfg.kv_latent:  # a latent head's rotary columns, in every layer
+        rotary = _rope_tables(positions, cfg.d_rope, cfg.rope_theta)
+    else:
+        rotary = (_rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+                  if any(rope for _, rope in kinds) else None)
+    if any(window for window, _ in kinds) and (attn_fn or cfg.kv_latent):
+        raise ValueError("attn_fn and latent heads take no window: a "
+                         "windowed layer runs the default attention")
 
-    # the scopes sit inside the block, so they survive jax.checkpoint
-    def _block(x, blk, window=None, rope=False):
-        if cfg.n_experts:  # the router reads the block's input
-            with jax.named_scope(scopes.ROUTER):
-                routed = _route(x, blk["router"], cfg)
+    def _attend(x, blk, window, rope):
+        """x + the block's attention over heads of one width."""
         with jax.named_scope(scopes.ATTENTION):
             h = _rmsnorm(x, blk["ln1"]["scale"])
             if attn_fn is None:
@@ -257,22 +333,49 @@ def apply(params, tokens, cfg: TransformerConfig, *, use_constraints: bool = Tru
                 else:
                     o = attn_fn(q, *(_repeat_kv(a, cfg) for a in (k, v)))
                 o = jnp.einsum("bshk,hkd->bsd", o, blk["wo"].astype(cfg.dtype))
-            x = x + o
-        x = _constrain(x, aspec, use_constraints)
-        if cfg.n_experts:
-            with jax.named_scope(scopes.MOE):
-                from ..parallel import moe
+            return x + o
 
+    # the scopes sit inside the block, so they survive jax.checkpoint; what
+    # a layer's feed-forward is follows from the parameters it was given
+    def _block(x, blk, window=None, rope=False):
+        sparse = "experts" in blk
+        if sparse and cfg.router_input == "block":
+            with jax.named_scope(scopes.ROUTER):
+                routed = _route(x, blk, cfg)
+        if cfg.kv_latent:
+            if attn_fn is None:
+                scopes.note_attention(kernel=blocks is not None, kept=keeps,
+                                      latent=True)
+            x = _latent_attention(x, blk, cfg, blocks, rotary, attn_fn)
+        else:
+            x = _attend(x, blk, window, rope)
+        x = _constrain(x, aspec, use_constraints)
+        if sparse:
+            from ..parallel import moe
+
+            with jax.named_scope(scopes.MOE):
                 h = _rmsnorm(x, blk["ln2"]["scale"])
+            if cfg.router_input != "block":  # reads what the experts read
+                with jax.named_scope(scopes.ROUTER):
+                    routed = _route(h, blk, cfg)
+            with jax.named_scope(scopes.MOE):
                 ff = moe.expert_layer(h.reshape(-1, h.shape[-1]), *routed,
-                                      blk["experts"], cfg.held)
+                                      blk["experts"], cfg.held,
+                                      activation=cfg.expert_activation)
                 x = x + ff.reshape(h.shape)
+            if "shared" in blk:  # every token's, beside the routed ones
+                with jax.named_scope(scopes.SHARED_EXPERT):
+                    x = x + _gated_mlp(h, blk["shared"], cfg.dtype)
             return _constrain(x, aspec, use_constraints), routed[0]
         with jax.named_scope(scopes.MLP):
             h = _rmsnorm(x, blk["ln2"]["scale"])
-            ff = jax.nn.gelu(
-                jnp.einsum("bsd,df->bsf", h, blk["w1"].astype(cfg.dtype)))
-            ff = jnp.einsum("bsf,fd->bsd", ff, blk["w2"].astype(cfg.dtype))
+            if "mlp" in blk:
+                ff = _gated_mlp(h, blk["mlp"], cfg.dtype)
+            else:
+                ff = jax.nn.gelu(
+                    jnp.einsum("bsd,df->bsf", h, blk["w1"].astype(cfg.dtype)))
+                ff = jnp.einsum("bsf,fd->bsd", ff,
+                                blk["w2"].astype(cfg.dtype))
             x = x + ff
         return _constrain(x, aspec, use_constraints)
 
@@ -285,15 +388,21 @@ def apply(params, tokens, cfg: TransformerConfig, *, use_constraints: bool = Tru
             block_fns[kind] = jax.checkpoint(
                 fn, policy=_KEEP_KERNEL_RESIDUALS) if cfg.remat else fn
         x = block_fns[kind](x, blk)
-        if keeps:
-            scopes.note_kept(_kept_bytes(tokens.shape, cfg))
-        if cfg.n_experts:
+        kept = _kept_bytes(tokens.shape, cfg, keeps,
+                           keeps_choice and "experts" in blk)
+        if kept:
+            scopes.note_kept(kept)
+        if "experts" in blk:
             x, chosen = x
             routing.append(chosen)
             scopes.note_moe(
                 cfg.held[1], cfg.n_experts, cfg.experts_per_token,
                 x.shape[0] * x.shape[1]
                 * min(cfg.experts_per_token, cfg.held[1]))
+            if "shared" in blk:
+                scopes.note_layer("shared_experts")
+        elif cfg.n_experts:
+            scopes.note_layer("dense_layers")
     with jax.named_scope(scopes.HEAD):
         x = _rmsnorm(x, params["ln_f"]["scale"])
         if return_hidden:
@@ -307,18 +416,93 @@ def apply(params, tokens, cfg: TransformerConfig, *, use_constraints: bool = Tru
     return (out, routing) if return_routing else out
 
 
-def _route(x, router, cfg: TransformerConfig):
+def _route(x, blk, cfg: TransformerConfig):
     """A sparse-expert block's router on its input [b, s, d]: scores in
     float32 at the highest matmul precision (on a TPU a float32 product
     is bfloat16 passes unless told otherwise, and a choice between two
     near-equal scores should hang on as little rounding as it can), then
-    ``parallel.moe.route``: (chosen, weights), [b*s, k] each."""
+    ``parallel.moe.route`` by the configuration's scoring: (chosen,
+    weights), [b*s, k] each.
+
+    A router that reads the expert layer's normed input reads what a
+    checkpointed block's backward pass recomputes, and not bit for bit
+    (XLA fuses the recomputation otherwise): its choice is named
+    (`scopes.KEPT_CHOICE`), whatever the scoring, so that the block's
+    policy keeps it and both passes route alike. The block's own input
+    is what the checkpoint kept: a router on it needs no name, and gets
+    none."""
     from ..parallel import moe
 
     scores = jnp.einsum("td,de->te",
                         x.reshape(-1, x.shape[-1]).astype(jnp.float32),
-                        router, precision=jax.lax.Precision.HIGHEST)
-    return moe.route(scores, cfg.experts_per_token)
+                        blk["router"], precision=jax.lax.Precision.HIGHEST)
+    name = None if cfg.router_input == "block" else scopes.KEPT_CHOICE
+    if cfg.router_scoring == "softmax":
+        return moe.route(scores, cfg.experts_per_token, name=name)
+    return moe.route(scores, cfg.experts_per_token,
+                     scoring=cfg.router_scoring, bias=blk["router_bias"],
+                     scale=cfg.routed_scale, name=name)
+
+
+def _gated_mlp(h, weights, dtype):
+    """``(silu(h G) * (h U)) D``: the dense gated feed-forward and the
+    shared experts."""
+    gate, up, down = (weights[w].astype(dtype) for w in ("gate", "up", "down"))
+    ff = (jax.nn.silu(jnp.einsum("bsd,df->bsf", h, gate))
+          * jnp.einsum("bsd,df->bsf", h, up))
+    return jnp.einsum("bsf,fd->bsd", ff, down)
+
+
+def _latent_attention(x, blk, cfg: TransformerConfig, blocks, rotary,
+                      attn_fn=None):
+    """``x`` + its attention over latent (MLA) heads, [b, s, d]. Keys and
+    values are expanded per head from one normed latent ``c``; a head's
+    score is its ``d_head`` columns without positions against its own
+    key plus its ``d_rope`` rotary columns against the one rotary key all
+    heads share, over ``sqrt(d_head + d_rope)``. With ``blocks`` through
+    the fused kernels (``ops/pallas/flash_attention.py
+    latent_attention``: the shared key is never copied per head), else
+    `causal_attention` (or ``attn_fn``) on heads put together. What
+    makes the latent and expands it runs under ``hvd.model/latent``;
+    the queries, the kernels and the output projection under
+    ``hvd.model/attention`` (side by side: a nested scope would be filed
+    under its parent)."""
+    dt, hd, latent = cfg.dtype, cfg.head_dim, cfg.kv_latent
+    with jax.named_scope(scopes.ATTENTION):
+        h = _rmsnorm(x, blk["ln1"]["scale"])
+    with jax.named_scope(scopes.LATENT):
+        kva = jnp.einsum("bsd,de->bse", h, blk["wkva"].astype(dt))
+        c = _rmsnorm(kva[..., :latent], blk["ln_kv"]["scale"])
+        k_rope = _rope(kva[:, :, None, latent:], rotary)[:, :, 0]
+        wkvb = blk["wkvb"].astype(dt)
+        if blocks is not None:  # the heads side by side, as the kernels take
+            k, v = (jnp.einsum("bsc,ce->bse", c, w.reshape(latent, -1))
+                    for w in (wkvb[..., :hd], wkvb[..., hd:]))
+        else:
+            kv = jnp.einsum("bsc,chk->bshk", c, wkvb)
+            k, v = kv[..., :hd], kv[..., hd:]
+    with jax.named_scope(scopes.ATTENTION):
+        wq = blk["wq"].astype(dt)
+        if blocks is not None:
+            from ..ops.pallas.flash_attention import latent_attention
+
+            q = jnp.einsum("bsd,de->bse", h,
+                           wq[..., :hd].reshape(cfg.d_model, -1))
+            q_rope = _rope(jnp.einsum("bsd,dhk->bhsk", h, wq[..., hd:]),
+                           rotary, heads_first=True)
+            o = latent_attention(q, q_rope, k, k_rope, v, *blocks,
+                                 cfg.n_heads)
+            o = jnp.einsum("bse,ed->bsd", o,
+                           blk["wo"].astype(dt).reshape(-1, cfg.d_model))
+        else:
+            q = jnp.einsum("bsd,dhk->bshk", h, wq)
+            q = jnp.concatenate([q[..., :hd], _rope(q[..., hd:], rotary)],
+                                axis=-1)
+            k = jnp.concatenate([k, jnp.broadcast_to(
+                k_rope[:, :, None], (*k.shape[:3], cfg.d_rope))], axis=-1)
+            o = (attn_fn or causal_attention)(q, k, v)
+            o = jnp.einsum("bshk,hkd->bsd", o, blk["wo"].astype(dt))
+        return x + o
 
 
 def _rope_tables(positions, head_dim: int, theta: float):
@@ -332,9 +516,11 @@ def _rope_tables(positions, head_dim: int, theta: float):
     return jnp.cos(angle), jnp.sin(angle)
 
 
-def _rope(x, tables):
-    """Rotary positions on [b, s, h, hd] (in float32, back in x's type)."""
-    cos, sin = (t[None, :, None, :] for t in tables)
+def _rope(x, tables, heads_first: bool = False):
+    """Rotary positions on [b, s, h, hd], or ``heads_first`` on [b, h, s,
+    hd] (in float32, back in x's type)."""
+    cos, sin = (t[None, None] if heads_first else t[None, :, None, :]
+                for t in tables)
     x32 = x.astype(jnp.float32)
     half = x.shape[-1] // 2
     turned = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
@@ -402,16 +588,24 @@ def fused_attention_blocks(s: int, head_dim: int, use_constraints: bool):
 
 
 _KEEP_KERNEL_RESIDUALS = jax.checkpoint_policies.save_only_these_names(
-    *scopes.KEPT_BY_REMAT)
+    # `KEPT_BY_REMAT` and the rotary parts; a router's choice (`_route`)
+    *scopes.KEPT_BY_REMAT_LATENT, scopes.KEPT_CHOICE)
 
 
-def _kept_bytes(shape, cfg: TransformerConfig) -> int:
-    """Bytes of `flash_attention`'s residuals for one block on ``shape``
-    = (b, s) tokens: q and o [b, s, heads*hd], k and v [b, s, kv*hd] in
-    ``cfg.dtype``, lse [b*heads, 1, s] float32."""
+def _kept_bytes(shape, cfg: TransformerConfig, kernels: bool = True,
+                choice: bool = False) -> int:
+    """Bytes a checkpointed block keeps on ``shape`` = (b, s) tokens.
+    ``kernels``: `flash_attention`'s residuals, q and o [b, s, heads*hd],
+    k and v [b, s, kv*hd] in ``cfg.dtype``, lse [b*heads, 1, s] float32;
+    of `latent_attention`'s also q_rope [b, heads, s, d_rope] and the one
+    k_rope [b, s, d_rope]. ``choice``: its router's, [b*s, k] int32."""
     b, s = shape
     wide = 2 * (cfg.n_heads + cfg.kv_heads) * cfg.head_dim
-    return b * s * (wide * jnp.dtype(cfg.dtype).itemsize + 4 * cfg.n_heads)
+    if cfg.kv_latent:
+        wide += (cfg.n_heads + 1) * cfg.d_rope
+    per_token = kernels * (wide * jnp.dtype(cfg.dtype).itemsize
+                           + 4 * cfg.n_heads)
+    return b * s * (per_token + choice * 4 * cfg.experts_per_token)
 
 
 def _fused_attention(h, blk, cfg: TransformerConfig, blocks, window=None,

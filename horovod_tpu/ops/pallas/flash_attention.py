@@ -202,7 +202,7 @@ def _as_col(row):
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                       acc_scr, m_scr, l_scr, *, scale: float, causal: bool,
                       causal_offset: int, block_q: int, block_k: int,
-                      num_k_blocks: int, window=None):
+                      num_k_blocks: int, window=None, rotary=None):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -214,7 +214,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 
     def _block(masked: bool):
         # q [bq, d] x k [bk, d] -> [bq, bk]
-        s = lax.mul(_dot(q_ref[0], k_ref[0], _NT), scale)
+        s = _dot(q_ref[0], k_ref[0], _NT)
+        if rotary is not None:  # + q_rope [bq, r] x the shared k_rope [bk, r]
+            qr_ref, kr_ref = rotary
+            s = lax.add(s, _dot(qr_ref[0, 0], kr_ref[0], _NT))
+        s = lax.mul(s, scale)
         if masked:
             # causal_offset=0: standard (row >= col); =1: STRICT (row > col)
             # — striped ring attention's j>i rounds exclude the diagonal
@@ -269,7 +273,10 @@ def block_sizes(s: int, head_dim: int):
     """``(block_q, block_k)`` for a decoder's self-attention over ``s``
     positions with its heads side by side, ``head_dim`` wide each, or
     None where the TPU kernels cannot take that (a head that is no lane
-    multiple, a length none of `BLOCKS` tiles). The one place a caller
+    multiple, a length none of `BLOCKS` tiles). A latent head's
+    ``head_dim`` is its no-rope columns (and its value's): the rotary
+    columns come as arrays of their own (`latent_attention`), whatever
+    their width. The one place a caller
     with no reason of its own gets its tile shape (ring attention passes
     its own). Square, and the largest that tiles: on the v5e a larger
     tile beat a finer causal skip at every length tried (PERF.md, PR 26;
@@ -602,15 +609,21 @@ attention_stats.defvjp(_stats_fwd, _stats_bwd)
 
 
 def _p_and_ds(q, k, v, do, lse, delta, qi, ki, *, scale: float, masked: bool,
-              block_q: int, block_k: int, transposed: bool, window=None):
+              block_q: int, block_k: int, transposed: bool, window=None,
+              rotary=None):
     """One tile of the backward pass, recomputed from the forward's
     ``lse``: ``p = exp(s - lse)`` and ``ds = p * (dp - delta)`` (without
     the ``scale`` factor, which the caller applies once to its sum), both
     float32, [bq, bk] — or, ``transposed``, [bk, bq], a K row per
     sublane and a Q row per lane. ``lse``/``delta`` are per Q row:
-    [bq, 1] columns, or [1, bq] rows when transposed."""
+    [bq, 1] columns, or [1, bq] rows when transposed. ``rotary``: a
+    latent head's ``(q_rope [bq, r], k_rope [bk, r])``, the second part
+    of its score."""
     a, b, c, e = (k, q, v, do) if transposed else (q, k, do, v)
-    s = lax.mul(_dot(a, b, _NT), scale)
+    s = _dot(a, b, _NT)
+    if rotary is not None:
+        s = lax.add(s, _dot(*(rotary[::-1] if transposed else rotary), _NT))
+    s = lax.mul(s, scale)
     if masked:
         s = _causal(s, qi, ki, block_q, block_k, q_axis=int(transposed),
                     window=window)
@@ -621,7 +634,10 @@ def _p_and_ds(q, k, v, do, lse, delta, qi, ki, *, scale: float, masked: bool,
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_scr, dv_scr, *, scale: float,
                           causal: bool, block_q: int, block_k: int,
-                          num_q_blocks: int, window=None, group: int = 1):
+                          num_q_blocks: int, window=None, group: int = 1,
+                          rotary=None):
+    if rotary is not None:  # a latent head's rotary parts
+        qr_ref, kr_ref, dkr_ref, dkr_scr = rotary
     ki = pl.program_id(2)
     # the inner axis walks the Q blocks of each of the ``group`` query
     # heads that read this key/value head, one head after the other
@@ -632,6 +648,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dk_scr[...] = _zeros(dk_scr)
         dv_scr[...] = _zeros(dv_scr)
+        if rotary is not None:
+            dkr_scr[...] = _zeros(dkr_scr)
 
     def _block(masked: bool):
         # the tile is held TRANSPOSED: the per-Q-row statistics are
@@ -641,11 +659,16 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         pt, dst = _p_and_ds(
             q, k_ref[0], v_ref[0], do, lse_ref[0], delta_ref[0], qi, ki,
             scale=scale, masked=masked, block_q=block_q, block_k=block_k,
-            transposed=True, **({} if window is None else {"window": window}))
+            transposed=True, **({} if window is None else {"window": window}),
+            **({} if rotary is None else
+               {"rotary": (qr_ref[0, 0], kr_ref[0])}))
         dv_scr[...] = lax.add(dv_scr[...], _dot(
             lax.convert_element_type(pt, do.dtype), do, _NN))
-        dk_scr[...] = lax.add(dk_scr[...], _dot(
-            lax.convert_element_type(dst, q.dtype), q, _NN))
+        dk = dk_scr[...]
+        dst = lax.convert_element_type(dst, q.dtype)
+        dk_scr[...] = lax.add(dk, _dot(dst, q, _NN))
+        if rotary is not None:
+            dkr_scr[...] = lax.add(dkr_scr[...], _dot(dst, qr_ref[0, 0], _NN))
 
     _on_visible_tiles(_block, causal, qi, ki, block_q, block_k,
                       window=window)
@@ -655,18 +678,26 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_ref[0] = lax.convert_element_type(lax.mul(dk_scr[...], scale),
                                              dk_ref.dtype)
         dv_ref[0] = lax.convert_element_type(dv_scr[...], dv_ref.dtype)
+        if rotary is not None:
+            dkr_ref[0, 0] = lax.convert_element_type(
+                lax.mul(dkr_scr[...], scale), dkr_ref.dtype)
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                          dq_ref, delta_ref, dq_scr, lse_scr, delta_scr, *,
                          scale: float, causal: bool, block_q: int,
-                         block_k: int, num_k_blocks: int, window=None):
+                         block_k: int, num_k_blocks: int, window=None,
+                         rotary=None):
+    if rotary is not None:  # a latent head's rotary parts
+        qr_ref, kr_ref, dqr_ref, dqr_scr = rotary
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
     @pl.when(lax.eq(ki, 0))
     def _init():
         dq_scr[...] = _zeros(dq_scr)
+        if rotary is not None:
+            dqr_scr[...] = _zeros(dqr_scr)
         lse_scr[...] = _as_col(lse_ref[0])
         # delta = rowsum(do * o), once per Q block while both are here;
         # it leaves as a row too, for the dK/dV kernel
@@ -682,9 +713,14 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
             q_ref[0], k, v_ref[0], do_ref[0], lse_scr[...], delta_scr[...],
             qi, ki, scale=scale, masked=masked, block_q=block_q,
             block_k=block_k, transposed=False,
-            **({} if window is None else {"window": window}))
-        dq_scr[...] = lax.add(dq_scr[...], _dot(
-            lax.convert_element_type(ds, k.dtype), k, _NN))
+            **({} if window is None else {"window": window}),
+            **({} if rotary is None else
+               {"rotary": (qr_ref[0, 0], kr_ref[0])}))
+        dq = dq_scr[...]
+        ds = lax.convert_element_type(ds, k.dtype)
+        dq_scr[...] = lax.add(dq, _dot(ds, k, _NN))
+        if rotary is not None:
+            dqr_scr[...] = lax.add(dqr_scr[...], _dot(ds, kr_ref[0], _NN))
 
     _on_visible_tiles(_block, causal, qi, ki, block_q, block_k,
                       window=window)
@@ -693,6 +729,9 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     def _finalize():
         dq_ref[0] = lax.convert_element_type(lax.mul(dq_scr[...], scale),
                                              dq_ref.dtype)
+        if rotary is not None:
+            dqr_ref[0, 0] = lax.convert_element_type(
+                lax.mul(dqr_scr[...], scale), dqr_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
@@ -798,3 +837,186 @@ def _bwd(causal, block_q, block_k, heads, window, kv_heads, res, do):
 
 
 flash_attention.defvjp(_fwd, _bwd)
+
+
+# -- latent heads: a score of two parts, one rotary key for all heads -------
+
+def _rope_specs(bq: int, bk: int, r: int, q_block, k_block):
+    """BlockSpecs, over `_specs`' grid, of a latent head's rotary parts:
+    a block of one head's rows of q_rope [b, heads, sq, r] (the heads
+    lead: ``r`` is no lane multiple, and a block's last dimension is one
+    or the whole array's) and of the one k_rope [b, sk, r] every head
+    reads. dk_rope, per head, is laid out as q_rope is."""
+    return (pl.BlockSpec((1, 1, bq, r), lambda b, h, x, y: (
+                b, h, q_block(x, y), 0)),
+            pl.BlockSpec((1, bk, r), lambda b, h, x, y: (
+                b, k_block(x, y), 0)),
+            pl.BlockSpec((1, 1, bk, r), lambda b, h, x, y: (
+                b, h, k_block(x, y), 0)))
+
+
+def _latent_fwd_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, *rest, **static):
+    _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, rotary=(qr_ref, kr_ref),
+                      **static)
+
+
+def _latent_bwd_dq_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, do_ref, o_ref,
+                          lse_ref, dq_ref, dqr_ref, delta_ref, dq_scr,
+                          dqr_scr, lse_scr, delta_scr, **static):
+    _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
+                         delta_ref, dq_scr, lse_scr, delta_scr,
+                         rotary=(qr_ref, kr_ref, dqr_ref, dqr_scr), **static)
+
+
+def _latent_bwd_dkv_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, do_ref,
+                           lse_ref, delta_ref, dk_ref, dkr_ref, dv_ref,
+                           dk_scr, dkr_scr, dv_scr, **static):
+    _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                          dk_ref, dv_ref, dk_scr, dv_scr,
+                          rotary=(qr_ref, kr_ref, dkr_ref, dkr_scr), **static)
+
+
+def _latent_shapes(q, q_rope, k, k_rope, v, heads: int):
+    """(B, s, d, r) of a latent call, checked: q, k, v [B, s, heads*d],
+    q_rope [B, heads, s, r], k_rope [B, s, r]."""
+    B, s, width = q.shape
+    r = k_rope.shape[-1]
+    if (width % heads or k.shape != q.shape or v.shape != q.shape
+            or q_rope.shape != (B, heads, s, r) or k_rope.shape != (B, s, r)):
+        raise ValueError(
+            f"latent heads take q, k, v [B, s, {heads}*d], q_rope [B, "
+            f"{heads}, s, r] and k_rope [B, s, r], not {q.shape}, {k.shape}, "
+            f"{v.shape}, {q_rope.shape}, {k_rope.shape}")
+    return B, s, width // heads, r
+
+
+@functools.partial(jax.jit, static_argnames=("block_q", "block_k", "heads"))
+def _latent_fwd_lse(q, q_rope, k, k_rope, v, block_q: int, block_k: int,
+                    heads: int = 1):
+    """`latent_attention`'s forward, for its primal and its VJP alike
+    (as `_flash_fwd_lse`): (o [B, s, heads*d], lse [B*heads, 1, s])."""
+    B, s, d, r = _latent_shapes(q, q_rope, k, k_rope, v, heads)
+    bq, bk, interpret = _blocks(s, s, block_q, block_k, d, heads)
+    vma = _vma(q, q_rope, k, k_rope, v)
+    k_block = functools.partial(_visible_k_block, True, bq, bk, 0)
+    q_spec, k_spec, row_spec = _specs(bq, bk, d, heads, lambda i, j: i,
+                                      k_block)
+    qr_spec, kr_spec, _ = _rope_specs(bq, bk, r, lambda i, j: i, k_block)
+    row = jax.ShapeDtypeStruct((B * heads, 1, s), jnp.float32, vma=vma)
+    o, m, l = pl.pallas_call(
+        functools.partial(_latent_fwd_kernel, scale=(d + r) ** -0.5,
+                          causal=True, causal_offset=0, block_q=bq,
+                          block_k=bk, num_k_blocks=s // bk),
+        grid=(B, heads, s // bq, s // bk),
+        in_specs=[q_spec, qr_spec, k_spec, kr_spec, k_spec],
+        out_specs=[q_spec, row_spec, row_spec],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma), row, row],
+        scratch_shapes=[
+            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+        ],
+        compiler_params=_GRID_SEMANTICS, interpret=interpret,
+        name="hvd_mla_fwd",
+    )(q, q_rope, k, k_rope, v)
+    return o, lax.add(m, lax.log(l))
+
+
+@functools.partial(jax.jit, static_argnames=("block_q", "block_k", "heads"))
+def _latent_bwd(q, q_rope, k, k_rope, v, do, o, lse, block_q: int,
+                block_k: int, heads: int = 1):
+    """The VJP of `latent_attention` as two kernels, as `_flash_bwd`:
+    → (dq, dq_rope, dk, dk_rope, dv). The dK/dV kernel writes each
+    head's part of dk_rope, [B, heads, s, r]; their sum over the heads,
+    in float32, is the one rotary key's gradient."""
+    B, s, d, r = _latent_shapes(q, q_rope, k, k_rope, v, heads)
+    bq, bk, interpret = _blocks(s, s, block_q, block_k, d, heads)
+    nq, nk = s // bq, s // bk
+    static = dict(scale=(d + r) ** -0.5, causal=True, block_q=bq, block_k=bk)
+    vma = _vma(q, q_rope, k, k_rope, v, do)
+    params = dict(compiler_params=_GRID_SEMANTICS, interpret=interpret)
+
+    def like(x, shape=None):
+        return jax.ShapeDtypeStruct(shape or x.shape, x.dtype, vma=vma)
+
+    k_block = functools.partial(_visible_k_block, True, bq, bk, 0)
+    q_spec, k_spec, row_spec = _specs(bq, bk, d, heads, lambda i, j: i,
+                                      k_block)
+    qr_spec, kr_spec, _ = _rope_specs(bq, bk, r, lambda i, j: i, k_block)
+    dq, dq_rope, delta = pl.pallas_call(
+        functools.partial(_latent_bwd_dq_kernel, num_k_blocks=nk, **static),
+        grid=(B, heads, nq, nk),
+        in_specs=[q_spec, qr_spec, k_spec, kr_spec, k_spec, q_spec, q_spec,
+                  row_spec],
+        out_specs=[q_spec, qr_spec, row_spec],
+        out_shape=[like(q), like(q_rope),
+                   jax.ShapeDtypeStruct(lse.shape, jnp.float32, vma=vma)],
+        scratch_shapes=[
+            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, r), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+        ],
+        name="hvd_mla_bwd_dq", **params,
+    )(q, q_rope, k, k_rope, v, do, o, lse)
+
+    def q_block(j, i):   # Q blocks above K block j's diagonal
+        return lax.min(lax.max(i, lax.div(lax.mul(j, bk), bq)), nq - 1)
+
+    q_spec, k_spec, row_spec = _specs(bq, bk, d, heads, q_block,
+                                      lambda j, i: j)
+    qr_spec, kr_spec, dkr_spec = _rope_specs(bq, bk, r, q_block,
+                                             lambda j, i: j)
+    dk, dk_rope, dv = pl.pallas_call(
+        functools.partial(_latent_bwd_dkv_kernel, num_q_blocks=nq, **static),
+        grid=(B, heads, nk, nq),
+        in_specs=[q_spec, qr_spec, k_spec, kr_spec, k_spec, q_spec, row_spec,
+                  row_spec],
+        out_specs=[k_spec, dkr_spec, k_spec],
+        out_shape=[like(k), like(k_rope, (B, heads, s, r)), like(v)],
+        scratch_shapes=[
+            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, r), jnp.float32),
+            pltpu.VMEM((bk, d), jnp.float32),
+        ],
+        name="hvd_mla_bwd_dkv", **params,
+    )(q, q_rope, k, k_rope, v, do, lse, delta)
+    dk_rope = jnp.sum(dk_rope, axis=1, dtype=jnp.float32).astype(k_rope.dtype)
+    return dq, dq_rope, dk, dk_rope, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def latent_attention(q, q_rope, k, k_rope, v, block_q: int = 512,
+                     block_k: int = 512, heads: int = 1):
+    """Fused causal attention over latent (MLA) heads, whose query/key
+    width differs from their value's: head ``a``'s score of query ``i``
+    against key ``j <= i`` is ``(q[i, a] . k[j, a] + q_rope[i, a] .
+    k_rope[j]) / sqrt(d + r)``, the second part against one rotary key
+    that all heads share (never copied per head). q, k, v: [B, s,
+    heads*d], each head ``d`` adjacent columns; q_rope: [B, heads, s, r];
+    k_rope: [B, s, r] → [B, s, heads*d]. Three kernels of their own
+    names (``hvd_mla_fwd``, ``hvd_mla_bwd_dq``, ``hvd_mla_bwd_dkv``):
+    `flash_attention`'s bodies with the second product in each tile's
+    score and ``ds``'s two further products."""
+    with _pinned_mesh():
+        return _latent_fwd_lse(q, q_rope, k, k_rope, v, block_q, block_k,
+                               heads)[0]
+
+
+def _latent_fwd(q, q_rope, k, k_rope, v, block_q, block_k, heads):
+    with _pinned_mesh():
+        o, lse = _latent_fwd_lse(q, q_rope, k, k_rope, v, block_q, block_k,
+                                 heads)
+    # named as `_fwd` names its own, and for its reason
+    res = tuple(map(ad_checkpoint.checkpoint_name,
+                    (q, q_rope, k, k_rope, v, o, lse),
+                    scopes.KEPT_BY_REMAT_LATENT))
+    return res[5], res
+
+
+def _latent_bwd_rule(block_q, block_k, heads, res, do):
+    with jax.named_scope(scopes.ATTENTION):
+        return _latent_bwd(*res[:5], do, *res[5:], block_q, block_k, heads)
+
+
+latent_attention.defvjp(_latent_fwd, _latent_bwd_rule)

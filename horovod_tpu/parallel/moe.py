@@ -33,21 +33,55 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import ad_checkpoint, lax
 
 from ..utils import scopes
 
 
-def route(logits, k: int):
-    """The ``k`` largest of each token's router scores and their
-    weights: ``logits`` [t, e] → (``chosen`` [t, k] int32, ``weights``
-    [t, k] float32), ``weights = exp(r) / sum over the k chosen of
-    exp(r)``, which is a softmax over all ``e`` renormalised over the
-    chosen. In float32 whatever comes in: a choice between near-equal
-    scores should not hang on the activations' precision more than it
-    must."""
-    top, chosen = lax.top_k(logits.astype(jnp.float32), k)
-    return chosen.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+def route(logits, k: int, *, scoring: str = "softmax", bias=None,
+          scale: float = 1.0, name=None):
+    """Each token's ``k`` experts and their weights: ``logits`` [t, e] →
+    (``chosen`` [t, k] int32, ``weights`` [t, k] float32). In float32
+    whatever comes in: a choice between near-equal scores should not
+    hang on the activations' precision more than it must.
+
+    ``scoring="softmax"``: the ``k`` largest logits, ``weights = exp(r) /
+    sum over the k chosen of exp(r)``, which is a softmax over all ``e``
+    renormalised over the chosen. ``scoring="sigmoid"``: ``s =
+    sigmoid(r)``; the ``k`` largest of ``s + bias`` (``bias`` [e], a
+    correction that enters the choice and nothing else: no gradient
+    reaches it); ``weights = scale * s / (sum over the k chosen of s +
+    1e-20)``.
+
+    ``name``: the choice is given this ``checkpoint_name`` and the
+    weights are read off the scores at the *named* choice, so that a
+    caller under ``jax.checkpoint`` whose policy saves the name routes
+    its backward pass as its forward pass. It has to where the logits
+    come from activations the backward pass recomputes, not bit for
+    bit: a token whose k-th and next scores nearly tie would be routed
+    otherwise there."""
+    logits = logits.astype(jnp.float32)
+    if scoring == "softmax":
+        scores = logits
+        top, chosen = lax.top_k(logits, k)
+    elif scoring == "sigmoid":
+        if bias is None:
+            raise ValueError("scoring='sigmoid' chooses by s + bias: give "
+                             "the bias [e] (zeros where there is none)")
+        scores = jax.nn.sigmoid(logits)
+        _, chosen = lax.top_k(lax.stop_gradient(scores + bias), k)
+        top = None  # the chosen's scores without the bias: read below
+    else:
+        raise ValueError(f"scoring={scoring!r}: softmax or sigmoid")
+    chosen = chosen.astype(jnp.int32)
+    if name is not None:
+        chosen = ad_checkpoint.checkpoint_name(chosen, name)
+    if top is None or name is not None:
+        top = jnp.take_along_axis(scores, chosen, axis=-1)
+    if scoring == "softmax":
+        return chosen, jax.nn.softmax(top, axis=-1)
+    return chosen, scale * top / (jnp.sum(top, axis=-1, keepdims=True)
+                                  + 1e-20)
 
 
 @jax.custom_vjp
@@ -80,7 +114,12 @@ def _take_rows_bwd(res, g):
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
-def _experts_on_pairs(x, source, key, readers, params, rows: int):
+#: the routed experts' gate: ``(act(u G) * (u U)) D``
+ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
+
+
+def _experts_on_pairs(x, source, key, readers, params, rows: int,
+                      activation: str = "relu"):
     """Each pair's expert applied to its row: ``x`` [n, d]; pair ``p``
     takes row ``source[p]`` to local expert ``key[p]`` (``count`` =
     none held here); ``readers`` [n, m] lists the pairs that read each
@@ -102,7 +141,7 @@ def _experts_on_pairs(x, source, key, readers, params, rows: int):
     gate_up = jnp.concatenate([params["gate"], params["up"]],
                               axis=-1).astype(x.dtype)
     h = lax.ragged_dot(xs, gate_up, group_sizes)
-    h = jax.nn.relu(h[:, :width]) * h[:, width:]
+    h = ACTIVATIONS[activation](h[:, :width]) * h[:, width:]
     out = lax.ragged_dot(h, params["down"].astype(x.dtype), group_sizes)
     # rows past the last group are no expert's, and whatever the kernel
     # left there: only pairs without a held expert read them (clipped),
@@ -163,7 +202,7 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def expert_layer(u, chosen, weights, expert_params, held=None, *,
-                 axis_name=None):
+                 axis_name=None, activation: str = "relu"):
     """The held experts' part of a gated sparse feed-forward.
 
     Args:
@@ -172,12 +211,13 @@ def expert_layer(u, chosen, weights, expert_params, held=None, *,
         router's experts.
       expert_params: ``gate``, ``up`` [h, d, f] and ``down`` [h, f, d] of
         the ``h`` experts held here; expert ``e`` computes
-        ``(relu(u @ gate_e) * (u @ up_e)) @ down_e``.
+        ``(act(u @ gate_e) * (u @ up_e)) @ down_e``.
       held: ``(first, count)``: this call holds experts ``first ..
         first + count - 1`` (default: ``0 .. h - 1``). Not with
         ``axis_name``, where chip ``i`` of the axis holds ``i*h ..``.
       axis_name: exchange the pairs over this mesh axis, so that every
         chosen expert is somebody's.
+      activation: ``act``: ``"relu"`` (ReGLU experts) or ``"silu"``.
 
     Returns ``sum over the chosen e that are held of w_e * E_e(u)``,
     [t, d] in ``u``'s dtype. The weights stay normalised over all the
@@ -192,7 +232,7 @@ def expert_layer(u, chosen, weights, expert_params, held=None, *,
             raise ValueError("under axis_name a chip's experts follow from "
                              "its place on the axis, not from held=")
         return _exchanged(u, chosen, weights, expert_params, axis_name,
-                          t * per_token)
+                          t * per_token, activation)
     first = 0 if held is None else held[0]
     if held is not None and held[1] != count:
         raise ValueError(f"held={held} but the parameters are of {count} "
@@ -202,11 +242,12 @@ def expert_layer(u, chosen, weights, expert_params, held=None, *,
     key = jnp.where(is_held, local, count).T.reshape(-1)
     token = jnp.tile(jnp.arange(t, dtype=jnp.int32), k)
     out_pairs = _experts_on_pairs(u, token, key, _pair_ids(t, k),
-                                  expert_params, t * per_token)
+                                  expert_params, t * per_token, activation)
     return _combine(out_pairs, weights, is_held).astype(u.dtype)
 
 
-def _exchanged(u, chosen, weights, params, axis_name, bound: int):
+def _exchanged(u, chosen, weights, params, axis_name, bound: int,
+               activation: str = "relu"):
     """`expert_layer` over the expert-parallel axis. A chip sends each
     peer at most ``bound`` rows (all of its pairs may go to one chip), so
     the exchange is ``[axis_size, bound, d]`` each way, padded."""
@@ -235,7 +276,7 @@ def _exchanged(u, chosen, weights, params, axis_name, bound: int):
     row_ids = jnp.arange(rows, dtype=jnp.int32)
     out = _experts_on_pairs(recv_x.reshape(rows, d), row_ids,
                             recv_key.reshape(-1), row_ids[:, None], params,
-                            rows)
+                            rows, activation)
     back = lax.all_to_all(out.reshape(n, bound, d), axis_name, 0, 0)
     out_pairs = _take_rows(back.reshape(rows, d), slot,
                            sent.reshape(-1, 1), filled.reshape(-1, 1))

@@ -24,6 +24,16 @@ Scopes the program sets (the only place their names are written):
 - ``hvd.model/moe``             its expert layer (``parallel/moe.py``): sort,
                                 gathers, grouped matmuls, the weighted sum,
                                 and the all-to-all where there is one
+- ``hvd.model/latent``          a latent-attention block's down-projection to
+                                the key/value latent and the shared rotary key,
+                                the latent's norm, that key's rotary turn and
+                                the up-projection to every head's keys and
+                                values (beside ``hvd.model/attention``, which
+                                keeps the queries, the kernels and the output
+                                projection: a nested name would be filed
+                                under its parent)
+- ``hvd.model/shared_expert``   the gated MLP every token takes beside its
+                                routed experts
 
 Forward, backward and recompute need no scope: JAX marks them itself
 (``jvp(``, ``transpose(``, ``rematted_computation``).
@@ -31,8 +41,11 @@ Forward, backward and recompute need no scope: JAX marks them itself
 Names the program gives arrays (``jax.ad_checkpoint.checkpoint_name``;
 the identity outside a ``jax.checkpoint``): `KEPT_BY_REMAT`, what the
 fused attention's backward kernels read (``hvd.attention/q|k|v|o|lse``,
-named in ``ops/pallas/flash_attention.py``'s forward rule). A decoder
-block under ``remat`` keeps these and recomputes the rest.
+named in ``ops/pallas/flash_attention.py``'s forward rule), and
+`KEPT_BY_REMAT_LATENT`, those and a latent head's rotary parts
+(``hvd.attention/q_rope``, ``/k_rope``: `latent_attention`'s residuals);
+`KEPT_CHOICE`, the choice of a router on the expert layer's normed input.
+A decoder block under ``remat`` keeps these and recomputes the rest.
 
 Counters (`StepRecord.counters`, noted once while a step is traced, so
 per step and per chip): ``collectives`` the exchange issued,
@@ -40,13 +53,16 @@ per step and per chip): ``collectives`` the exchange issued,
 buffers, ``axis_size``; ``attention_calls`` the decoder's default attention
 traced and ``attention_kernel_calls`` of them routed to the fused
 kernels (a share of the two survives retracing under ``jax.checkpoint``),
-``attention_window_calls`` of them with a window, ``attention_kept_calls``
+``attention_window_calls`` of them with a window,
+``attention_latent_calls`` of them over latent heads, ``attention_kept_calls``
 of them in a checkpointed block that keeps the kernels' residuals, and
-``remat_kept_mb``, those residuals' bytes / 1e6 over the blocks traced
+``remat_kept_mb``, those residuals' and a kept choice's bytes / 1e6 over the blocks traced
 (a count: each block's call is noted once); of a sparse-expert
 decoder ``moe_layers``, ``experts_held`` of ``experts_total`` in each,
 ``experts_per_token`` chosen, and ``moe_buffer_rows``, the bound its expert layer's buffers are sized for
-(per layer: tokens times the most experts one token can have here).
+(per layer: tokens times the most experts one token can have here),
+``dense_layers`` its leading layers without experts and ``shared_experts``
+its expert layers with shared experts (a count of layers each).
 """
 
 from __future__ import annotations
@@ -73,10 +89,22 @@ MLP = MODEL + "/mlp"
 HEAD = MODEL + "/head"
 ROUTER = MODEL + "/router"
 MOE = MODEL + "/moe"
+LATENT = MODEL + "/latent"
+SHARED_EXPERT = MODEL + "/shared_expert"
 
 #: `flash_attention`'s residuals, by the names its forward rule gives them
 KEPT_BY_REMAT = tuple("hvd.attention/" + a
                       for a in ("q", "k", "v", "o", "lse"))
+#: `latent_attention`'s, in its residuals' order: a latent head's rotary
+#: parts beside the five
+KEPT_BY_REMAT_LATENT = tuple("hvd.attention/" + a for a in (
+    "q", "q_rope", "k", "k_rope", "v", "o", "lse"))
+
+#: the experts a router chose that reads its expert layer's normed input
+#: (``models/transformer.py _route``, either scoring), [tokens, k] int32 a
+#: layer: kept by a checkpointed decoder block, whose backward pass
+#: recomputes that input, not bit for bit
+KEPT_CHOICE = "hvd.router/chosen"
 
 #: in `phase_of`'s order of precedence
 PHASES = ("grad_exchange", "optimizer", "recompute", "backward", "forward",
@@ -245,7 +273,7 @@ def note_exchange(buffers, axis_name: str, packed: bool = False) -> None:
 
 
 def note_attention(kernel: bool, window: bool = False,
-                   kept: bool = False) -> None:
+                   kept: bool = False, latent: bool = False) -> None:
     """Called where ``models/transformer.py`` routes one default
     attention call, to the fused kernels or to `causal_attention`, with
     a window or without; ``kept``: in a checkpointed block whose policy
@@ -264,18 +292,30 @@ def note_attention(kernel: bool, window: bool = False,
     c.setdefault("remat_kept_mb", 0.0)
     if window:  # a decoder without windows keeps the counters it had
         c["attention_window_calls"] = c.get("attention_window_calls", 0) + 1
+    if latent:  # and one without latent heads
+        c["attention_latent_calls"] = c.get("attention_latent_calls", 0) + 1
 
 
 def note_kept(nbytes: int) -> None:
-    """Called once per checkpointed block that keeps `KEPT_BY_REMAT`,
-    where ``models/transformer.py`` lays its blocks out (outside
-    ``jax.checkpoint``, so ``remat_kept_mb`` is a sum over the blocks),
-    with the bytes of the arrays kept. A no-op outside a traced
+    """Called once per checkpointed block that keeps `KEPT_BY_REMAT` or
+    `KEPT_CHOICE`, where ``models/transformer.py`` lays its blocks out
+    (outside ``jax.checkpoint``, so ``remat_kept_mb`` is a sum over the
+    blocks), with the bytes of the arrays kept. A no-op outside a traced
     ``data_parallel_step``."""
     record = _tracing.get()
     if record is not None:
         c = record.counters
         c["remat_kept_mb"] = c.get("remat_kept_mb", 0.0) + nbytes / 1e6
+
+
+def note_layer(counter: str) -> None:
+    """Called once per layer of a kind a decoder counts (``dense_layers``:
+    a leading dense layer of a sparse-expert decoder; ``shared_experts``:
+    an expert layer with shared experts), where ``models/transformer.py``
+    lays its blocks out. A no-op outside a traced ``data_parallel_step``."""
+    record = _tracing.get()
+    if record is not None:
+        record.counters[counter] = record.counters.get(counter, 0) + 1
 
 
 def note_moe(held: int, total: int, per_token: int,

@@ -13,6 +13,7 @@ from horovod_tpu.ops.pallas.flash_attention import (
     attention_stats,
     block_sizes,
     flash_attention,
+    latent_attention,
     scan_stats,
 )
 
@@ -486,3 +487,66 @@ def test_window_and_head_arguments_are_checked():
         flash_attention(q, q, q, False, 64, 64, 4, 32)
     with pytest.raises(ValueError, match="key/value heads"):
         flash_attention(q, kv3, kv3, True, 64, 64, 4, None, 3)
+
+
+# -- latent heads: a score of two parts, one rotary key for all heads --------
+
+@pytest.mark.parametrize("wrt", [None, 0, 1, 2, 3, 4],
+                         ids=["o", "dq", "dq_rope", "dk", "dk_rope", "dv"])
+def test_latent_kernels_match_the_einsum_path_and_scan_stats(wrt):
+    """The three ``hvd_mla_*`` kernels (interpret mode) at four
+    128-tiles a side, three heads of 32 + 16 columns: against
+    `causal_attention` on heads put together (the rotary key copied per
+    head there, never in the kernels) and, forward, against `scan_stats`
+    head by head. Forward, and each of the five gradients through the
+    custom VJP: the shared key's is the sum over the heads."""
+    from horovod_tpu.models.transformer import causal_attention
+
+    b, s, heads, d, r, block = 2, 512, 3, 32, 16, 128
+    rng = np.random.RandomState(11)
+    q, k, v, t = (jnp.asarray(rng.randn(b, s, heads * d), jnp.float32)
+                  for _ in range(4))
+    q_rope = jnp.asarray(rng.randn(b, heads, s, r), jnp.float32)
+    k_rope = jnp.asarray(rng.randn(b, s, r), jnp.float32)
+
+    def kernels(q, q_rope, k, k_rope, v):
+        return latent_attention(q, q_rope, k, k_rope, v, block, block, heads)
+
+    def together(q, q_rope, k, k_rope):
+        """[b, s, heads, d + r] queries and keys."""
+        return (jnp.concatenate([q.reshape(b, s, heads, d),
+                                 q_rope.transpose(0, 2, 1, 3)], -1),
+                jnp.concatenate([k.reshape(b, s, heads, d), jnp.broadcast_to(
+                    k_rope[:, :, None], (b, s, heads, r))], -1))
+
+    def einsum(q, q_rope, k, k_rope, v):
+        qq, kk = together(q, q_rope, k, k_rope)
+        return causal_attention(qq, kk, v.reshape(b, s, heads, d)).reshape(
+            b, s, heads * d)
+
+    args = (q, q_rope, k, k_rope, v)
+    if wrt is None:
+        got, want = kernels(*args), einsum(*args)
+        qq, kk = together(q, q_rope, k, k_rope)
+        for h in range(heads):  # scan_stats pads v to the score's width
+            vv = jnp.pad(v.reshape(b, s, heads, d)[:, :, h],
+                         ((0, 0), (0, 0), (0, r)))
+            o, _, _ = scan_stats(qq[:, :, h], kk[:, :, h], vv, True, 0, 128)
+            np.testing.assert_allclose(
+                np.asarray(got.reshape(b, s, heads, d)[:, :, h]),
+                np.asarray(o[..., :d]), atol=2e-4, rtol=2e-4)
+    else:
+        got, want = (jax.grad(lambda *a: jnp.sum(f(*a) * t), argnums=wrt)(
+            *args) for f in (kernels, einsum))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4,
+                               rtol=2e-4)
+
+
+def test_latent_shapes_are_checked_and_plain_heads_tile_as_before():
+    x = jnp.zeros((1, 128, 2 * 32))
+    with pytest.raises(ValueError, match="latent heads"):
+        latent_attention(x, jnp.zeros((1, 128, 2, 16)), x,
+                         jnp.zeros((1, 128, 16)), x, 64, 64, 2)
+    # a latent head's head_dim is its no-rope columns: the same rule
+    assert block_sizes(8192, 128) == (1024, 1024)
+    assert block_sizes(8192, 192) is None

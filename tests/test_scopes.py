@@ -28,6 +28,14 @@ PRE = "jit(hvd_data_parallel_step)/shard_map/hvd.step/"
      "dot_general", "backward", "hvd.model/attention"),
     (PRE + "transpose(jvp(hvd.step))/jvp()/checkpoint/rematted_computation/"
      "hvd.model/attention/dot_general", "recompute", "hvd.model/attention"),
+    (PRE + "jvp(hvd.model/latent)/dot_general", "forward",
+     "hvd.model/latent"),
+    (PRE + "transpose(jvp(hvd.step))/jvp()/checkpoint/rematted_computation/"
+     "hvd.model/shared_expert/dot_general", "recompute",
+     "hvd.model/shared_expert"),
+    # a nested name is filed under its parent: the new parts sit beside it
+    (PRE + "jvp(hvd.model/attention)/hvd.model/latent/dot_general",
+     "forward", "hvd.model/attention"),
     (PRE + "hvd.optimizer/sub", "optimizer", None),
     (PRE + "hvd.grad_exchange/pack/concatenate", "grad_exchange",
      "hvd.grad_exchange/pack"),
@@ -447,6 +455,26 @@ def test_attention_counters_note_where_each_call_went(routed, want):
             scopes.note_attention(kernel=kernel)
     scopes.note_attention(kernel=True)   # outside a traced step
     assert record.counters == want
+
+
+def test_latent_and_layer_counters_are_there_only_where_noted():
+    """A latent call counts as an attention call too; the layer counters
+    count a layer each; a decoder that notes neither keeps the counters
+    it had (no ``attention_latent_calls``, ``dense_layers`` or
+    ``shared_experts`` key at all)."""
+    record = scopes.StepRecord()
+    with scopes.recording(record):
+        scopes.note_attention(kernel=True, kept=True, latent=True)
+        scopes.note_attention(kernel=False, latent=True)
+        scopes.note_layer("dense_layers")
+        scopes.note_layer("shared_experts")
+        scopes.note_layer("shared_experts")
+    scopes.note_layer("dense_layers")    # outside a traced step
+    assert record.counters == {
+        "attention_calls": 2, "attention_kernel_calls": 1,
+        "attention_kept_calls": 1, "remat_kept_mb": 0.0,
+        "attention_latent_calls": 2, "dense_layers": 1, "shared_experts": 2}
+    assert set(scopes.KEPT_BY_REMAT) < set(scopes.KEPT_BY_REMAT_LATENT)
 
 
 @pytest.mark.parametrize("path,remat,kept", [

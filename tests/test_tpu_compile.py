@@ -116,6 +116,41 @@ def test_grouped_head_kernels_compile_at_the_sparse_decoder_shapes(
     assert [o.shape for o in bwd.out_info] == [q.shape, k.shape, k.shape]
 
 
+def test_latent_kernels_compile_at_the_mla_decoder_shapes(topo):
+    """The latent-attention cell's heads: 32 of 128 no-rope + 64 rotary
+    columns (a query/key 192 wide, no lane multiple) and a value of 128
+    at 8192 positions, the rotary key one [1, 8192, 64] array for all
+    heads, at the blocks `block_sizes` gives. The TPU compiler takes all
+    three kernels, under names of their own that no reader of
+    ``hvd_flash_*`` matches; the shared key's gradient comes back in its
+    own shape."""
+    import re
+
+    one = SingleDeviceSharding(topo.devices[0])
+    heads, r, s = 32, 64, 8192
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    x, q_rope, k_rope = (shape(1, s, heads * D), shape(1, heads, s, r),
+                         shape(1, s, r))
+    lse = shape(heads, 1, s, dtype=jnp.float32)
+    bq, bk = F.block_sizes(s, D)
+    static = dict(block_q=bq, block_k=bk, heads=heads)
+    fwd = F._latent_fwd_lse.lower(x, q_rope, x, k_rope, x,
+                                  **static).compile().as_text()
+    assert "tpu_custom_call" in fwd and "hvd_mla_fwd" in fwd
+    bwd = F._latent_bwd.lower(x, q_rope, x, k_rope, x, x, x, lse,
+                              **static).compile()
+    text = bwd.as_text()
+    assert "hvd_mla_bwd_dq" in text and "hvd_mla_bwd_dkv" in text
+    assert [o.shape for o in bwd.out_info] == [
+        x.shape, q_rope.shape, x.shape, k_rope.shape, x.shape]
+    flash = re.compile(r"^hvd_flash_(fwd|bwd_dq|bwd_dkv)(_w\d+)?$")
+    assert not any(flash.match(n) for n in (
+        "hvd_mla_fwd", "hvd_mla_bwd_dq", "hvd_mla_bwd_dkv"))
+
+
 def test_dense_kernels_trace_as_before_the_window_and_the_groups(topo):
     """``window=None, kv_heads=heads`` is the dense decoder's call: its
     jaxpr (grid, index maps and kernel bodies) is, letter for letter,
