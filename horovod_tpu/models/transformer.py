@@ -395,10 +395,13 @@ def apply(params, tokens, cfg: TransformerConfig, *, use_constraints: bool = Tru
         if "experts" in blk:
             x, chosen = x
             routing.append(chosen)
-            scopes.note_moe(
-                cfg.held[1], cfg.n_experts, cfg.experts_per_token,
-                x.shape[0] * x.shape[1]
-                * min(cfg.experts_per_token, cfg.held[1]))
+            from ..parallel import moe
+
+            rows = x.shape[0] * x.shape[1]   # the tokens a chip has
+            bound = rows * min(cfg.experts_per_token, cfg.held[1])
+            scopes.note_moe(cfg.held[1], cfg.n_experts,
+                            cfg.experts_per_token, bound,
+                            moe.chunk_rows(rows, bound))
             if "shared" in blk:
                 scopes.note_layer("shared_experts")
         elif cfg.n_experts:

@@ -12,9 +12,26 @@ the layer is whole, around two functions:
   are sorted by expert, the pairs of held experts come first, and one
   grouped matmul per weight (``jax.lax.ragged_dot``: on a TPU XLA's own
   grouped-matmul kernel, whose work follows the rows really routed)
-  runs over a buffer sized for the bound ``tokens * min(k, held)``. What
-  the experts held elsewhere would add is left out, here and in whatever
-  this is compared with.
+  runs over them. The bound on those pairs is ``tokens * min(k, held)``,
+  and nothing is sized for less: there is no capacity to choose and
+  nothing a skewed step can overflow. What the experts held elsewhere
+  would add is left out, here and in whatever this is compared with.
+
+The cost follows the rows routed, chunk by chunk (`_sorted_side`). The
+sorted positions are cut into chunks of as many rows as the layer has
+tokens (`chunk_rows`: ``min(k, held)`` chunks on one chip; one, the
+whole, behind the exchange), and everything whose leading dimension is
+the sorted position (the gather of the tokens' rows, both grouped
+matmuls, the activation, and in the backward pass the gather of the
+cotangent out of pair order and all their transposes) is computed for
+one chunk at a time. Chunk 0 always runs; one loop a direction walks
+the further chunks that a routed row reaches, so a step in which the
+held experts take no more rows than there are tokens pays for one chunk
+and a loop of no trips, and a skewed one for as many chunks as its rows
+fill: more trips, never a dropped row, and one path whatever the
+routing. The pair side (the gather back to pair order, the weighted sum
+over a token's choices, and their transposes) runs once a layer at the
+bound, over one array assembled in place per direction.
 
 On one chip nothing is exchanged. With ``axis_name`` (inside a
 ``shard_map`` over the expert-parallel axis, each chip holding
@@ -26,10 +43,14 @@ split is padding, never a dropped token (SURVEY.md §7 hard part 6).
 Both directions of every row movement are gathers: the transpose of a
 gather is a scatter-add, which a TPU serialises row by row, but the
 sort that made the gather's indices also gives the indices of its
-inverse (`_take_rows`).
+inverse (`_take_rows`; `_sorted_side` has its own VJP for the same
+reason, and recomputes a chunk's hidden activation inside the backward
+loop, so that nothing of a chunk's size is handed out of a loop).
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -96,19 +117,26 @@ def _take_rows_fwd(x, idx, back_idx, back_mask):
     return _take_rows(x, idx, back_idx, back_mask), (back_idx, back_mask)
 
 
+def _rows_back(g, n: int, back_idx, back_mask):
+    """`_take_rows`' transpose, [n, d]: row ``i`` is the sum, in
+    float32, of ``g``'s rows ``back_idx[j*n + i]`` over the ``j`` where
+    ``back_mask[j*n + i]`` (reader-major vectors of ``n * m``)."""
+    # one gather, then reader by reader in slices (no reshape to
+    # [m, n, d]: see `_choice`)
+    rows = g.at[back_idx].get(mode="promise_in_bounds")
+    dx = sum(jnp.where(_choice(back_mask, n, j)[:, None],
+                       _choice(rows, n, j).astype(jnp.float32), 0.0)
+             for j in range(back_idx.shape[0] // n))
+    return dx.astype(g.dtype)
+
+
 def _take_rows_bwd(res, g):
     back_idx, back_mask = res
-    n, m = back_idx.shape
     # a custom VJP's backward pass is traced outside the scope its call
     # stood in: name it again, or a trace files it under nothing
     with jax.named_scope(scopes.MOE):
-        # one gather, reader-major, then reader by reader in slices (no
-        # reshape to [m, n, d]: see `_choice`)
-        rows = g.at[back_idx.T.reshape(-1)].get(mode="promise_in_bounds")
-        dx = sum(jnp.where(back_mask[:, j, None],
-                           _choice(rows, n, j).astype(jnp.float32), 0.0)
-                 for j in range(m))
-        return dx.astype(g.dtype), None, None, None
+        return (_rows_back(g, back_idx.shape[0], back_idx.T.reshape(-1),
+                           back_mask.T.reshape(-1)), None, None, None)
 
 
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
@@ -118,36 +146,163 @@ _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
 
 
-def _experts_on_pairs(x, source, key, readers, params, rows: int,
-                      activation: str = "relu"):
+def _chunk(c, chunk: int, x, sizes, order):
+    """Chunk ``c`` of the sorted positions: its first position, the
+    pairs at its ``chunk`` positions, their rows of ``x`` (pair ``p``
+    reads row ``p % n``) and the part of each expert's group that lies in
+    it (the groups follow one another from position 0)."""
+    lo = c * chunk
+    pairs = lax.dynamic_slice_in_dim(order, lo, chunk)
+    ends = jnp.cumsum(sizes)
+    inside = (jnp.clip(ends, lo, lo + chunk)
+              - jnp.clip(ends - sizes, lo, lo + chunk)).astype(jnp.int32)
+    return (lo, pairs, x.at[pairs % x.shape[0]].get(mode="promise_in_bounds"),
+            inside)
+
+
+def _experts_on_rows(xs, gate_up, down, sizes, activation: str):
+    """``xs`` [r, d] sorted by expert, ``sizes`` rows to each: every
+    row through its expert. Rows past the last group are no expert's,
+    and come out as whatever the kernel left there."""
+    width = gate_up.shape[-1] // 2
+    h = lax.ragged_dot(xs, gate_up, sizes)
+    h = ACTIVATIONS[activation](h[:, :width]) * h[:, width:]
+    return lax.ragged_dot(h, down, sizes)
+
+
+def _in_chunks(live, chunk: int, chunks: int, on_chunk):
+    """A carry through the chunks in turn: ``on_chunk(0, None)`` makes
+    it, then one loop takes it through ``on_chunk(c, carry)`` for the
+    further chunks ``c`` that a routed row reaches (``c * chunk <
+    live``). Its trip count is the data's: with no such chunk the loop
+    costs the test of a scalar, and there is one copy of a chunk's code
+    however many there are."""
+    carry = on_chunk(0, None)
+    if chunks == 1:
+        return carry
+    return lax.fori_loop(1, (live + chunk - 1) // chunk, on_chunk, carry)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _sorted_side(chunk: int, activation: str, x, weights, sizes, order,
+                 place):
+    """Everything of the expert layer whose leading dimension is the
+    sorted position, chunk by chunk: ``x`` [n, d] → [pairs, d], pair
+    ``p``'s row (row ``p % n`` of ``x``) through its expert.
+
+    ``weights``: ``gate``, ``up``, ``down``; ``sizes`` [held]: rows to
+    each expert, which fill the sorted positions from 0; ``order``
+    [rows]: sorted position → pair; ``place`` [pairs]: pair → sorted
+    position.
+
+    Its own VJP (`_sorted_side_fwd`, `_sorted_side_bwd`), so that a
+    chunk's gathers stay gathers in both directions and nothing of a
+    chunk's size leaves the loop but its rows of the one assembled
+    array."""
+    return _sorted_side_fwd(chunk, activation, x, weights, sizes, order,
+                            place)[0]
+
+
+def _cast(weights, dtype):
+    # gate and up as one matmul: the rows are read once, and their
+    # gradient is one array, not the sum of two at the buffers' size
+    return (jnp.concatenate([weights["gate"], weights["up"]],
+                            axis=-1).astype(dtype),
+            weights["down"].astype(dtype))
+
+
+def _grown(rows, chunks: int):
+    """Chunk 0's rows at the head of the array the further chunks write
+    theirs into, zero where none does."""
+    return jnp.pad(rows, ((0, (chunks - 1) * rows.shape[0]), (0, 0)))
+
+
+def _sorted_side_fwd(chunk, activation, x, weights, sizes, order, place):
+    chunks = order.shape[0] // chunk
+    gate_up, down = _cast(weights, x.dtype)
+
+    def on_chunk(c, out):
+        lo, _, xs, inside = _chunk(c, chunk, x, sizes, order)
+        rows = _experts_on_rows(xs, gate_up, down, inside, activation)
+        return (_grown(rows, chunks) if out is None
+                else lax.dynamic_update_slice_in_dim(out, rows, lo, 0))
+
+    out = _in_chunks(jnp.sum(sizes), chunk, chunks, on_chunk)
+    # the pair side, once a layer however many chunks ran. Rows past the
+    # last group are junk or, in a chunk that did not run, zero: only
+    # pairs without a held expert read them (clipped), and `_combine`
+    # selects those away; no pass over the buffer here
+    return (out.at[jnp.minimum(place, order.shape[0] - 1)].get(
+        mode="promise_in_bounds"), (x, weights, sizes, order, place))
+
+
+def _sorted_side_bwd(chunk, activation, res, g):
+    x, weights, sizes, order, place = res
+    n, chunks, live = x.shape[0], order.shape[0] // chunk, jnp.sum(sizes)
+    with jax.named_scope(scopes.MOE):  # as in `_take_rows_bwd`
+        gate_up, down = _cast(weights, x.dtype)
+
+        def on_chunk(c, carry):
+            lo, pairs, xs, inside = _chunk(c, chunk, x, sizes, order)
+            # recomputed here from `x` and the indices, not kept
+            _, back = jax.vjp(functools.partial(
+                _experts_on_rows, sizes=inside, activation=activation),
+                xs, gate_up, down)
+            # positions past `live` are in no expert's group: what their
+            # pairs' cotangent holds reaches no weight's gradient, and
+            # their rows of `d_xs` are selected away below
+            d_xs, *d_w = back(g.at[pairs].get(mode="promise_in_bounds"))
+            d_w = [d.astype(jnp.float32) for d in d_w]
+            if carry is None:
+                return _grown(d_xs, chunks), d_w
+            return (lax.dynamic_update_slice_in_dim(carry[0], d_xs, lo, 0),
+                    [a + d for a, d in zip(carry[1], d_w)])
+
+        d_rows, (d_gate_up, d_down) = _in_chunks(live, chunk, chunks,
+                                                 on_chunk)
+        width = weights["gate"].shape[-1]
+        d_weights = {"gate": d_gate_up[..., :width],
+                     "up": d_gate_up[..., width:], "down": d_down}
+        # the token side, once a layer: row i of `x` was read at the
+        # sorted positions of the pairs j*n + i that have a held expert
+        d_x = _rows_back(d_rows, n, jnp.minimum(place, order.shape[0] - 1),
+                         place < live)
+        return (d_x, jax.tree.map(lambda d, w: d.astype(w.dtype), d_weights,
+                                  weights), None, None, None)
+
+
+_sorted_side.defvjp(_sorted_side_fwd, _sorted_side_bwd)
+
+
+def chunk_rows(n: int, rows: int) -> int:
+    """Rows of one of the chunks a layer's ``rows`` sorted positions are
+    walked in, ``n`` being the rows of what the layer gathers from: ``n``
+    where that cuts ``rows`` into whole chunks. On one chip it does
+    (``rows`` is the tokens times the most held experts one token can
+    have); behind the exchange ``n`` is ``rows``, the received buffer:
+    one chunk."""
+    return n if rows % n == 0 else rows
+
+
+def _experts_on_pairs(x, key, params, rows: int, activation: str = "relu"):
     """Each pair's expert applied to its row: ``x`` [n, d]; pair ``p``
-    takes row ``source[p]`` to local expert ``key[p]`` (``count`` =
-    none held here); ``readers`` [n, m] lists the pairs that read each
-    row of ``x``. → [pairs, d], junk where ``key == count``. ``rows`` is
-    the bound on the pairs with a held expert: the buffers' size."""
+    takes row ``p % n`` to local expert ``key[p]`` (``count`` = none
+    held here). → [pairs, d], junk where ``key == count``. ``rows`` is
+    the bound on the pairs with a held expert: the buffers' size.
+
+    The sorted positions are walked in chunks of ``n`` rows, ``x``'s
+    own count (`chunk_rows`, `_sorted_side`). Every index comes of the
+    two sorts and of arithmetic on them: an integer gather of ``pairs``
+    elements costs a TPU as much as a sixth of a row gather."""
     count = params["gate"].shape[0]
     order = jnp.argsort(key, stable=True).astype(jnp.int32)  # held first
     place = jnp.argsort(order).astype(jnp.int32)   # pair -> sorted position
-    order, last = order[:rows], rows - 1
-    valid = key[order] < count
     group_sizes = jnp.sum(
         key[:, None] == jnp.arange(count, dtype=key.dtype)[None],
         axis=0, dtype=jnp.int32)
-    xs = _take_rows(x, source[order], jnp.minimum(place[readers], last),
-                    key[readers] < count)
-    # gate and up as one matmul: the rows are read once, and their
-    # gradient is one array, not the sum of two at the buffers' size
-    width = params["gate"].shape[-1]
-    gate_up = jnp.concatenate([params["gate"], params["up"]],
-                              axis=-1).astype(x.dtype)
-    h = lax.ragged_dot(xs, gate_up, group_sizes)
-    h = ACTIVATIONS[activation](h[:, :width]) * h[:, width:]
-    out = lax.ragged_dot(h, params["down"].astype(x.dtype), group_sizes)
-    # rows past the last group are no expert's, and whatever the kernel
-    # left there: only pairs without a held expert read them (clipped),
-    # and `_combine` selects those away; no pass over the buffer here
-    return _take_rows(out, jnp.minimum(place, last), order[:, None],
-                      valid[:, None])
+    weights = {name: params[name] for name in ("gate", "up", "down")}
+    return _sorted_side(chunk_rows(x.shape[0], rows), activation, x, weights,
+                        group_sizes, order[:rows], place)
 
 
 def _pair_ids(t: int, k: int):
@@ -240,9 +395,8 @@ def expert_layer(u, chosen, weights, expert_params, held=None, *,
     local = chosen - first
     is_held = (local >= 0) & (local < count)
     key = jnp.where(is_held, local, count).T.reshape(-1)
-    token = jnp.tile(jnp.arange(t, dtype=jnp.int32), k)
-    out_pairs = _experts_on_pairs(u, token, key, _pair_ids(t, k),
-                                  expert_params, t * per_token, activation)
+    out_pairs = _experts_on_pairs(u, key, expert_params, t * per_token,
+                                  activation)
     return _combine(out_pairs, weights, is_held).astype(u.dtype)
 
 
@@ -273,10 +427,8 @@ def _exchanged(u, chosen, weights, params, axis_name, bound: int,
     recv_x = lax.all_to_all(send_x.reshape(n, bound, d), axis_name, 0, 0)
     recv_key = lax.all_to_all(send_key.astype(jnp.int32), axis_name, 0, 0)
     rows = n * bound
-    row_ids = jnp.arange(rows, dtype=jnp.int32)
-    out = _experts_on_pairs(recv_x.reshape(rows, d), row_ids,
-                            recv_key.reshape(-1), row_ids[:, None], params,
-                            rows, activation)
+    out = _experts_on_pairs(recv_x.reshape(rows, d), recv_key.reshape(-1),
+                            params, rows, activation)
     back = lax.all_to_all(out.reshape(n, bound, d), axis_name, 0, 0)
     out_pairs = _take_rows(back.reshape(rows, d), slot,
                            sent.reshape(-1, 1), filled.reshape(-1, 1))

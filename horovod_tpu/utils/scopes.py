@@ -59,8 +59,10 @@ of them in a checkpointed block that keeps the kernels' residuals, and
 ``remat_kept_mb``, those residuals' and a kept choice's bytes / 1e6 over the blocks traced
 (a count: each block's call is noted once); of a sparse-expert
 decoder ``moe_layers``, ``experts_held`` of ``experts_total`` in each,
-``experts_per_token`` chosen, and ``moe_buffer_rows``, the bound its expert layer's buffers are sized for
+``experts_per_token`` chosen, ``moe_buffer_rows``, the bound its expert layer's buffers are sized for
 (per layer: tokens times the most experts one token can have here),
+``moe_chunks`` and ``moe_chunk_rows``, the chunks that bound is walked in
+and the rows of each (how many of them ran is the data's: a trace's),
 ``dense_layers`` its leading layers without experts and ``shared_experts``
 its expert layers with shared experts (a count of layers each).
 """
@@ -119,9 +121,17 @@ _OP_NAME = re.compile(r'\bop_name="((?:[^"\\]|\\.)*)"')
 _COMPUTATION = re.compile(r"(?:ENTRY\s+)?%?([^\s(]+) \(.*\{\s*$")
 _CALLS = re.compile(r"\bcalls=%?([^\s,}]+)")
 _COPY_OF = re.compile(r"\scopy\(%?([^\s,)]+)\)")
+_ASYNC_COPY_OF = re.compile(r"\scopy-(?:start|done)\(%?([^\s,)]+)\)")
 #: XLA's grouped matmul, as the TPU compiler rewrites ``ragged_dot``
 _GROUPED_MATMUL = re.compile(r"ragged-dot(?!-metadata)")
 _OPERANDS = re.compile(r"\s[a-z\-]+\(([^)]*)\)")
+#: an instruction's opcode: the first lower-case word before a ``(``
+#: (layouts and tiles in the shape are upper case)
+_OPCODE = re.compile(r"\s([a-z][a-z\-]*)\(")
+#: what `instruction_scopes` files a ``conditional`` or a ``while`` under:
+#: its event in a profile spans its branch's or its body's events, which
+#: are counted by themselves
+SPANS_ITS_BODY = "hvd.spans_its_body"
 
 
 def phase_of(op_name: Optional[str]) -> str:
@@ -161,15 +171,25 @@ def instruction_scopes(hlo_text: str) -> dict:
     grouped matmul (``ragged-dot*``, what it makes of
     ``jax.lax.ragged_dot``) carries its own name for metadata and none
     of the program's: it is filed with the first of its operands that
-    has a phase (the rows it multiplies; PERF.md, PR 28)."""
+    has a phase (the rows it multiplies; PERF.md, PR 28), seen through
+    the compiler's asynchronous copies (``copy-start``/``copy-done``,
+    which carry no metadata and stay unfiled themselves; PERF.md, PR
+    34). A ``conditional`` or a ``while`` is filed under
+    `SPANS_ITS_BODY`, which `seconds_by_phase` and `seconds_by_part`
+    leave out: the instructions of the computations it calls are in the
+    table under their own names."""
     table, inside, fusions, copies, computation = {}, {}, {}, {}, None
-    grouped = {}
+    grouped, moved = {}, {}
     for line in hlo_text.splitlines():
         m = _INSTRUCTION.match(line)
         if m is None:
             header = _COMPUTATION.match(line)
             if header:
                 computation = header.group(1)
+            continue
+        opcode = _OPCODE.search(line, m.end())
+        if opcode and opcode.group(1) in ("conditional", "while"):
+            table[m.group(1)] = SPANS_ITS_BODY
             continue
         op = _OP_NAME.search(line, m.end())
         table[m.group(1)] = op.group(1) if op else ""
@@ -179,8 +199,11 @@ def instruction_scopes(hlo_text: str) -> dict:
         if called and phase_of(table[m.group(1)]) == "other":
             fusions[m.group(1)] = called.group(1)
         copied = None if op else _COPY_OF.search(line, m.end())
+        moving = None if op else _ASYNC_COPY_OF.search(line, m.end())
         if copied:
             copies[m.group(1)] = copied.group(1)
+        elif moving:
+            moved[m.group(1)] = moving.group(1)
         elif (_GROUPED_MATMUL.match(m.group(1))
               and phase_of(table[m.group(1)]) == "other"):
             operands = _OPERANDS.search(line, m.end())
@@ -195,8 +218,14 @@ def instruction_scopes(hlo_text: str) -> dict:
                 n for n in names if phase_of(n) == winner))
     for name, source in copies.items():  # in program order: chains resolve
         table[name] = table.get(source, "")
+
+    def made(operand):  # what an asynchronous copy moved, else itself
+        while operand in moved:
+            operand = moved[operand]
+        return operand
+
     for name, operands in grouped.items():
-        table[name] = next((table[o] for o in operands
+        table[name] = next((table[o] for o in map(made, operands)
                             if phase_of(table.get(o)) != "other"),
                            table[name])
     return table
@@ -206,6 +235,8 @@ def _seconds_by(key_of: Callable, instructions: dict, table: dict):
     seconds, found, total = {}, 0.0, 0.0
     for hlo_text, seen in instructions.items():
         name = hlo_text.split(" ", 1)[0].lstrip("%")
+        if table.get(name) == SPANS_ITS_BODY:
+            continue
         total += seen["seconds"]
         if name in table:
             found += seen["seconds"]
@@ -219,7 +250,9 @@ def seconds_by_phase(instructions: dict, table: dict):
     """``({phase: seconds}, found)`` for a profile's op events as
     ``{hlo text: {"count", "seconds"}}`` (chipbench's ``instructions``;
     the instruction's name is the text's first token). The phases
-    partition the seconds: an instruction the table lacks is ``other``.
+    partition the seconds, but for a ``conditional``'s or a ``while``'s
+    own event, which is left out (`SPANS_ITS_BODY`): an instruction the
+    table lacks is ``other``.
     ``found`` is the share of the seconds whose instruction the table
     has; where it is low the module is not the one that was profiled."""
     return _seconds_by(phase_of, instructions, table)
@@ -319,10 +352,12 @@ def note_layer(counter: str) -> None:
 
 
 def note_moe(held: int, total: int, per_token: int,
-             buffer_rows: int) -> None:
+             buffer_rows: int, chunk_rows: int) -> None:
     """Called once per sparse-expert layer where ``models/transformer.py``
     lays its blocks out (outside ``jax.checkpoint``, so ``moe_layers`` is
-    a count). A no-op outside a traced ``data_parallel_step``."""
+    a count), with the layer's bound and the rows of one of the chunks
+    its sorted side is walked in. A no-op outside a traced
+    ``data_parallel_step``."""
     record = _tracing.get()
     if record is None:
         return
@@ -331,3 +366,5 @@ def note_moe(held: int, total: int, per_token: int,
     c["experts_held"], c["experts_total"] = held, total
     c["experts_per_token"] = per_token
     c["moe_buffer_rows"] = buffer_rows
+    c["moe_chunk_rows"] = chunk_rows
+    c["moe_chunks"] = buffer_rows // chunk_rows

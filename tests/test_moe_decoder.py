@@ -145,6 +145,80 @@ def test_no_row_is_dropped_under_skew(count):
                                    rtol=2e-3)
 
 
+#: 8 experts, 4 a token, experts 4..7 held: four chunks of a token's rows
+#: each. The scores' bias decides how many of a token's four are held.
+LIVE_CHUNKS = {
+    "none": ((0, 1, 2, 3), 0),       # no token chooses a held expert
+    "one": ((0, 1, 2), 1),           # at most one held expert a token
+    "a-group-split": ((0, 1), 2),    # ~4/3 a token: chunk 0 ends in a group
+    "all": ((4, 5, 6, 7), 4),        # every token chooses all four held
+}
+
+
+@pytest.mark.parametrize("case", LIVE_CHUNKS)
+def test_chunks_follow_the_rows_routed(case, monkeypatch):
+    """(g) The sorted side is walked in chunks of a token's rows, and a
+    chunk no routed row reaches does not run: with none, one, two (an
+    expert's rows split over the boundary) and all four chunks live, the
+    layer equals the reference row for row and gradient for gradient,
+    and the same layer evaluated as one chunk of all the rows."""
+    favoured, live_chunks = LIVE_CHUNKS[case]
+    t, first, count, k = 32, 4, 4, 4
+    u, router, params = layer_inputs(t=t, experts=8, seed=3)
+    held = jax.tree.map(lambda x: x[first:first + count], params)
+    bias = jnp.zeros((8,)).at[jnp.asarray(favoured)].set(50.0)
+    chosen, w = moe.route(u @ router + bias, k)
+    sizes = np.bincount(np.asarray(chosen).ravel(), minlength=8)[first:]
+    live = int(sizes.sum())
+    assert -(-live // t) == live_chunks
+    if case == "a-group-split":   # the boundary lies inside a group
+        assert t not in np.cumsum(sizes)
+
+    def ours(u, held, w):
+        return moe.expert_layer(u, chosen, w, held, (first, count))
+
+    def ref(u, held, w):
+        dense = jnp.zeros((t, 8)).at[jnp.arange(t)[:, None], chosen].set(w)
+        return reference.experts(u, held, dense, first)
+
+    def value_and_grads(f):
+        cot = jnp.cos(jnp.arange(t * 32, dtype=jnp.float32)).reshape(t, 32)
+        return jax.jit(lambda *a: (f(*a), jax.grad(
+            lambda *a: jnp.sum(f(*a) * cot), (0, 1, 2))(*a)))(u, held, w)
+
+    got, want = value_and_grads(ours), value_and_grads(ref)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4,
+                                   rtol=1e-4)
+    if case == "none":
+        assert all(not np.asarray(a).any() for a in jax.tree.leaves(got))
+    assert moe.chunk_rows(t, t * k) == t
+    monkeypatch.setattr(moe, "chunk_rows", lambda n, rows: rows)
+    for a, b in zip(jax.tree.leaves(got),
+                    jax.tree.leaves(value_and_grads(ours))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-6,
+                                   rtol=2e-6)
+
+
+def test_a_cotangent_at_a_pair_without_a_held_expert_reaches_nothing():
+    """A pair no held expert takes comes out as junk, which a caller
+    selects away; whatever cotangent it hands back there is in no
+    expert's group and moves no gradient, masked or not."""
+    t, k, count = 32, 4, 4
+    u, _, params = layer_inputs(t=t, experts=count, seed=5)
+    rng = np.random.RandomState(6)
+    key = jnp.asarray(rng.randint(0, 2 * count, t * k).clip(0, count),
+                      jnp.int32)                      # count: none held
+    out, back = jax.vjp(
+        lambda u, p: moe._experts_on_pairs(u, key, p, t * k), u, params)
+    g = jnp.asarray(rng.randn(*out.shape), jnp.float32)
+    held = (key < count)[:, None]
+    assert 0 < int(held.sum()) < t * k
+    for a, b in zip(jax.tree.leaves(back(g)),
+                    jax.tree.leaves(back(jnp.where(held, g, 0.0)))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_expert_parallel_exchange_equals_the_one_chip_layer():
     """(f) The 'ep' path on a 4-device mesh (16 experts a chip, a
     quarter of the tokens each, two all-to-alls sized for the bound)
@@ -215,6 +289,7 @@ def test_step_scopes_and_counters(seeded):
     assert (counters["experts_held"], counters["experts_total"],
             counters["experts_per_token"]) == (4, 8, 2)
     assert counters["moe_buffer_rows"] == 24 * 2    # 24 tokens a chip, k=2
+    assert (counters["moe_chunks"], counters["moe_chunk_rows"]) == (2, 24)
     assert 0 < counters["attention_window_calls"] < counters["attention_calls"]
     assert counters["attention_window_calls"] * 2 == \
         counters["attention_calls"]

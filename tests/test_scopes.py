@@ -61,10 +61,11 @@ def test_phase_and_part_of_literal_op_names(op_name, phase, part):
     assert phase in scopes.PHASES
 
 
-def test_instruction_scopes_and_seconds_by_phase():
-    def named(op):
-        return 'metadata={op_name="' + PRE + op + '" stack_frame_id=7}'
+def named(op):
+    return 'metadata={op_name="' + PRE + op + '" stack_frame_id=7}'
 
+
+def test_instruction_scopes_and_seconds_by_phase():
     text = "\n".join([
         "HloModule jit_hvd_data_parallel_step, entry_computation_layout={}",
         "%fused_computation.1 (p: f32[4]) -> f32[4] {",
@@ -157,6 +158,73 @@ def test_grouped_matmul_is_filed_with_the_rows_it_multiplies():
     assert scopes.phase_of(table["ragged-dot-none.2"]) == "backward"
     assert scopes.part_of(table["ragged-dot-none.2"]) == scopes.MOE
     assert table["ragged-dot-metadata.1"] == "ragged-dot-metadata"
+    # the rows may reach it through the compiler's asynchronous copy, which
+    # has no metadata: the matmul is filed with what was copied, the copy
+    # itself stays unfiled
+    moved = text.replace("/*index=1*/%fusion.4", "/*index=1*/%copy-done.6") \
+        .replace("  ROOT %ragged", "\n".join([
+            "  %copy-start.5 = (bf16[64,8]{1,0:S(1)}, bf16[64,8]{1,0}, u32[]) "
+            "copy-start(%fusion.4)",
+            "  %copy-done.6 = bf16[64,8]{1,0:S(1)} copy-done(%copy-start.5)",
+            "  ROOT %ragged"]))
+    table = scopes.instruction_scopes(moved)
+    assert table["ragged-dot-none.2"] == moe
+    assert table["copy-done.6"] == table["copy-start.5"] == ""
+
+
+@pytest.mark.parametrize("opcode", ["while", "conditional"])
+def test_a_loop_spans_its_body_and_is_counted_once(opcode):
+    """A ``while``'s (or a ``conditional``'s) own event in a profile
+    spans the events of its body (of the branch that ran): the sums
+    leave it out, so the phases still add up to the step, and the body's
+    instructions are filed by their own names, a backward rule's under
+    the scope the rule names again."""
+    fwd = PRE + "jvp(" + scopes.MOE + ")/while/body/gather"
+    bwd = PRE + "transpose(jvp(hvd.model))/" + scopes.MOE + "/while/body/mul"
+    calls = ("branch_computations={%skip, %chunk}" if opcode == "conditional"
+             else "condition=%skip, body=%chunk")
+    text = "\n".join([
+        "%chunk (p: (bf16[64,8])) -> (bf16[64,8]) {",
+        "  %p = (bf16[64,8]{1,0}) parameter(0)",
+        "  %fusion.4 = bf16[64,8]{1,0} fusion(%p), kind=kCustom, calls=%g, "
+        + named(fwd[len(PRE):]),
+        "  %fusion.5 = bf16[64,8]{1,0} fusion(%fusion.4), kind=kLoop, "
+        "calls=%h, " + named(bwd[len(PRE):]),
+        "  ROOT %tuple.6 = (bf16[64,8]{1,0}) tuple(%fusion.5)",
+        "}",
+        "ENTRY %main (a: bf16[64,8]) -> (bf16[64,8]) {",
+        "  %a = bf16[64,8]{1,0} parameter(0)",
+        "  %t = (bf16[64,8]{1,0}) tuple(%a)",
+        f"  ROOT %cond.7.clone = (bf16[64,8]{{1,0}}) {opcode}(%t), {calls}, "
+        + named("jvp(" + scopes.MOE + ")/while"),
+        "}"])
+    table = scopes.instruction_scopes(text)
+    assert table["cond.7.clone"] == scopes.SPANS_ITS_BODY
+    assert (table["fusion.4"], table["fusion.5"]) == (fwd, bwd)
+    instructions = {
+        "%cond.7.clone = (bf16[64,8]{1,0}) " + opcode + "(%t)":
+            {"count": 2, "seconds": 3.1},     # spans the two below
+        "%fusion.4 = bf16[64,8]{1,0} fusion(%p), kind=kCustom":
+            {"count": 2, "seconds": 2.0},
+        "%fusion.5 = bf16[64,8]{1,0} fusion(%fusion.4), kind=kLoop":
+            {"count": 2, "seconds": 1.0},
+    }
+    by_phase, found = scopes.seconds_by_phase(instructions, table)
+    assert by_phase == {"forward": 2.0, "backward": 1.0} and found == 1.0
+    by_part, found = scopes.seconds_by_part(instructions, table)
+    assert by_part == {scopes.MOE: 3.0} and found == 1.0
+
+
+def test_note_moe_notes_the_bound_and_its_chunks():
+    record = scopes.StepRecord()
+    with scopes.recording(record):
+        scopes.note_moe(8, 64, 6, 98304, 16384)
+        scopes.note_moe(8, 64, 6, 98304, 16384)
+    scopes.note_moe(8, 64, 6, 98304, 16384)   # outside a traced step
+    assert record.counters == {
+        "moe_layers": 2, "experts_held": 8, "experts_total": 64,
+        "experts_per_token": 6, "moe_buffer_rows": 98304,
+        "moe_chunk_rows": 16384, "moe_chunks": 6}
 
 
 @pytest.fixture(scope="module")

@@ -151,6 +151,46 @@ def test_latent_kernels_compile_at_the_mla_decoder_shapes(topo):
         "hvd_mla_fwd", "hvd_mla_bwd_dq", "hvd_mla_bwd_dkv"))
 
 
+@pytest.mark.parametrize("direction", ["forward", "gradient"])
+def test_chunked_expert_layer_compiles_at_the_sparse_cell_sizes(
+        topo, direction):
+    """The sparse-expert cell's layer: 16,384 tokens of 2560, experts 0-7
+    of 64 held, 6 a token, so six chunks of 16,384 sorted rows. The TPU's
+    compiler keeps one ``while`` a direction for the chunks after the
+    first (and no ``conditional``: nothing unrolled), adds no ``scatter``
+    (both directions of every row movement are gathers) and leaves no
+    hidden activation at the bound's size, [98304, 1536]."""
+    import re
+
+    from horovod_tpu.parallel import moe
+
+    one = SingleDeviceSharding(topo.devices[0])
+    t, d, f, held, k = 16384, 2560, 768, 8, 6
+
+    def array(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = {"gate": array((held, d, f), jnp.float32),
+              "up": array((held, d, f), jnp.float32),
+              "down": array((held, f, d), jnp.float32)}
+
+    def layer(u, w, params, chosen):  # the router's own gradient aside
+        return moe.expert_layer(u, chosen, w, params, (0, held))
+
+    fn = layer if direction == "forward" else jax.grad(
+        lambda *a: jnp.sum(layer(*a).astype(jnp.float32) ** 2), (0, 1, 2))
+    text = jax.jit(fn).lower(
+        array((t, d), jnp.bfloat16), array((t, k), jnp.float32), params,
+        array((t, k), jnp.int32)).compile().as_text()
+    assert moe.chunk_rows(t, t * k) == t
+    directions = 1 if direction == "forward" else 2
+    assert len(re.findall(r"\swhile\(", text)) == directions
+    assert not re.search(r"\sconditional\(", text)
+    assert not re.search(r"\sscatter\(", text)
+    assert "ragged-dot" in text and f"[{t},{2 * f}]" in text
+    assert f"[{t * k},{2 * f}]" not in text and f"[{t * k},{f}]" not in text
+
+
 def test_dense_kernels_trace_as_before_the_window_and_the_groups(topo):
     """``window=None, kv_heads=heads`` is the dense decoder's call: its
     jaxpr (grid, index maps and kernel bodies) is, letter for letter,
