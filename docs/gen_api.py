@@ -22,7 +22,7 @@ MODULES = [
     ("horovod_tpu.utils.checkpoint", "Checkpoints"),
     ("horovod_tpu.utils.timeline", "Timeline/profiling"),
     ("horovod_tpu.models", "Model zoo"),
-    ("horovod_tpu.models.transformer", "Decoder (dense, sparse-expert or latent-attention, composed per layer)"),
+    ("horovod_tpu.models.transformer", "Decoder (dense, sparse-expert, latent-attention or looped, composed per layer)"),
     ("horovod_tpu.parallel.moe", "Sparse experts: router and expert layer"),
     ("horovod_tpu.ops.pallas.flash_attention", "Pallas kernels"),
 ]

@@ -48,6 +48,21 @@ correction bias enters the choice only, the weights are the sigmoids
 normalised over the chosen times ``routed_scale``), its **input**
 (``router_input="normed"``: the expert layer's normed input) and the
 routed experts' **activation** (``expert_activation="silu"``).
+
+A **looped** decoder (``n_loops`` > 1; plain heads and a dense
+feed-forward) runs the one stack of blocks ``n_loops`` times over its
+own output with the same weights, as one ``lax.scan`` over the passes
+whose body is the stack: a weight's gradient is a sum over its uses.
+The final norm lies inside the recurrence: its output is what a pass's
+**exit** reads (the classifier, and a learned gate ``gate.w``,
+``gate.b`` on the normed state) and what the next pass starts from.
+`apply` returns the last pass's logits; `lm_loss` is the expected loss
+over the exits under the gates' exit distribution less ``exit_beta``
+times that distribution's entropy, each exit's classifier and
+cross-entropy running inside the recurrence so that only ``[n_loops,
+tokens]`` losses and gate logits leave it. **Sandwich norms**
+(``sandwich_norms=True``) put a second RMSNorm on each sub-layer's
+output before the residual add (``ln1_post``, ``ln2_post``).
 """
 
 from __future__ import annotations
@@ -107,6 +122,9 @@ class TransformerConfig:
     router_input: str = "block"            # or "normed": as the experts'
     routed_scale: float = 1.0              # on the sigmoid scoring's weights
     expert_activation: str = "relu"        # or "silu"
+    n_loops: int = 1                       # passes over the one stack
+    sandwich_norms: bool = False           # a norm on each sub-layer's output
+    exit_beta: float = 0.0                 # entropy's weight in a looped loss
 
     @property
     def head_dim(self) -> int:
@@ -155,6 +173,10 @@ def init(rng, cfg: TransformerConfig):
         params["pos"] = normal(keys[1], cfg.max_seq, cfg.d_model)
     if not cfg.tie_embeddings:
         params["head"] = normal(keys[2], cfg.d_model, cfg.vocab_size)
+    _check_looped(cfg)
+    if cfg.n_loops > 1:  # the exits' gate, from the one key no older leaf drew
+        params["gate"] = {"w": normal(keys[3], cfg.d_model),
+                          "b": jnp.zeros((), jnp.float32)}
     held = cfg.held[1]
     # as many keys a layer as the first decoders drew, so that a seed
     # still gives them the weights it gave; the further settings draw 18
@@ -199,8 +221,24 @@ def init(rng, cfg: TransformerConfig):
         else:
             blk["w1"] = normal(k[4], cfg.d_model, cfg.d_ff)
             blk["w2"] = normal(k[5], cfg.d_ff, cfg.d_model)
+        if cfg.sandwich_norms:
+            for name in ("ln1_post", "ln2_post"):
+                blk[name] = {"scale": jnp.ones((cfg.d_model,), jnp.float32)}
         params["blocks"].append(blk)
     return params
+
+
+def _check_looped(cfg: TransformerConfig) -> None:
+    """The recurrence and the sandwich norms are built for plain heads
+    and a dense feed-forward: no cell needs them elsewhere."""
+    if (cfg.n_loops > 1 or cfg.sandwich_norms) and (cfg.kv_latent
+                                                    or cfg.n_experts):
+        raise ValueError("n_loops > 1 and sandwich_norms take plain heads "
+                         "and a dense feed-forward: no latent heads, no "
+                         "experts")
+    if cfg.n_loops > 1 and cfg.xent_chunk:
+        raise ValueError("a looped decoder's exits take the dense loss, one "
+                         "exit at a time: no xent_chunk")
 
 
 def param_specs(cfg: TransformerConfig):
@@ -232,6 +270,8 @@ def param_specs(cfg: TransformerConfig):
             blk["mlp"] = dict(gated)
         else:
             blk.update(w1=P(None, tp), w2=P(tp, None))
+        if cfg.sandwich_norms:
+            blk.update(ln1_post={"scale": P()}, ln2_post={"scale": P()})
         return blk
 
     specs = {
@@ -243,6 +283,8 @@ def param_specs(cfg: TransformerConfig):
         specs["pos"] = P(None, None)
     if not cfg.tie_embeddings:
         specs["head"] = P(None, None)
+    if cfg.n_loops > 1:
+        specs["gate"] = {"w": P(None), "b": P()}
     return specs
 
 
@@ -264,7 +306,8 @@ def _constrain(x, spec, use_constraints):
 
 def apply(params, tokens, cfg: TransformerConfig, *, use_constraints: bool = True,
           attn_fn=None, positions=None,
-          return_hidden: bool = False, return_routing: bool = False):
+          return_hidden: bool = False, return_routing: bool = False,
+          per_pass=None):
     """Forward pass → logits (float32), or — with ``return_hidden=True``
     — the pre-projection hidden states [b, s, d] in ``cfg.dtype`` for
     the chunked LM loss (lm_loss with cfg.xent_chunk).
@@ -283,7 +326,16 @@ def apply(params, tokens, cfg: TransformerConfig, *, use_constraints: bool = Tru
     ``return_routing=True`` (a sparse-expert decoder) returns ``(result,
     [chosen experts [b*s, k] of each layer])``: what a comparison with a
     reference needs to tell a tie in the router from a fault.
+
+    A looped decoder (``cfg.n_loops`` > 1) gives the last pass's result;
+    with ``per_pass(s)``, a function of a pass's normed state [b, s, d],
+    it returns what that makes of every pass instead, stacked over the
+    passes (`lm_loss`'s exits).
     """
+    _check_looped(cfg)
+    if cfg.n_loops > 1 and attn_fn is not None:
+        raise ValueError("a looped decoder runs the default attention: "
+                         "no attn_fn")
     aspec = act_spec(cfg)
     if positions is None:
         positions = jnp.arange(tokens.shape[1])
@@ -316,9 +368,6 @@ def apply(params, tokens, cfg: TransformerConfig, *, use_constraints: bool = Tru
         """x + the block's attention over heads of one width."""
         with jax.named_scope(scopes.ATTENTION):
             h = _rmsnorm(x, blk["ln1"]["scale"])
-            if attn_fn is None:
-                scopes.note_attention(kernel=blocks is not None,
-                                      window=window is not None, kept=keeps)
             if blocks is not None:
                 o = _fused_attention(h, blk, cfg, blocks, window,
                                      rotary if rope else None)
@@ -333,7 +382,7 @@ def apply(params, tokens, cfg: TransformerConfig, *, use_constraints: bool = Tru
                 else:
                     o = attn_fn(q, *(_repeat_kv(a, cfg) for a in (k, v)))
                 o = jnp.einsum("bshk,hkd->bsd", o, blk["wo"].astype(cfg.dtype))
-            return x + o
+            return x + _normed_if(blk, "ln1_post", o)
 
     # the scopes sit inside the block, so they survive jax.checkpoint; what
     # a layer's feed-forward is follows from the parameters it was given
@@ -343,9 +392,6 @@ def apply(params, tokens, cfg: TransformerConfig, *, use_constraints: bool = Tru
             with jax.named_scope(scopes.ROUTER):
                 routed = _route(x, blk, cfg)
         if cfg.kv_latent:
-            if attn_fn is None:
-                scopes.note_attention(kernel=blocks is not None, kept=keeps,
-                                      latent=True)
             x = _latent_attention(x, blk, cfg, blocks, rotary, attn_fn)
         else:
             x = _attend(x, blk, window, rope)
@@ -376,47 +422,87 @@ def apply(params, tokens, cfg: TransformerConfig, *, use_constraints: bool = Tru
                     jnp.einsum("bsd,df->bsf", h, blk["w1"].astype(cfg.dtype)))
                 ff = jnp.einsum("bsf,fd->bsd", ff,
                                 blk["w2"].astype(cfg.dtype))
-            x = x + ff
+            x = x + _normed_if(blk, "ln2_post", ff)
         return _constrain(x, aspec, use_constraints)
 
     # one (checkpointed) function per kind of layer the pattern has
     block_fns, routing = {}, []
-    for kind, blk in zip(kinds, params["blocks"]):
-        if kind not in block_fns:
-            fn = _block if kind == (None, False) else functools.partial(
-                _block, window=kind[0], rope=kind[1])
-            block_fns[kind] = jax.checkpoint(
-                fn, policy=_KEEP_KERNEL_RESIDUALS) if cfg.remat else fn
-        x = block_fns[kind](x, blk)
-        kept = _kept_bytes(tokens.shape, cfg, keeps,
-                           keeps_choice and "experts" in blk)
-        if kept:
-            scopes.note_kept(kept)
-        if "experts" in blk:
-            x, chosen = x
-            routing.append(chosen)
-            from ..parallel import moe
 
-            rows = x.shape[0] * x.shape[1]   # the tokens a chip has
-            bound = rows * min(cfg.experts_per_token, cfg.held[1])
-            scopes.note_moe(cfg.held[1], cfg.n_experts,
-                            cfg.experts_per_token, bound,
-                            moe.chunk_rows(rows, bound))
-            if "shared" in blk:
-                scopes.note_layer("shared_experts")
-        elif cfg.n_experts:
-            scopes.note_layer("dense_layers")
+    def _stack(x):
+        """The blocks, one after the other."""
+        for kind, blk in zip(kinds, params["blocks"]):
+            if kind not in block_fns:
+                fn = _block if kind == (None, False) else functools.partial(
+                    _block, window=kind[0], rope=kind[1])
+                block_fns[kind] = jax.checkpoint(
+                    fn, policy=_KEEP_KERNEL_RESIDUALS) if cfg.remat else fn
+            x = block_fns[kind](x, blk)
+            if attn_fn is None:  # out here a layer counts, traced or not
+                scopes.note_attention(
+                    kernel=blocks is not None, window=kind[0] is not None,
+                    kept=keeps, latent=bool(cfg.kv_latent))
+            kept = _kept_bytes(tokens.shape, cfg, keeps,
+                               keeps_choice and "experts" in blk)
+            if kept:
+                scopes.note_kept(kept)
+            if "experts" in blk:
+                x, chosen = x
+                routing.append(chosen)
+                from ..parallel import moe
+
+                rows = x.shape[0] * x.shape[1]   # the tokens a chip has
+                bound = rows * min(cfg.experts_per_token, cfg.held[1])
+                scopes.note_moe(cfg.held[1], cfg.n_experts,
+                                cfg.experts_per_token, bound,
+                                moe.chunk_rows(rows, bound))
+                if "shared" in blk:
+                    scopes.note_layer("shared_experts")
+            elif cfg.n_experts:
+                scopes.note_layer("dense_layers")
+        return x
+
+    def _final_norm(x):
+        with jax.named_scope(scopes.HEAD):
+            return _rmsnorm(x, params["ln_f"]["scale"])
+
+    if cfg.n_loops > 1:
+        # the recurrence: one scan over the passes, its body the stack and
+        # the final norm, whose output an exit reads and the next pass takes
+        def one_pass(s, _):
+            s = _final_norm(_stack(s))
+            return s, per_pass(s) if per_pass else None
+
+        with scopes.note_loop(cfg.n_loops, cfg.n_layers,
+                              cfg.n_loops if per_pass else 1):
+            x, passes = jax.lax.scan(one_pass, x, None, length=cfg.n_loops)
+        if per_pass:
+            return passes
+    else:
+        x = _final_norm(_stack(x))
     with jax.named_scope(scopes.HEAD):
-        x = _rmsnorm(x, params["ln_f"]["scale"])
-        if return_hidden:
-            out = x  # pre-projection activations for the chunked LM loss
-        elif not cfg.tie_embeddings:
-            out = jnp.einsum("bsd,dv->bsv", x.astype(jnp.float32),
-                             params["head"])
-        else:
-            out = jnp.einsum("bsd,vd->bsv", x.astype(jnp.float32),
-                             params["embed"])
+        # pre-projection activations for the chunked LM loss, or logits
+        out = x if return_hidden else _classify(x, params, cfg)
     return (out, routing) if return_routing else out
+
+
+def _classify(x, params, cfg: TransformerConfig):
+    """Float32 logits [b, s, rows] of normed states [b, s, d]."""
+    if not cfg.tie_embeddings:
+        return jnp.einsum("bsd,dv->bsv", x.astype(jnp.float32),
+                          params["head"])
+    return jnp.einsum("bsd,vd->bsv", x.astype(jnp.float32), params["embed"])
+
+
+def _target_logp(logits, targets):
+    """Each token's log-probability of its target, [b, s] float32."""
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    return jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+
+
+def _normed_if(blk, name: str, out):
+    """A sub-layer's output on its way to the residual add: through the
+    sandwich norm ``name`` where the block was given one."""
+    return _rmsnorm(out, blk[name]["scale"]) if name in blk else out
 
 
 def _route(x, blk, cfg: TransformerConfig):
@@ -671,8 +757,17 @@ def lm_loss(params, tokens, cfg: TransformerConfig, *,
     With ``cfg.xent_chunk`` set, the classifier streams over vocab
     chunks (ops/xent.py chunked_softmax_xent) and float32 logits
     [tokens, vocab] are never materialized. ``return_routing=True``
-    returns ``(loss, routing)`` with `apply`'s routing (``has_aux``)."""
+    returns ``(loss, routing)`` with `apply`'s routing (``has_aux``).
+
+    A looped decoder (``cfg.n_loops`` > 1): the expected loss over its
+    exits less ``cfg.exit_beta`` times the exit distribution's entropy
+    (`exit_loss`); ``return_exits=True`` returns ``(loss, (each exit's
+    mean loss [n_loops], the mean exit distribution [n_loops]))``."""
     targets = tokens[:, 1:]
+    if cfg.n_loops > 1:
+        if return_routing:
+            raise ValueError("a looped decoder has no routing to return")
+        return _looped_loss(params, tokens[:, :-1], targets, cfg, **kw)
     out = apply(params, tokens[:, :-1], cfg, return_hidden=bool(cfg.xent_chunk),
                 return_routing=return_routing, **kw)
     out, routing = out if return_routing else (out, None)
@@ -685,8 +780,53 @@ def lm_loss(params, tokens, cfg: TransformerConfig, *,
             loss = chunked_softmax_xent(out.reshape(b * s, d), w,
                                         targets.reshape(-1), cfg.xent_chunk)
         else:
-            logp = jax.nn.log_softmax(out, axis=-1)
-            ll = jnp.take_along_axis(logp, targets[..., None],
-                                     axis=-1)[..., 0]
-            loss = -jnp.mean(ll)
+            loss = -jnp.mean(_target_logp(out, targets))
     return (loss, routing) if return_routing else loss
+
+
+def _looped_loss(params, tokens, targets, cfg: TransformerConfig, *,
+                 return_exits: bool = False, **kw):
+    """`lm_loss` of a looped decoder. Each pass's exit runs inside the
+    recurrence, under a checkpoint that keeps nothing: the float32
+    logits [tokens, rows] of one exit at a time exist, forward and
+    backward, and only each token's loss and gate logit leave a pass.
+    (Taking an exit's positions a piece at a time, or picking the
+    target's logit by a comparison for the gather, made the TPU
+    compiler's count of the step larger, not smaller: PERF.md, PR 35.)"""
+    @jax.checkpoint
+    def exit_of(s, head, gate):
+        with jax.named_scope(scopes.HEAD):
+            losses = -_target_logp(_classify(s, head, cfg), targets)
+        with jax.named_scope(scopes.EXIT):
+            logit = jnp.einsum("bsd,d->bs", s.astype(jnp.float32),
+                               gate["w"]) + gate["b"]
+        return losses, logit
+
+    head = {w: params[w] for w in ("embed", "head") if w in params}
+    losses, gate_logits = apply(
+        params, tokens, cfg, **kw,
+        per_pass=lambda s: exit_of(s, head, params["gate"]))
+    with jax.named_scope(scopes.EXIT):
+        loss, exits = exit_loss(losses, gate_logits, cfg.exit_beta)
+    return (loss, exits) if return_exits else loss
+
+
+def exit_loss(losses, gate_logits, beta: float):
+    """The expected loss over a looped decoder's exits less ``beta``
+    times the exit distribution's entropy, from each exit's token losses
+    and gate logits, [passes, ...tokens] float32 each. With ``lam_t =
+    sigmoid(gate_logits[t])`` a token leaves at exit ``t`` with
+    probability ``p_t = lam_t * prod_{j<t}(1 - lam_j)`` and at the last
+    with what is left, ``prod_{j<last}(1 - lam_j)`` (the last gate is
+    not read). In logarithms: ``log(1 - lam) = log_sigmoid(-logit)``.
+    → (loss, (each exit's mean loss, the mean exit distribution))."""
+    stays = jax.nn.log_sigmoid(-gate_logits[:-1])
+    reached = jnp.concatenate(  # log prod_{j<t}(1 - lam_j), t = 0..last
+        [jnp.zeros_like(stays[:1]), jnp.cumsum(stays, axis=0)])
+    log_p = reached + jnp.concatenate(
+        [jax.nn.log_sigmoid(gate_logits[:-1]), jnp.zeros_like(stays[:1])])
+    p = jnp.exp(log_p)
+    per_token = jnp.sum(p * losses, axis=0) + beta * jnp.sum(p * log_p, axis=0)
+    over_tokens = tuple(range(1, losses.ndim))
+    return jnp.mean(per_token), (jnp.mean(losses, axis=over_tokens),
+                                 jnp.mean(p, axis=over_tokens))

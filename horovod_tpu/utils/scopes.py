@@ -34,6 +34,11 @@ Scopes the program sets (the only place their names are written):
                                 under its parent)
 - ``hvd.model/shared_expert``   the gated MLP every token takes beside its
                                 routed experts
+- ``hvd.model/exit``            a looped decoder's exits: each pass's gate on
+                                its normed state, then the exit distribution,
+                                its entropy and the expected loss (the final
+                                norm, classifier and cross-entropy of every
+                                exit stay under ``hvd.model/head``)
 
 Forward, backward and recompute need no scope: JAX marks them itself
 (``jvp(``, ``transpose(``, ``rematted_computation``).
@@ -50,21 +55,27 @@ A decoder block under ``remat`` keeps these and recomputes the rest.
 Counters (`StepRecord.counters`, noted once while a step is traced, so
 per step and per chip): ``collectives`` the exchange issued,
 ``collective_bytes`` handed to them, ``packed_bytes`` copied into flat
-buffers, ``axis_size``; ``attention_calls`` the decoder's default attention
-traced and ``attention_kernel_calls`` of them routed to the fused
-kernels (a share of the two survives retracing under ``jax.checkpoint``),
+buffers, ``axis_size``; ``attention_calls`` the layers whose attention is
+the decoder's default one (a looped decoder's once a pass) and
+``attention_kernel_calls`` of them routed to the fused kernels,
 ``attention_window_calls`` of them with a window,
 ``attention_latent_calls`` of them over latent heads, ``attention_kept_calls``
 of them in a checkpointed block that keeps the kernels' residuals, and
-``remat_kept_mb``, those residuals' and a kept choice's bytes / 1e6 over the blocks traced
-(a count: each block's call is noted once); of a sparse-expert
+``remat_kept_mb``, those residuals' and a kept choice's bytes / 1e6 over the blocks
+(counts all: each layer is noted once, outside its checkpoint); of a sparse-expert
 decoder ``moe_layers``, ``experts_held`` of ``experts_total`` in each,
 ``experts_per_token`` chosen, ``moe_buffer_rows``, the bound its expert layer's buffers are sized for
 (per layer: tokens times the most experts one token can have here),
 ``moe_chunks`` and ``moe_chunk_rows``, the chunks that bound is walked in
 and the rows of each (how many of them ran is the data's: a trace's),
 ``dense_layers`` its leading layers without experts and ``shared_experts``
-its expert layers with shared experts (a count of layers each).
+its expert layers with shared experts (a count of layers each); of a
+looped decoder ``loop_steps`` the passes over its stack, ``loop_layers``
+the blocks of one pass and ``loop_exits`` the exits a step takes a loss
+from. The recurrence is a ``lax.scan`` whose body is traced once, so
+nothing counts there by being traced: what is noted inside `note_loop`
+counts once a pass (``attention_calls`` = blocks x passes, and
+``remat_kept_mb`` what all the passes keep).
 """
 
 from __future__ import annotations
@@ -93,6 +104,7 @@ ROUTER = MODEL + "/router"
 MOE = MODEL + "/moe"
 LATENT = MODEL + "/latent"
 SHARED_EXPERT = MODEL + "/shared_expert"
+EXIT = MODEL + "/exit"
 
 #: `flash_attention`'s residuals, by the names its forward rule gives them
 KEPT_BY_REMAT = tuple("hvd.attention/" + a
@@ -278,6 +290,10 @@ class StepRecord:
 
 _tracing: contextvars.ContextVar = contextvars.ContextVar(
     "hvd_step_record", default=None)
+#: how often what is being traced runs in a step: a loop's trip count
+#: while its body is traced (`note_loop`), else 1
+_times: contextvars.ContextVar = contextvars.ContextVar(
+    "hvd_times_a_step", default=1)
 
 
 @contextlib.contextmanager
@@ -307,26 +323,31 @@ def note_exchange(buffers, axis_name: str, packed: bool = False) -> None:
 
 def note_attention(kernel: bool, window: bool = False,
                    kept: bool = False, latent: bool = False) -> None:
-    """Called where ``models/transformer.py`` routes one default
-    attention call, to the fused kernels or to `causal_attention`, with
-    a window or without; ``kept``: in a checkpointed block whose policy
-    keeps the kernels' residuals (`KEPT_BY_REMAT`). A block under
-    ``jax.checkpoint`` is traced once or more than once, so read the
-    counters as shares of ``attention_calls``. A no-op outside a traced
+    """Called once per layer whose attention is the default one, where
+    ``models/transformer.py`` lays its blocks out (outside
+    ``jax.checkpoint``, which traces a block of one kind once however
+    many layers share it: the counters count layers, and inside
+    `note_loop` every pass's), with where the layer's call goes: to the
+    fused kernels or to `causal_attention`, with a window or without;
+    ``kept``: in a checkpointed block whose policy keeps the kernels'
+    residuals (`KEPT_BY_REMAT`). A no-op outside a traced
     ``data_parallel_step``."""
     record = _tracing.get()
     if record is None:
         return
-    c = record.counters
-    c["attention_calls"] = c.get("attention_calls", 0) + 1
+    c, times = record.counters, _times.get()
+    c["attention_calls"] = c.get("attention_calls", 0) + times
     c["attention_kernel_calls"] = (c.get("attention_kernel_calls", 0)
-                                   + int(kernel))
-    c["attention_kept_calls"] = c.get("attention_kept_calls", 0) + int(kept)
+                                   + times * int(kernel))
+    c["attention_kept_calls"] = (c.get("attention_kept_calls", 0)
+                                 + times * int(kept))
     c.setdefault("remat_kept_mb", 0.0)
     if window:  # a decoder without windows keeps the counters it had
-        c["attention_window_calls"] = c.get("attention_window_calls", 0) + 1
+        c["attention_window_calls"] = (c.get("attention_window_calls", 0)
+                                       + times)
     if latent:  # and one without latent heads
-        c["attention_latent_calls"] = c.get("attention_latent_calls", 0) + 1
+        c["attention_latent_calls"] = (c.get("attention_latent_calls", 0)
+                                       + times)
 
 
 def note_kept(nbytes: int) -> None:
@@ -338,7 +359,28 @@ def note_kept(nbytes: int) -> None:
     record = _tracing.get()
     if record is not None:
         c = record.counters
-        c["remat_kept_mb"] = c.get("remat_kept_mb", 0.0) + nbytes / 1e6
+        c["remat_kept_mb"] = (c.get("remat_kept_mb", 0.0)
+                              + _times.get() * nbytes / 1e6)
+
+
+@contextlib.contextmanager
+def note_loop(steps: int, layers: int, exits: int):
+    """Around the one ``lax.scan`` of a looped decoder, where
+    ``models/transformer.py`` lays the recurrence out: ``steps`` passes
+    over ``layers`` blocks, ``exits`` of them ending in a loss. The
+    scan's body is traced once whatever its trip count, so what
+    `note_attention` and `note_kept` note while it is traced counts
+    ``steps`` times: the counters state what a step runs and keeps. The
+    counters are a no-op outside a traced ``data_parallel_step``."""
+    record = _tracing.get()
+    if record is not None:
+        record.counters.update(loop_steps=steps, loop_layers=layers,
+                               loop_exits=exits)
+    token = _times.set(_times.get() * steps)
+    try:
+        yield
+    finally:
+        _times.reset(token)
 
 
 def note_layer(counter: str) -> None:

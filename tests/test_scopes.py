@@ -33,6 +33,14 @@ PRE = "jit(hvd_data_parallel_step)/shard_map/hvd.step/"
     (PRE + "transpose(jvp(hvd.step))/jvp()/checkpoint/rematted_computation/"
      "hvd.model/shared_expert/dot_general", "recompute",
      "hvd.model/shared_expert"),
+    # a looped decoder's model lives in a scan's body, forward and backward
+    (PRE + "jvp(hvd.step)/jvp(while)/body/closed_call/checkpoint/"
+     "hvd.model/exit/dot_general", "forward", "hvd.model/exit"),
+    (PRE + "transpose(jvp(hvd.step))/while/body/closed_call/checkpoint/"
+     "rematted_computation/hvd.model/head/dot_general", "recompute",
+     "hvd.model/head"),
+    (PRE + "transpose(jvp(hvd.model/exit))/mul", "backward",
+     "hvd.model/exit"),
     # a nested name is filed under its parent: the new parts sit beside it
     (PRE + "jvp(hvd.model/attention)/hvd.model/latent/dot_general",
      "forward", "hvd.model/attention"),
@@ -225,6 +233,75 @@ def test_note_moe_notes_the_bound_and_its_chunks():
         "moe_layers": 2, "experts_held": 8, "experts_total": 64,
         "experts_per_token": 6, "moe_buffer_rows": 98304,
         "moe_chunk_rows": 16384, "moe_chunks": 6}
+
+
+def test_note_loop_counts_what_its_body_notes_once_a_pass():
+    """A scan's body is traced once whatever its trip count: inside
+    `note_loop` an attention call and a kept block count ``steps``
+    times; outside it, and after it, once; and the loop's own counters
+    are what it was given. The multiplier works with no step being
+    traced too (it is the counters that are a no-op there)."""
+    record = scopes.StepRecord()
+    with scopes.recording(record):
+        scopes.note_attention(kernel=True, kept=True)
+        scopes.note_kept(1_000_000)
+        with scopes.note_loop(4, 2, 4):
+            for _ in range(2):            # the body's two blocks, traced once
+                scopes.note_attention(kernel=True, kept=True)
+                scopes.note_kept(1_000_000)
+        scopes.note_attention(kernel=False)
+    with scopes.note_loop(3, 1, 1):       # outside a traced step
+        scopes.note_attention(kernel=True)
+    assert record.counters == {
+        "attention_calls": 1 + 2 * 4 + 1, "attention_kernel_calls": 9,
+        "attention_kept_calls": 9, "remat_kept_mb": pytest.approx(9.0),
+        "loop_steps": 4, "loop_layers": 2, "loop_exits": 4}
+
+
+def test_a_looped_step_is_filed_from_inside_its_loops():
+    """A looped decoder's compiled step keeps its whole model inside two
+    ``while`` bodies (the scan forward, its transpose backward): the
+    loops' own events are left out (`SPANS_ITS_BODY`), the bodies'
+    instructions carry every phase and every part of the model, the
+    exits' among them, and the counters count every pass's blocks."""
+    cfg = T.TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=2, d_ff=48,
+        max_seq=16, remat=True, positions="layout", rope_layout=(1,),
+        tie_embeddings=False, mlp="gated", n_loops=3, sandwich_norms=True,
+        exit_beta=0.05)
+    opt = hvd.DistributedOptimizer(optax.adamw(1e-3))
+
+    def per_chip(params, opt_state, tokens):
+        loss, grads = jax.value_and_grad(T.lm_loss)(
+            params, tokens, cfg, use_constraints=False)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return (optax.apply_updates(params, updates), opt_state,
+                jax.lax.pmean(loss, "hvd"))
+
+    from jax.sharding import Mesh
+
+    step = data_parallel_step(per_chip, mesh=Mesh(jax.devices()[:1], ("hvd",)))
+    params = T.init(jax.random.PRNGKey(0), cfg)
+    step.lower(params, opt.init(params), jnp.zeros((2, 17), jnp.int32))
+    counters = dp.step_counters(step)
+    assert (counters["loop_steps"], counters["loop_layers"],
+            counters["loop_exits"]) == (3, 2, 3)
+    assert counters["attention_calls"] == 2 * 3   # layers x passes
+    # the einsum path names nothing to keep: numbers all the same
+    assert (counters["attention_kernel_calls"],
+            counters["attention_kept_calls"],
+            counters["remat_kept_mb"]) == (0, 0, 0.0)
+    table = dp.scope_table(step)
+    loops = [n for n, op in table.items() if op == scopes.SPANS_ITS_BODY]
+    assert len(loops) >= 2
+    in_a_body = {op for op in table.values() if "while/body" in op}
+    phases = collections.Counter(scopes.phase_of(op) for op in in_a_body)
+    assert {"forward", "backward", "recompute"} <= set(phases)
+    parts = {scopes.part_of(op) for op in in_a_body}
+    assert {scopes.ATTENTION, scopes.MLP, scopes.HEAD, scopes.EXIT} <= parts
+    # the exit distribution and the expected loss come after the loop
+    assert any(scopes.part_of(op) == scopes.EXIT and "while/body" not in op
+               for op in table.values())
 
 
 @pytest.fixture(scope="module")

@@ -11,6 +11,7 @@ off around the compiles: an entry written for a described chip cannot be
 read back without one, and the next compile would warn.
 """
 
+import collections
 import importlib
 import os
 import types
@@ -384,6 +385,69 @@ def test_toy_lm_step_builds_no_gradient_exchange_on_one_chip(topo):
     assert (counters["collectives"], counters["collective_bytes"],
             counters["packed_bytes"], counters["axis_size"]) == (0, 0, 0, 1)
     assert counters["attention_kernel_calls"] == counters["attention_calls"]
+
+
+def test_looped_lm_step_keeps_its_kernels_inside_the_loops(topo, monkeypatch):
+    """A looped decoder's step for one described chip, as the chip traces
+    it (the decoder's rule answering for a TPU): the recurrence compiles
+    to ``while``s whose bodies hold the three fused kernels once a block
+    (two blocks, three passes: two forward kernels in the module, none
+    in the recomputation, since what the backward kernels read is kept
+    across the scan), the loops' own instructions are filed as spanning
+    their bodies, and the exits' part is there in every phase."""
+    import optax
+
+    import horovod_tpu as hvd
+    from horovod_tpu.models import transformer as T
+    from horovod_tpu.parallel import data_parallel_step, dp
+    from horovod_tpu.utils import scopes
+
+    monkeypatch.setattr(T, "_on_tpu", lambda: True)
+    monkeypatch.setattr(jax, "device_count", lambda *a: 1)
+    mesh = Mesh(np.array(topo.devices[:1]), ("hvd",))
+    cfg = T.TransformerConfig(
+        vocab_size=512, d_model=256, n_heads=2, n_layers=2, d_ff=512,
+        max_seq=512, remat=True, positions="layout", rope_layout=(1,),
+        rope_theta=1e6, tie_embeddings=False, mlp="gated", n_loops=3,
+        sandwich_norms=True, exit_beta=0.05)
+    opt = hvd.DistributedOptimizer(optax.adamw(1e-3))
+
+    def step(params, opt_state, tokens):
+        loss, grads = jax.value_and_grad(T.lm_loss)(
+            params, tokens, cfg, use_constraints=False)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return (optax.apply_updates(params, updates), opt_state,
+                jax.lax.pmean(loss, "hvd"))
+
+    def placed(tree, spec):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, spec)), tree)
+
+    params = jax.eval_shape(lambda: T.init(jax.random.PRNGKey(0), cfg))
+    step = data_parallel_step(step, mesh=mesh)
+    text = step.lower(
+        placed(params, P()), placed(jax.eval_shape(opt.init, params), P()),
+        placed(jax.ShapeDtypeStruct((1, 513), jnp.int32), P("hvd"))
+    ).compile().as_text()
+    table = scopes.instruction_scopes(text)
+    assert sum(op == scopes.SPANS_ITS_BODY for op in table.values()) >= 2
+    kernels = collections.Counter(
+        (name.split(".")[0], scopes.phase_of(op), scopes.part_of(op),
+         "while/body" in op)
+        for name, op in table.items() if name.startswith("hvd_flash"))
+    assert kernels == {
+        ("hvd_flash_fwd", "forward", scopes.ATTENTION, True): 2,
+        ("hvd_flash_bwd_dq", "backward", scopes.ATTENTION, True): 2,
+        ("hvd_flash_bwd_dkv", "backward", scopes.ATTENTION, True): 2}
+    exits = {scopes.phase_of(op) for op in table.values()
+             if scopes.part_of(op) == scopes.EXIT}
+    assert exits >= {"forward", "backward"}
+    counters = dp.step_counters(step)
+    assert (counters["loop_steps"], counters["attention_calls"],
+            counters["attention_kernel_calls"],
+            counters["attention_kept_calls"]) == (3, 6, 6, 6)
+    assert counters["remat_kept_mb"] == pytest.approx(
+        6 * T._kept_bytes((1, 512), cfg) / 1e6)
 
 
 def test_checkpointed_decoder_traces_and_lowers_each_kernel_once(
