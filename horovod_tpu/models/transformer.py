@@ -349,11 +349,10 @@ def apply(params, tokens, cfg: TransformerConfig, *, use_constraints: bool = Tru
     blocks = None if attn_fn else fused_attention_blocks(
         tokens.shape[1], cfg.head_dim, use_constraints)
     # a checkpointed block keeps what the fused backward kernels read (the
-    # arrays `flash_attention` names) and, of a router on the normed input,
-    # the choice (`_route`), and recomputes the rest; where no kernel runs
-    # the choice is all that carries a name
+    # arrays `flash_attention` names) and, of an expert layer, its router's
+    # choice (`_route`) and what it sorted out of it, and recomputes the
+    # rest; where no kernel runs those are all that carry a name
     keeps = cfg.remat and blocks is not None
-    keeps_choice = cfg.remat and cfg.router_input != "block"
     kinds = [cfg.layer_kind(i) for i in range(cfg.n_layers)]
     if cfg.kv_latent:  # a latent head's rotary columns, in every layer
         rotary = _rope_tables(positions, cfg.d_rope, cfg.rope_theta)
@@ -442,7 +441,8 @@ def apply(params, tokens, cfg: TransformerConfig, *, use_constraints: bool = Tru
                     kernel=blocks is not None, window=kind[0] is not None,
                     kept=keeps, latent=bool(cfg.kv_latent))
             kept = _kept_bytes(tokens.shape, cfg, keeps,
-                               keeps_choice and "experts" in blk)
+                               cfg.remat and "experts" in blk,
+                               cfg.remat and "experts" in blk)
             if kept:
                 scopes.note_kept(kept)
             if "experts" in blk:
@@ -513,24 +513,26 @@ def _route(x, blk, cfg: TransformerConfig):
     ``parallel.moe.route`` by the configuration's scoring: (chosen,
     weights), [b*s, k] each.
 
-    A router that reads the expert layer's normed input reads what a
-    checkpointed block's backward pass recomputes, and not bit for bit
-    (XLA fuses the recomputation otherwise): its choice is named
-    (`scopes.KEPT_CHOICE`), whatever the scoring, so that the block's
-    policy keeps it and both passes route alike. The block's own input
-    is what the checkpoint kept: a router on it needs no name, and gets
-    none."""
+    Its choice is named (`scopes.KEPT_CHOICE`), whatever the scoring and
+    the input, so that a checkpointed block's policy keeps it and both
+    passes route alike: the expert layer keeps what it sorted out of the
+    forward pass's choice (`scopes.KEPT_ROUTING`), and the router's
+    gradient has to be taken at that choice. A recomputed choice is not
+    that one on the chip: a normed input is recomputed not bit for bit
+    (XLA fuses it otherwise), and a router on the block's own input whose
+    choice was recomputed read its gradient 2-3 times worse beside the
+    kept indices, never on the CPU (PERF.md §6, PRs 33 and 37)."""
     from ..parallel import moe
 
     scores = jnp.einsum("td,de->te",
                         x.reshape(-1, x.shape[-1]).astype(jnp.float32),
                         blk["router"], precision=jax.lax.Precision.HIGHEST)
-    name = None if cfg.router_input == "block" else scopes.KEPT_CHOICE
     if cfg.router_scoring == "softmax":
-        return moe.route(scores, cfg.experts_per_token, name=name)
+        return moe.route(scores, cfg.experts_per_token,
+                         name=scopes.KEPT_CHOICE)
     return moe.route(scores, cfg.experts_per_token,
                      scoring=cfg.router_scoring, bias=blk["router_bias"],
-                     scale=cfg.routed_scale, name=name)
+                     scale=cfg.routed_scale, name=scopes.KEPT_CHOICE)
 
 
 def _gated_mlp(h, weights, dtype):
@@ -677,18 +679,26 @@ def fused_attention_blocks(s: int, head_dim: int, use_constraints: bool):
 
 
 _KEEP_KERNEL_RESIDUALS = jax.checkpoint_policies.save_only_these_names(
-    # `KEPT_BY_REMAT` and the rotary parts; a router's choice (`_route`)
-    *scopes.KEPT_BY_REMAT_LATENT, scopes.KEPT_CHOICE)
+    # `KEPT_BY_REMAT` and the rotary parts; a router's choice (`_route`);
+    # what an expert layer sorted out of its routing
+    *scopes.KEPT_BY_REMAT_LATENT, scopes.KEPT_CHOICE, scopes.KEPT_ROUTING)
 
 
 def _kept_bytes(shape, cfg: TransformerConfig, kernels: bool = True,
-                choice: bool = False) -> int:
+                choice: bool = False, routing: bool = False) -> int:
     """Bytes a checkpointed block keeps on ``shape`` = (b, s) tokens.
     ``kernels``: `flash_attention`'s residuals, q and o [b, s, heads*hd],
     k and v [b, s, kv*hd] in ``cfg.dtype``, lse [b*heads, 1, s] float32;
     of `latent_attention`'s also q_rope [b, heads, s, d_rope] and the one
-    k_rope [b, s, d_rope]. ``choice``: its router's, [b*s, k] int32."""
+    k_rope [b, s, d_rope]. ``choice``: its router's, [b*s, k] int32.
+    ``routing``: what its expert layer sorted out of the routing
+    (``parallel.moe.index_bytes``)."""
     b, s = shape
+    if routing:
+        from ..parallel import moe
+
+        return (_kept_bytes(shape, cfg, kernels, choice)
+                + moe.index_bytes(b * s, cfg.experts_per_token, cfg.held[1]))
     wide = 2 * (cfg.n_heads + cfg.kv_heads) * cfg.head_dim
     if cfg.kv_latent:
         wide += (cfg.n_heads + 1) * cfg.d_rope

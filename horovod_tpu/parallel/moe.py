@@ -21,17 +21,18 @@ The cost follows the rows routed, chunk by chunk (`_sorted_side`). The
 sorted positions are cut into chunks of as many rows as the layer has
 tokens (`chunk_rows`: ``min(k, held)`` chunks on one chip; one, the
 whole, behind the exchange), and everything whose leading dimension is
-the sorted position (the gather of the tokens' rows, both grouped
-matmuls, the activation, and in the backward pass the gather of the
-cotangent out of pair order and all their transposes) is computed for
-one chunk at a time. Chunk 0 always runs; one loop a direction walks
-the further chunks that a routed row reaches, so a step in which the
-held experts take no more rows than there are tokens pays for one chunk
-and a loop of no trips, and a skewed one for as many chunks as its rows
-fill: more trips, never a dropped row, and one path whatever the
-routing. The pair side (the gather back to pair order, the weighted sum
-over a token's choices, and their transposes) runs once a layer at the
-bound, over one array assembled in place per direction.
+the sorted position is computed for one chunk at a time: the gather of
+the tokens' rows, both grouped matmuls, the activation and the routing
+weight, and the token side, which sums each token's rows of the chunk in
+token order and brings the sums home with one gather of ``tokens`` rows
+(`_token_sums`); in the backward pass the gather of the cotangent to the
+rows and all their transposes. Chunk 0 always runs; one loop a direction
+walks the further chunks that a routed row reaches, so a step in which
+the held experts take no more rows than there are tokens pays for one
+chunk and a loop of no trips, and a skewed one for as many chunks as its
+rows fill: more trips, never a dropped row, and one path whatever the
+routing. Nothing is sized for the (token, expert) pairs but vectors of
+indices (`_indices`).
 
 On one chip nothing is exchanged. With ``axis_name`` (inside a
 ``shard_map`` over the expert-parallel axis, each chip holding
@@ -43,9 +44,10 @@ split is padding, never a dropped token (SURVEY.md §7 hard part 6).
 Both directions of every row movement are gathers: the transpose of a
 gather is a scatter-add, which a TPU serialises row by row, but the
 sort that made the gather's indices also gives the indices of its
-inverse (`_take_rows`; `_sorted_side` has its own VJP for the same
-reason, and recomputes a chunk's hidden activation inside the backward
-loop, so that nothing of a chunk's size is handed out of a loop).
+inverse (`_sorted_side` has its own VJP for that reason, and recomputes
+a chunk's hidden activation inside the backward loop, so that nothing
+of a chunk's size is handed out of a loop; the exchange's `_take_rows`
+likewise).
 """
 
 from __future__ import annotations
@@ -54,6 +56,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import ad_checkpoint, lax
 
 from ..utils import scopes
@@ -109,7 +112,8 @@ def route(logits, k: int, *, scoring: str = "softmax", bias=None,
 def _take_rows(x, idx, back_idx, back_mask):
     """``x[idx]`` ([n, d] → [len(idx), d]) whose transpose is a gather
     too: row ``i`` of ``x`` is read by the result's rows ``back_idx[i]``
-    where ``back_mask[i]`` ([n, m] each), and by no other."""
+    where ``back_mask[i]`` ([n, m] each), and by no other. The `'ep'`
+    exchange's (`_exchanged`) only, as are `_combine` and its helpers."""
     return x.at[idx].get(mode="promise_in_bounds")
 
 
@@ -146,18 +150,17 @@ _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
 
 
-def _chunk(c, chunk: int, x, sizes, order):
+def _chunk(c, chunk: int, x, ix):
     """Chunk ``c`` of the sorted positions: its first position, the
     pairs at its ``chunk`` positions, their rows of ``x`` (pair ``p``
     reads row ``p % n``) and the part of each expert's group that lies in
     it (the groups follow one another from position 0)."""
     lo = c * chunk
-    pairs = lax.dynamic_slice_in_dim(order, lo, chunk)
-    ends = jnp.cumsum(sizes)
-    inside = (jnp.clip(ends, lo, lo + chunk)
-              - jnp.clip(ends - sizes, lo, lo + chunk)).astype(jnp.int32)
-    return (lo, pairs, x.at[pairs % x.shape[0]].get(mode="promise_in_bounds"),
-            inside)
+    pairs = lax.dynamic_slice_in_dim(ix["order"], lo, chunk)
+    inside = (lax.clamp(lo, ix["ends"], lo + chunk)
+              - lax.clamp(lo, ix["starts"], lo + chunk))
+    return (lo, pairs, x.at[lax.rem(pairs, x.shape[0])].get(
+        mode="promise_in_bounds"), inside)
 
 
 def _experts_on_rows(xs, gate_up, down, sizes, activation: str):
@@ -183,24 +186,147 @@ def _in_chunks(live, chunk: int, chunks: int, on_chunk):
     return lax.fori_loop(1, (live + chunk - 1) // chunk, on_chunk, carry)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _sorted_side(chunk: int, activation: str, x, weights, sizes, order,
-                 place):
-    """Everything of the expert layer whose leading dimension is the
-    sorted position, chunk by chunk: ``x`` [n, d] → [pairs, d], pair
-    ``p``'s row (row ``p % n`` of ``x``) through its expert.
+def _shifted(a, s: int, fill):
+    """``a`` moved ``s`` rows on, ``fill`` in the first ``s``: one pad."""
+    return lax.pad(a, np.array(fill, a.dtype),
+                   [(s, -s, 0)] + [(0, 0, 0)] * (a.ndim - 1))
 
-    ``weights``: ``gate``, ``up``, ``down``; ``sizes`` [held]: rows to
-    each expert, which fill the sorted positions from 0; ``order``
-    [rows]: sorted position → pair; ``place`` [pairs]: pair → sorted
-    position.
+
+def _as(a, dtype):
+    return lax.bitcast_convert_type(a, dtype)
+
+
+#: every sort of the layer: two int32 operands, by the first, unstable.
+#: The TPU's compiler builds a sort of 98,304 elements in 5 s and 1.5 MB
+#: of code, a stable one in 11 s and 1.8 MB, and it makes a stable sort
+#: whose second operand is no iota a sort of three (17 s, 2.4 MB). The
+#: keys are unique but for the token sort's, where a tie is two rows of
+#: one token, summed in either order.
+_UNSTABLE = {"num_keys": 1, "is_stable": False}
+
+
+def _indices(chunk: int, rows: int, count: int, key, route_w) -> dict:
+    """What both directions of `_sorted_side` read off the routing,
+    made outside it by three sorts and arithmetic, all of them sorts of
+    two int32 operands by the first, and each named `scopes.KEPT_ROUTING`
+    so that a checkpointed block keeps them and does not sort again (an
+    integer gather of ``pairs`` elements costs a TPU as much as a sixth
+    of a row gather; a sort of them costs its compiler seconds and
+    megabytes of code).
+
+    ``order`` [pairs]: sorted position → pair, the held experts' pairs
+    first and grouped by expert (a routed row lies in the first
+    ``rows``); ``starts``, ``ends`` [count]: each group's bounds (the
+    last ``ends`` is the rows routed); ``weight`` [pairs]: the pair's
+    routing weight, by sorted position (moved by the sort as its bits:
+    ``route_w``'s gradient is `_sorted_side`'s). The token side, by
+    chunk of ``chunk`` positions: ``back`` [rows], the chunk's positions
+    in token order; ``token`` [rows], the token of that slot (``n``
+    where no routed row is: last); ``end`` [chunks, n], the slot that
+    ends each token's rows in the chunk, or ``chunk`` where the token has
+    none there."""
+    n, k = route_w.shape
+    chunks = rows // chunk
+    pairs = lax.iota(jnp.int32, n * k)
+    order, weight = lax.sort((key * (n * k) + pairs, _as(
+        lax.stop_gradient(route_w).T.reshape(-1), jnp.int32)), **_UNSTABLE)
+    order = lax.rem(order, n * k)
+    ends = jnp.cumsum(jnp.sum(
+        key[:, None] == lax.iota(key.dtype, count)[None], axis=0,
+        dtype=jnp.int32))
+    live = ends[-1]
+    at = pairs[:rows]
+    slot, back = lax.sort(
+        (lax.div(at, chunk) * (n + 1)
+         + lax.select(at < live, lax.rem(order[:rows], n),
+                      lax.full_like(at, n)), at), **_UNSTABLE)
+    place = lax.sort((order, pairs), **_UNSTABLE)[1]    # pair → position
+    of = lax.select(place < live, lax.div(place, chunk),
+                    lax.full_like(place, chunks)).reshape(k, 1, n)
+    held = jnp.sum(of == lax.iota(jnp.int32, chunks)[:, None], axis=0,
+                   dtype=jnp.int32)                  # [chunks, n]
+    ix = {"order": order, "weight": _as(weight, jnp.float32), "ends": ends,
+          "starts": jnp.concatenate([jnp.zeros(1, jnp.int32), ends[:-1]]),
+          "back": lax.rem(back, chunk), "token": lax.rem(slot, n + 1),
+          "end": lax.select(held > 0, jnp.cumsum(held, 1) - 1,
+                            lax.full_like(held, chunk))}
+    return {name: ad_checkpoint.checkpoint_name(a, scopes.KEPT_ROUTING)
+            for name, a in ix.items()}
+
+
+def index_bytes(n: int, k: int, count: int) -> int:
+    """Bytes of `_indices`' arrays for ``n`` tokens, ``k`` choices and
+    ``count`` experts held, all int32 or float32: what a checkpointed
+    block keeps of an expert layer."""
+    rows = n * min(k, count)
+    return 4 * (2 * n * k + 2 * count + 2 * rows
+                + rows // chunk_rows(n, rows) * n)
+
+
+def _token_sums(rows, c, chunk: int, span: int, ix, scale=None):
+    """Chunk ``c``'s ``rows`` [chunk, d] (in sorted order), each token's
+    summed → [n, d] in ``rows``' dtype: exactly zero for a token none of
+    them is of.
+
+    The rows are taken into the chunk's token order (``ix["back"]``),
+    where a token's rows lie side by side, at most ``span`` of them, and
+    times ``scale`` [chunk] (in that order) where given. One pass over
+    the chunk then sums, in float32, each row with the up to ``span - 1``
+    rows before it that are of its token, so that the last of a token's
+    rows holds them all, and one gather of ``n`` rows brings those home
+    (``ix["end"]``; a token with none there reads a zero, selected after
+    the gather: a zero row padded on would copy the chunk)."""
+    lo = c * chunk
+    z = rows.at[lax.dynamic_slice_in_dim(ix["back"], lo, chunk)].get(
+        mode="promise_in_bounds")
+    token = lax.dynamic_slice_in_dim(ix["token"], lo, chunk)
+
+    def row(s):  # the rows ``s`` slots back, read as they are
+        z_s = z if s == 0 else _shifted(z, s, 0)
+        z_s = z_s.astype(jnp.float32)
+        if scale is None:
+            return z_s
+        return z_s * (scale if s == 0 else _shifted(scale, s, 0))[:, None]
+
+    total = row(0)
+    for s in range(1, span):
+        total = lax.select(lax.broadcast_in_dim(
+            token == _shifted(token, s, -1), z.shape, (0,)),
+            total + row(s), total)
+    end = lax.dynamic_index_in_dim(ix["end"], c, keepdims=False)
+    home = total.astype(rows.dtype).at[jnp.minimum(end, chunk - 1)].get(
+        mode="promise_in_bounds")
+    return lax.select(lax.broadcast_in_dim(end < chunk, home.shape, (0,)),
+                      home, lax.full_like(home, 0))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _sorted_side(chunk: int, activation: str, x, route_w, weights, ix):
+    """The expert layer on one chip's tokens, chunk by chunk: ``x``
+    [n, d] → [n, d] in ``x``'s dtype, row ``i`` the sum over token
+    ``i``'s pairs that a held expert takes of the pair's weight times
+    the expert applied to ``x[i]``.
+
+    ``route_w`` [n, k] float32, the weights by pair (`_indices` moved
+    them to ``ix["weight"]``, which the forward pass reads: this is
+    where their gradient goes); ``weights``: ``gate``, ``up``, ``down``
+    of the experts held; ``ix``: `_indices`'. Everything whose leading
+    dimension is the sorted position runs a chunk of ``chunk`` positions
+    at a time (`_in_chunks`): the gather of the tokens' rows, both
+    grouped matmuls and the activation, and the token side
+    (`_token_sums`: a token's rows weighted and summed in token order and
+    brought home by one gather of ``n`` rows), and in the backward pass
+    the gather of the cotangent to the rows, each row's weight gradient
+    and all their transposes.
+    Chunks after the first add into the result: nothing is sized for
+    the pairs.
 
     Its own VJP (`_sorted_side_fwd`, `_sorted_side_bwd`), so that a
-    chunk's gathers stay gathers in both directions and nothing of a
-    chunk's size leaves the loop but its rows of the one assembled
-    array."""
-    return _sorted_side_fwd(chunk, activation, x, weights, sizes, order,
-                            place)[0]
+    chunk's gathers stay gathers in both directions, what is kept for
+    the backward pass is the inputs and the indices (the backward loop
+    recomputes a chunk's hidden rows), and nothing of a chunk's size
+    leaves a loop."""
+    return _sorted_side_fwd(chunk, activation, x, route_w, weights, ix)[0]
 
 
 def _cast(weights, dtype):
@@ -211,64 +337,79 @@ def _cast(weights, dtype):
             weights["down"].astype(dtype))
 
 
-def _grown(rows, chunks: int):
-    """Chunk 0's rows at the head of the array the further chunks write
-    theirs into, zero where none does."""
-    return jnp.pad(rows, ((0, (chunks - 1) * rows.shape[0]), (0, 0)))
+def _span(pairs: int, n: int, weights) -> int:
+    """The most held rows one token can have: its chosen are distinct."""
+    return min(pairs // n, weights["gate"].shape[0])
 
 
-def _sorted_side_fwd(chunk, activation, x, weights, sizes, order, place):
-    chunks = order.shape[0] // chunk
+def _sorted_side_fwd(chunk, activation, x, route_w, weights, ix):
+    n, span = x.shape[0], _span(ix["order"].shape[0], x.shape[0], weights)
     gate_up, down = _cast(weights, x.dtype)
 
-    def on_chunk(c, out):
-        lo, _, xs, inside = _chunk(c, chunk, x, sizes, order)
-        rows = _experts_on_rows(xs, gate_up, down, inside, activation)
-        return (_grown(rows, chunks) if out is None
-                else lax.dynamic_update_slice_in_dim(out, rows, lo, 0))
+    def on_chunk(c, y):
+        lo, _, xs, inside = _chunk(c, chunk, x, ix)
+        # each row's routing weight, in the chunk's token order: applied in
+        # float32 to the expert's bf16 row, as the parent's weighted sum did
+        w = ix["weight"].at[lo + lax.dynamic_slice_in_dim(
+            ix["back"], lo, chunk)].get(mode="promise_in_bounds")
+        y_c = _token_sums(_experts_on_rows(xs, gate_up, down, inside,
+                                           activation), c, chunk, span, ix, w)
+        return y_c if y is None else y + y_c
 
-    out = _in_chunks(jnp.sum(sizes), chunk, chunks, on_chunk)
-    # the pair side, once a layer however many chunks ran. Rows past the
-    # last group are junk or, in a chunk that did not run, zero: only
-    # pairs without a held expert read them (clipped), and `_combine`
-    # selects those away; no pass over the buffer here
-    return (out.at[jnp.minimum(place, order.shape[0] - 1)].get(
-        mode="promise_in_bounds"), (x, weights, sizes, order, place))
+    y = _in_chunks(ix["ends"][-1], chunk, n * span // chunk, on_chunk)
+    return y, (x, route_w.shape, weights, ix)
 
 
 def _sorted_side_bwd(chunk, activation, res, g):
-    x, weights, sizes, order, place = res
-    n, chunks, live = x.shape[0], order.shape[0] // chunk, jnp.sum(sizes)
-    with jax.named_scope(scopes.MOE):  # as in `_take_rows_bwd`
+    x, shape, weights, ix = res
+    n, pairs, live = x.shape[0], ix["order"].shape[0], ix["ends"][-1]
+    span = _span(pairs, n, weights)
+    # a custom VJP's backward pass is traced outside the scope its call
+    # stood in: name it again, or a trace files it under nothing
+    with jax.named_scope(scopes.MOE):
         gate_up, down = _cast(weights, x.dtype)
 
         def on_chunk(c, carry):
-            lo, pairs, xs, inside = _chunk(c, chunk, x, sizes, order)
+            lo, at, xs, inside = _chunk(c, chunk, x, ix)
             # recomputed here from `x` and the indices, not kept
-            _, back = jax.vjp(functools.partial(
-                _experts_on_rows, sizes=inside, activation=activation),
-                xs, gate_up, down)
-            # positions past `live` are in no expert's group: what their
-            # pairs' cotangent holds reaches no weight's gradient, and
-            # their rows of `d_xs` are selected away below
-            d_xs, *d_w = back(g.at[pairs].get(mode="promise_in_bounds"))
-            d_w = [d.astype(jnp.float32) for d in d_w]
+            out, back = jax.vjp(functools.partial(
+                _experts_on_rows, sizes=inside, activation=activation), xs,
+                gate_up, down)
+            dy = g.at[lax.rem(at, n)].get(mode="promise_in_bounds").astype(
+                jnp.float32)
+            # the weight's gradient a dot product of the expert's row and
+            # the cotangent, d wide in float32: taken from the cotangent of
+            # the hidden row instead (f wide) it reads that row rounded to
+            # bf16 by the grouped matmul, and the router's gradient, a
+            # difference of these, read 2-3 times worse on the chip (PR 37).
+            # Positions past `live` are in no expert's group: what they hold
+            # reaches no weight's gradient, no token's sum (their slots are
+            # the token ``n``'s) and no routing weight's
+            d_w = jnp.sum(out.astype(jnp.float32) * dy, axis=-1)
+            d_xs, *d_p = back((lax.dynamic_slice_in_dim(
+                ix["weight"], lo, chunk)[:, None] * dy).astype(x.dtype))
+            d_x = _token_sums(d_xs, c, chunk, span, ix)
+            d_p = [d.astype(jnp.float32) for d in d_p]
             if carry is None:
-                return _grown(d_xs, chunks), d_w
-            return (lax.dynamic_update_slice_in_dim(carry[0], d_xs, lo, 0),
-                    [a + d for a, d in zip(carry[1], d_w)])
+                return d_x, lax.pad(d_w, np.array(0, d_w.dtype),
+                                    [(0, pairs - chunk, 0)]), d_p
+            return (carry[0] + d_x,
+                    lax.dynamic_update_slice_in_dim(carry[1], d_w, lo, 0),
+                    [a + d for a, d in zip(carry[2], d_p)])
 
-        d_rows, (d_gate_up, d_down) = _in_chunks(live, chunk, chunks,
-                                                 on_chunk)
+        d_x, d_w, (d_gate_up, d_down) = _in_chunks(live, chunk,
+                                                   n * span // chunk, on_chunk)
         width = weights["gate"].shape[-1]
         d_weights = {"gate": d_gate_up[..., :width],
                      "up": d_gate_up[..., width:], "down": d_down}
-        # the token side, once a layer: row i of `x` was read at the
-        # sorted positions of the pairs j*n + i that have a held expert
-        d_x = _rows_back(d_rows, n, jnp.minimum(place, order.shape[0] - 1),
-                         place < live)
-        return (d_x, jax.tree.map(lambda d, w: d.astype(w.dtype), d_weights,
-                                  weights), None, None, None)
+        # the routing weights' gradient back in pair order: one sort
+        d_w = lax.select(lax.iota(jnp.int32, pairs) < live, d_w,
+                         lax.full_like(d_w, 0))
+        d_route = _as(lax.sort((ix["order"], _as(d_w, jnp.int32)),
+                               **_UNSTABLE)[1], jnp.float32)
+        return (d_x, d_route.reshape(shape[::-1]).T,
+                jax.tree.map(lambda d, w: d.astype(w.dtype), d_weights,
+                             weights), None)
 
 
 _sorted_side.defvjp(_sorted_side_fwd, _sorted_side_bwd)
@@ -284,25 +425,20 @@ def chunk_rows(n: int, rows: int) -> int:
     return n if rows % n == 0 else rows
 
 
-def _experts_on_pairs(x, key, params, rows: int, activation: str = "relu"):
-    """Each pair's expert applied to its row: ``x`` [n, d]; pair ``p``
-    takes row ``p % n`` to local expert ``key[p]`` (``count`` = none
-    held here). → [pairs, d], junk where ``key == count``. ``rows`` is
-    the bound on the pairs with a held expert: the buffers' size.
-
-    The sorted positions are walked in chunks of ``n`` rows, ``x``'s
-    own count (`chunk_rows`, `_sorted_side`). Every index comes of the
-    two sorts and of arithmetic on them: an integer gather of ``pairs``
-    elements costs a TPU as much as a sixth of a row gather."""
+def _experts_on_tokens(x, key, route_w, params, activation: str = "relu"):
+    """``x`` [n, d], ``key`` [n * k] (pair ``j*n + i`` → local expert,
+    ``count`` = none held here), ``route_w`` [n, k] → [n, d]: each
+    token's held pairs' weighted expert outputs, summed (`_sorted_side`),
+    in chunks of ``n`` sorted positions (`chunk_rows`) up to the bound
+    ``n * min(k, count)``."""
+    n, k = route_w.shape
     count = params["gate"].shape[0]
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)  # held first
-    place = jnp.argsort(order).astype(jnp.int32)   # pair -> sorted position
-    group_sizes = jnp.sum(
-        key[:, None] == jnp.arange(count, dtype=key.dtype)[None],
-        axis=0, dtype=jnp.int32)
+    bound = n * min(k, count)
+    chunk = chunk_rows(n, bound)
+    route_w = route_w.astype(jnp.float32)
     weights = {name: params[name] for name in ("gate", "up", "down")}
-    return _sorted_side(chunk_rows(x.shape[0], rows), activation, x, weights,
-                        group_sizes, order[:rows], place)
+    return _sorted_side(chunk, activation, x, route_w, weights,
+                        _indices(chunk, bound, count, key, route_w))
 
 
 def _pair_ids(t: int, k: int):
@@ -325,7 +461,8 @@ def _choice(out_pairs, t: int, j: int):
 @jax.custom_vjp
 def _combine(out_pairs, weights, mask):
     """``sum_j weights[tok, j] * out_pairs[j*t + tok]`` over the pairs
-    ``mask`` keeps, accumulated in float32 → [t, d] float32. A masked
+    ``mask`` keeps, accumulated in float32 → [t, d] float32 (the `'ep'`
+    exchange's weighted sum; one chip sums in `_token_sums`). A masked
     pair's row is junk: selected away, not multiplied by zero. Its own
     VJP, so that what is kept for the backward pass is ``out_pairs`` as
     it came and not a float32 copy of a buffer sized for a bound."""
@@ -375,8 +512,14 @@ def expert_layer(u, chosen, weights, expert_params, held=None, *,
       activation: ``act``: ``"relu"`` (ReGLU experts) or ``"silu"``.
 
     Returns ``sum over the chosen e that are held of w_e * E_e(u)``,
-    [t, d] in ``u``'s dtype. The weights stay normalised over all the
-    chosen, held or not.
+    [t, d] in ``u``'s dtype, exactly zero for a token that chose none of
+    them. The weights stay normalised over all the chosen, held or not.
+    On one chip the pairs are sorted by expert and walked in chunks of
+    ``t`` sorted rows (`_sorted_side`); each token's rows are summed in
+    token order and brought home by one gather of ``t`` rows
+    (`_token_sums`), and no array of the pairs' rows is made in either
+    direction. Under ``axis_name`` each received row is a token of one
+    choice of weight 1 to the same walk, and `_combine` weighs them.
     """
     count = expert_params["gate"].shape[0]
     t, _ = u.shape
@@ -393,11 +536,9 @@ def expert_layer(u, chosen, weights, expert_params, held=None, *,
         raise ValueError(f"held={held} but the parameters are of {count} "
                          "experts")
     local = chosen - first
-    is_held = (local >= 0) & (local < count)
-    key = jnp.where(is_held, local, count).T.reshape(-1)
-    out_pairs = _experts_on_pairs(u, key, expert_params, t * per_token,
-                                  activation)
-    return _combine(out_pairs, weights, is_held).astype(u.dtype)
+    key = jnp.where((local >= 0) & (local < count), local, count)
+    return _experts_on_tokens(u, key.T.reshape(-1), weights, expert_params,
+                              activation)
 
 
 def _exchanged(u, chosen, weights, params, axis_name, bound: int,
@@ -427,8 +568,10 @@ def _exchanged(u, chosen, weights, params, axis_name, bound: int,
     recv_x = lax.all_to_all(send_x.reshape(n, bound, d), axis_name, 0, 0)
     recv_key = lax.all_to_all(send_key.astype(jnp.int32), axis_name, 0, 0)
     rows = n * bound
-    out = _experts_on_pairs(recv_x.reshape(rows, d), recv_key.reshape(-1),
-                            params, rows, activation)
+    # each received row a token of one choice, of weight 1
+    out = _experts_on_tokens(recv_x.reshape(rows, d), recv_key.reshape(-1),
+                             jnp.ones((rows, 1), jnp.float32), params,
+                             activation)
     back = lax.all_to_all(out.reshape(n, bound, d), axis_name, 0, 0)
     out_pairs = _take_rows(back.reshape(rows, d), slot,
                            sent.reshape(-1, 1), filled.reshape(-1, 1))

@@ -49,7 +49,8 @@ fused attention's backward kernels read (``hvd.attention/q|k|v|o|lse``,
 named in ``ops/pallas/flash_attention.py``'s forward rule), and
 `KEPT_BY_REMAT_LATENT`, those and a latent head's rotary parts
 (``hvd.attention/q_rope``, ``/k_rope``: `latent_attention`'s residuals);
-`KEPT_CHOICE`, the choice of a router on the expert layer's normed input.
+`KEPT_CHOICE`, the choice of an expert layer's router, and `KEPT_ROUTING`,
+what the expert layer sorted out of it.
 A decoder block under ``remat`` keeps these and recomputes the rest.
 
 Counters (`StepRecord.counters`, noted once while a step is traced, so
@@ -114,11 +115,16 @@ KEPT_BY_REMAT = tuple("hvd.attention/" + a
 KEPT_BY_REMAT_LATENT = tuple("hvd.attention/" + a for a in (
     "q", "q_rope", "k", "k_rope", "v", "o", "lse"))
 
-#: the experts a router chose that reads its expert layer's normed input
-#: (``models/transformer.py _route``, either scoring), [tokens, k] int32 a
-#: layer: kept by a checkpointed decoder block, whose backward pass
-#: recomputes that input, not bit for bit
+#: the experts an expert layer's router chose (``models/transformer.py
+#: _route``, either scoring, either input), [tokens, k] int32 a layer:
+#: kept by a checkpointed decoder block, whose backward pass has to route
+#: as the forward pass did (the expert layer keeps `KEPT_ROUTING`)
 KEPT_CHOICE = "hvd.router/chosen"
+#: what an expert layer reads off its routing (``parallel/moe.py
+#: _indices``: its sorts' results, int32 and float32 vectors of the
+#: pairs' and the bound's size): kept by a checkpointed decoder block, so
+#: that its backward pass does not sort again
+KEPT_ROUTING = "hvd.moe/routing"
 
 #: in `phase_of`'s order of precedence
 PHASES = ("grad_exchange", "optimizer", "recompute", "backward", "forward",

@@ -187,15 +187,15 @@ def test_sigmoid_route_chooses_by_the_biased_score_and_weighs_by_the_plain():
 
 @pytest.mark.parametrize("scoring", ["softmax", "sigmoid"])
 @pytest.mark.parametrize("router_input", ["normed", "block"])
-def test_a_checkpointed_block_keeps_the_choice_of_a_router_on_the_normed_input(
+def test_a_checkpointed_block_keeps_its_routers_choice(
         scoring, router_input, capsys):
-    """A router that reads the expert layer's normed input reads what a
-    checkpointed block recomputes: the block keeps its choice, [tokens,
-    k] int32 an expert layer, under either scoring, and takes the
-    weights at the kept choice (the gradients are those of the block
-    without remat). A router on the block's input, which the checkpoint
-    keeps as it was, names nothing. `route` itself: a named choice
-    changes neither the choice nor the weights."""
+    """The expert layer keeps what it sorted out of the forward pass's
+    routing, so a checkpointed block keeps its router's choice, [tokens,
+    k] int32 an expert layer, whether the router reads the expert
+    layer's normed input or the block's own, under either scoring, and
+    takes the weights at the kept choice (the gradients are those of the
+    block without remat). `route` itself: a named choice changes neither
+    the choice nor the weights."""
     cfg = dataclasses.replace(toy(remat=True), router_scoring=scoring,
                               router_input=router_input)
     params = seeded_params(cfg)
@@ -205,11 +205,14 @@ def test_a_checkpointed_block_keeps_the_choice_of_a_router_on_the_normed_input(
         return T.lm_loss(p, tokens, cfg, use_constraints=False)
 
     jax.ad_checkpoint.print_saved_residuals(loss, params)
+    # what `route` hands the backward pass: the index its weights were
+    # read at the named choice (the expert layer keeps what it sorted out
+    # of the routing, `scopes.KEPT_ROUTING`, and reads the choice no
+    # more), one [tokens, k] int32 a layer
     kept = [line.split()[0] for line in capsys.readouterr().out.splitlines()
-            if scopes.KEPT_CHOICE in line]
-    normed = router_input == "normed"
-    assert kept == normed * 2 * ["i32[48,2]"]
-    assert T._kept_bytes((2, 24), cfg, False, normed) == normed * 2 * 24 * 2 * 4
+            if "(route)" in line]
+    assert kept == 2 * ["i32[48,2]"]
+    assert T._kept_bytes((2, 24), cfg, False, True) == 2 * 24 * 2 * 4
     plain = jax.grad(lambda p: T.lm_loss(
         p, tokens, dataclasses.replace(cfg, remat=False),
         use_constraints=False))(params)
@@ -346,8 +349,15 @@ def test_remat_keeps_what_the_latent_kernels_read(on_the_kernels):
     # and the expert layer's router, on the normed input, its choice
     choice = 2 * 256 * 2 * 4
     assert T._kept_bytes((2, 256), cfg, True, True) == a_block + choice
+    # and what the expert layer sorted out of its routing
+    indices = sum(a.size * a.dtype.itemsize for a in jax.eval_shape(
+        lambda key, w: moe._indices(512, 1024, 4, key, w),
+        jax.ShapeDtypeStruct((1024,), jnp.int32),
+        jax.ShapeDtypeStruct((512, 2), jnp.float32)).values())
+    assert T._kept_bytes((2, 256), cfg, True, True, True) == \
+        a_block + choice + indices
     assert counters["remat_kept_mb"] == pytest.approx(
-        (2 * a_block + choice) / 1e6)
+        (2 * a_block + choice + indices) / 1e6)
 
 
 def test_the_first_decoders_are_settings_of_the_same_block():

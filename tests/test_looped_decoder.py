@@ -129,7 +129,8 @@ def test_a_shared_leafs_gradient_is_the_sum_over_its_uses():
 
 #: the three older decoders at toy sizes: sha256 (16 hex digits) of the
 #: seeded weights' bytes and of the traced loss-and-gradient program's
-#: text, both taken on the parent of PR 35 (commit c01d7b2). A change to
+#: text, both taken on the parent of PR 35 (commit c01d7b2), the two
+#: expert decoders' programs anew in PR 37 (its expert layer). A change to
 #: `init`'s keys or to what an older configuration traces shows here; a
 #: PR that means to change either takes the pins anew.
 OLDER = {
@@ -142,7 +143,7 @@ OLDER = {
                     window_layout=(0, 1, 1, 1), n_experts=8,
                     experts_per_token=2, d_expert=16, experts_held=(2, 4),
                     tie_embeddings=False),
-               "e39f63738102cbe0", "def4d565a8cc24cb"),
+               "e39f63738102cbe0", "0be0a84cb0a173c3"),
     "latent": (dict(vocab_size=64, d_model=32, n_heads=4, d_head=8,
                     n_layers=3, d_ff=48, max_seq=16, remat=True,
                     positions="layout", tie_embeddings=False, kv_latent=16,
@@ -151,7 +152,7 @@ OLDER = {
                     n_shared_experts=1, router_scoring="sigmoid",
                     router_input="normed", routed_scale=2.5,
                     expert_activation="silu"),
-               "4a94bc3337cf63cb", "f0035467ead84a75"),
+               "4a94bc3337cf63cb", "e969273b6e53ed27"),
 }
 
 

@@ -200,23 +200,93 @@ def test_chunks_follow_the_rows_routed(case, monkeypatch):
                                    rtol=2e-6)
 
 
-def test_a_cotangent_at_a_pair_without_a_held_expert_reaches_nothing():
-    """A pair no held expert takes comes out as junk, which a caller
-    selects away; whatever cotangent it hands back there is in no
-    expert's group and moves no gradient, masked or not."""
+def test_a_cotangent_at_a_token_without_a_held_expert_reaches_nothing():
+    """A token none of whose pairs a held expert takes comes out zero;
+    whatever cotangent it is handed there reaches no gradient (not of
+    the rows, the routing weights or the experts), masked or not: its
+    rows of the cotangent are read only at sorted positions past the
+    rows routed, which are in no expert's group and no token's sum."""
     t, k, count = 32, 4, 4
     u, _, params = layer_inputs(t=t, experts=count, seed=5)
     rng = np.random.RandomState(6)
-    key = jnp.asarray(rng.randint(0, 2 * count, t * k).clip(0, count),
-                      jnp.int32)                      # count: none held
-    out, back = jax.vjp(
-        lambda u, p: moe._experts_on_pairs(u, key, p, t * k), u, params)
+    key = rng.randint(0, 2 * count, (t, k)).clip(0, count)
+    key[: t // 4] = count                              # none held
+    w = jnp.asarray(rng.rand(t, k), jnp.float32)
+    out, back = jax.vjp(lambda u, w, p: moe._experts_on_tokens(
+        u, jnp.asarray(key.T.reshape(-1), jnp.int32), w, p), u, w, params)
     g = jnp.asarray(rng.randn(*out.shape), jnp.float32)
-    held = (key < count)[:, None]
-    assert 0 < int(held.sum()) < t * k
+    held = (key < count).any(1)[:, None]
+    assert 0 < int(held.sum()) < t
+    assert not np.asarray(out)[~held[:, 0]].any()
     for a, b in zip(jax.tree.leaves(back(g)),
                     jax.tree.leaves(back(jnp.where(held, g, 0.0)))):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_a_token_without_a_held_expert_is_exactly_zero():
+    """A quarter of the tokens choose only experts held elsewhere: their
+    rows of the layer, of ``d_u`` and of the routing weights' gradient
+    are exactly zero, and every other row equals the reference's."""
+    t, k, first, count = 32, 4, 4, 4
+    u, _, params = layer_inputs(t=t, experts=8, seed=7)
+    held = jax.tree.map(lambda x: x[first:first + count], params)
+    rng = np.random.RandomState(8)
+    chosen = np.stack([rng.permutation(8)[:k] for _ in range(t)])
+    chosen[: t // 4] = rng.permutation(4)              # experts 0..3 alone
+    chosen = jnp.asarray(chosen, jnp.int32)
+    w = jnp.asarray(rng.rand(t, k), jnp.float32)
+    none = np.arange(t) < t // 4
+
+    def ours(u, w):
+        return moe.expert_layer(u, chosen, w, held, (first, count))
+
+    def ref(u, w):
+        dense = jnp.zeros((t, 8)).at[jnp.arange(t)[:, None], chosen].set(w)
+        return reference.experts(u, held, dense, first)
+
+    cot = jnp.asarray(rng.randn(t, 32), jnp.float32)
+    got, (d_u, d_w) = jax.jit(lambda u, w: (ours(u, w), jax.grad(
+        lambda u, w: jnp.sum(ours(u, w) * cot), (0, 1))(u, w)))(u, w)
+    want, (r_u, r_w) = jax.jit(lambda u, w: (ref(u, w), jax.grad(
+        lambda u, w: jnp.sum(ref(u, w) * cot), (0, 1))(u, w)))(u, w)
+    for a in (got, d_u, d_w):
+        assert not np.asarray(a)[none].any()
+        assert np.asarray(a)[~none].any()
+    for a, b in ((got, want), (d_u, r_u), (d_w, r_w)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4,
+                                   rtol=1e-4)
+
+
+#: the parent's count (PR 35's tree) of the equations in the gradient of
+#: `expert_layer` and everything nested in it, at t=256, d=64, f=32, 8 of
+#: 64 experts held, k=6: what tracing and lowering a layer cost grows
+#: with it, once a layer, direction and program (PERF.md §6, PR 37)
+PARENT_EQUATIONS = 602
+
+
+def test_the_layer_traces_no_more_than_its_parent():
+    """The set-up guard: the expert layer's gradient jaxpr, nested bodies
+    (the chunk loops, the custom rules, jnp's own jits) included, counts
+    no more equations than the parent's at the same shape."""
+    from jax.extend import core
+
+    def count(jaxpr):
+        n = len(jaxpr.eqns)
+        for eqn in jaxpr.eqns:
+            for v in eqn.params.values():
+                for j in v if isinstance(v, (list, tuple)) else [v]:
+                    if isinstance(j, core.ClosedJaxpr):
+                        n += count(j.jaxpr)
+                    elif isinstance(j, core.Jaxpr):
+                        n += count(j)
+        return n
+
+    u, router, params = layer_inputs(t=256, d=64, f=32, experts=64)
+    held = jax.tree.map(lambda x: x[:8], params)
+    chosen, w = moe.route(u @ router, 6)
+    grad = jax.make_jaxpr(jax.grad(lambda u, w, p: jnp.sum(moe.expert_layer(
+        u, chosen, w, p, (0, 8)) ** 2), (0, 1, 2)))(u, w, held)
+    assert count(grad.jaxpr) <= PARENT_EQUATIONS
 
 
 def test_expert_parallel_exchange_equals_the_one_chip_layer():

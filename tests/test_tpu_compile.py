@@ -160,7 +160,8 @@ def test_chunked_expert_layer_compiles_at_the_sparse_cell_sizes(
     compiler keeps one ``while`` a direction for the chunks after the
     first (and no ``conditional``: nothing unrolled), adds no ``scatter``
     (both directions of every row movement are gathers) and leaves no
-    hidden activation at the bound's size, [98304, 1536]."""
+    hidden activation at the bound's size, [98304, 1536], and since PR 37
+    no rows of the pairs, [98304, 2560], in either direction."""
     import re
 
     from horovod_tpu.parallel import moe
@@ -190,6 +191,7 @@ def test_chunked_expert_layer_compiles_at_the_sparse_cell_sizes(
     assert not re.search(r"\sscatter\(", text)
     assert "ragged-dot" in text and f"[{t},{2 * f}]" in text
     assert f"[{t * k},{2 * f}]" not in text and f"[{t * k},{f}]" not in text
+    assert f"[{t * k},{d}]" not in text
 
 
 def test_dense_kernels_trace_as_before_the_window_and_the_groups(topo):
