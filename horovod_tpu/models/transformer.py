@@ -442,6 +442,8 @@ def _forward(params, tokens, cfg: TransformerConfig, *,
                          "windowed layer runs the default attention")
     index_rotary = (_rope_tables(positions, cfg.index_rope, cfg.rope_theta)
                     if cfg.sparse_topk and cfg.index_rope else None)
+    index_blocks = (_index_kernel_blocks(cfg, tokens.shape[1], blocks)
+                    if cfg.sparse_topk else None)
 
     def _attend(x, blk, window, rope):
         """x + the block's attention over heads of one width."""
@@ -479,7 +481,7 @@ def _forward(params, tokens, cfg: TransformerConfig, *,
         if cfg.kv_latent:
             x = _latent_attention(x, blk, cfg, blocks, rotary, attn_fn)
         elif cfg.sparse_topk:
-            x, indexed = _sparse_attention(x, blk, cfg, blocks,
+            x, indexed = _sparse_attention(x, blk, cfg, blocks, index_blocks,
                                            rotary if rope else None,
                                            index_rotary)
             indexed = (indexed,)
@@ -532,7 +534,8 @@ def _forward(params, tokens, cfg: TransformerConfig, *,
                 scopes.note_attention(
                     kernel=blocks is not None, window=kind[0] is not None,
                     kept=keeps, latent=bool(cfg.kv_latent),
-                    sparse=bool(cfg.sparse_topk))
+                    sparse=bool(cfg.sparse_topk),
+                    indexer_kernel=index_blocks is not None)
             kept = _kept_bytes(tokens.shape, cfg, keeps,
                                cfg.remat and "experts" in blk,
                                cfg.remat and "experts" in blk)
@@ -907,8 +910,8 @@ def _head_norm(x, scale, head_dim: int):
 
 # -- learned sparse attention ------------------------------------------------
 
-def _sparse_attention(x, blk, cfg: TransformerConfig, blocks, rotary,
-                      index_rotary):
+def _sparse_attention(x, blk, cfg: TransformerConfig, blocks, index_blocks,
+                      rotary, index_rotary):
     """``x`` + its learned sparse attention, and the indexer's ``(KL
     term, selection packed by query)``. The main heads' projections, the
     kernels (``hvd_dsa_*``, where ``blocks`` says the fused kernels are
@@ -916,11 +919,15 @@ def _sparse_attention(x, blk, cfg: TransformerConfig, blocks, rotary,
     under ``hvd.model/attention``; the indexer, from its projections to
     its KL term, under ``hvd.model/indexer`` (side by side, as the latent
     part is). The indexer reads the normed input and the main heads'
-    queries and keys detached: what it does moves no other leaf."""
+    queries and keys detached: what it does moves no other leaf. The
+    indexer's scores and their gradient are kernels too where
+    ``index_blocks`` (`_index_kernel_blocks`) is given
+    (``hvd_dsa_index_*``; else `_index_scores`)."""
     with jax.named_scope(scopes.ATTENTION):
         h = _rmsnorm(x, blk["ln1"]["scale"])
         q, k, v = _heads_side_by_side(h, blk, cfg, rotary)
-    spec = (cfg.sparse_topk, cfg.index_chunk, cfg.n_heads, blocks)
+    spec = (cfg.sparse_topk, cfg.index_chunk, cfg.n_heads, blocks,
+            index_blocks)
     with jax.named_scope(scopes.INDEXER):
         iq, ik, iw = _index_inputs(h, blk["indexer"], cfg, index_rotary)
         selected = _index_select(iq, ik, iw, spec)
@@ -978,12 +985,38 @@ def _index_inputs(h, idx, cfg: TransformerConfig, tables):
 def _index_scores(iq, iw, ik):
     """``I(t, s) = sum_j w_j(t) heads**-1/2 relu(q_j(t) . k(s)) dim**-1/2``,
     [b, c, s] float32, of queries ``iq`` [b, c, heads, dim] with weights
-    ``iw`` [b, c, heads] against keys ``ik`` [b, s, dim]."""
+    ``iw`` [b, c, heads] against keys ``ik`` [b, s, dim]: the einsum
+    path's, and what the kernels' (``flash_attention.index_scores``)
+    are held to."""
     heads, dim = iq.shape[-2:]
     dots = jnp.einsum("bchk,bsk->bchs", iq, ik,
                       preferred_element_type=jnp.float32)
     return jnp.einsum("bchs,bch->bcs", jax.nn.relu(dots),
                       iw) * (heads * dim) ** -0.5
+
+
+def _index_kernel_blocks(cfg: TransformerConfig, s: int, blocks):
+    """``(group, block_k)`` of the indexer's kernels over ``s`` positions
+    (``flash_attention.index_blocks``), or None: they are taken where the
+    attention kernels are (``blocks``) and tile the indexer's chunks."""
+    if blocks is None:
+        return None
+    from ..ops.pallas.flash_attention import index_blocks
+
+    return index_blocks(_chunks(s, cfg.index_chunk), s, cfg.index_heads,
+                        cfg.index_dim)
+
+
+def _chunk_scores(iq, iw, ik, first, kernel):
+    """The scores of the chunk of queries from ``first`` (`_index_scores`'
+    arguments), through the kernels where ``kernel`` (`_index_kernel_blocks`)
+    is given: one function for the selection and the KL term, so both see
+    the same scores."""
+    if kernel is None:
+        return _index_scores(iq, iw, ik)
+    from ..ops.pallas.flash_attention import index_scores
+
+    return index_scores(iq, iw, ik, first, *kernel)
 
 
 def _select(scores, first, topk: int):
@@ -1068,14 +1101,15 @@ def _index_select(iq, ik, iw, spec):
     from ..ops import selection
 
     iq, ik, iw = map(jax.lax.stop_gradient, (iq, ik, iw))
-    topk, chunk, _, blocks = spec
+    topk, chunk, _, blocks, kernel = spec
     b, s = iq.shape[:2]
     c = _chunks(s, chunk)
 
     def one(_, first):
         iq_c, iw_c = (jax.lax.dynamic_slice_in_dim(a, first, c, axis=1)
                       for a in (iq, iw))
-        keep = _select(_index_scores(iq_c, iw_c, ik), first, topk)
+        keep = _select(_chunk_scores(iq_c, iw_c, ik, first, kernel), first,
+                       topk)
         out = [selection.pack(keep)]
         if blocks is not None:
             out.append(selection.pack(jnp.swapaxes(keep, 1, 2)))
@@ -1100,7 +1134,7 @@ def _kl_pass(iq, ik, iw, q, k, lse, selected, spec, grads: bool):
     or None)."""
     from ..ops import selection
 
-    topk, chunk, heads, blocks = spec
+    topk, chunk, heads, blocks, kernel = spec
     b, s = iq.shape[:2]
     c = _chunks(s, chunk)
     by_query = selected[0]
@@ -1112,10 +1146,12 @@ def _kl_pass(iq, ik, iw, q, k, lse, selected, spec, grads: bool):
         words = jax.lax.dynamic_slice_in_dim(
             by_query, first // selection.WORD, c // selection.WORD, axis=1)
         keep = selection.unpack(words)
+        scores_of = functools.partial(_chunk_scores, first=first,
+                                      kernel=kernel)
         if grads:
-            scores, back = jax.vjp(_index_scores, iq_c, iw_c, ik)
+            scores, back = jax.vjp(scores_of, iq_c, iw_c, ik)
         else:
-            scores = _index_scores(iq_c, iw_c, ik)
+            scores = scores_of(iq_c, iw_c, ik)
         if blocks is not None:
             from ..ops.pallas.flash_attention import sparse_target
 
@@ -1153,7 +1189,7 @@ def _indexer(iq, ik, iw, q, k, lse, selected, spec):
     s, heads*hd], keys ``k`` [b, s, kv*hd] and log-sum-exps ``lse``
     [b*heads, 1, s] (a constant), ``selected`` `_index_select`'s
     (``spec``: the keys a query selects, the queries a chunk, the main
-    heads, the kernels' blocks or None).
+    heads, the kernels' blocks or None, the indexer kernels' or None).
 
     A rule of its own: the forward pass takes the term's gradients where
     it has the scores (one pass over them, not two), and the backward

@@ -120,6 +120,7 @@ BLOCKS = (1024, 512, 256)
 
 _NT = (((1,), (1,)), ((), ()))   # a @ b.T
 _NN = (((1,), (0,)), ((), ()))   # a @ b
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
 
 # The kernel bodies below are written with ``lax`` functions, not with
 # operators or ``jnp``: on a tracer ``a * b`` and ``jnp.where`` each go
@@ -1390,3 +1391,274 @@ def sparse_target(q, k, lse, by_query, first, block_q: int, block_k: int,
         name=_dsa_name("hvd_dsa_target", topk),
     )(tiles_of(by_query, bq, bk), jnp.reshape(first, (1,)).astype(jnp.int32),
       q, k, lse, by_query)
+
+
+# -- the learned-sparse indexer's scores, its heads summed in the tile -------
+
+#: the key blocks `index_blocks` tries, largest first
+INDEX_BLOCKS = (512, 256, 128)
+#: the largest chunk of queries the indexer kernels hold as one block
+INDEX_MAX_CHUNK = 1024
+
+#: the indexer kernels' grid is (batch, key block); the backward kernel
+#: accumulates the query side over the key blocks
+_INDEX_GRID = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"))
+
+
+def index_blocks(c: int, s: int, heads: int, dim: int):
+    """``(group, block_k)`` for the indexer kernels over chunks of ``c``
+    queries and ``s`` keys, ``heads`` heads ``dim`` wide each, or None
+    where they cannot take that. A chunk is one query block, of at most
+    `INDEX_MAX_CHUNK` queries. ``group`` heads share a lane tile of the
+    queries (their ``dim`` columns side by side, as the projection wrote
+    them): a ``dim`` that divides `LANES`, whose groups the heads fill.
+    ``block_k``: the largest of `INDEX_BLOCKS` that tiles ``s``."""
+    if LANES % dim:
+        return None
+    group = min(heads, LANES // dim)
+    block_k = next((bk for bk in INDEX_BLOCKS if s % bk == 0), None)
+    if heads % group or c > INDEX_MAX_CHUNK or block_k is None:
+        return None
+    return group, block_k
+
+
+def _index_operands():
+    """The dtype the indexer kernels hand the MXU: bf16 on the TPU, as
+    XLA's default precision rounds the float32 einsum they replace
+    (``models/transformer.py`` ``_index_scores``), and float32 in
+    interpret mode, as the CPU computes that einsum."""
+    return jnp.float32 if _interpret() else jnp.bfloat16
+
+
+def _placed(k, group: int, dim: int):
+    """A key tile [bk, dim] as ``group`` tiles [bk, group*dim], the
+    ``r``-th holding it in head ``r``'s lanes and zeros in the others:
+    its product with a group's queries [c, group*dim] is head ``r``'s
+    alone, and no lane is sliced. Placed by the MXU (a product with a
+    0/1 matrix, exact)."""
+    if group == 1:
+        return [k]
+    shape = (dim, group * dim)
+    rows = lax.broadcasted_iota(jnp.int32, shape, 0)
+    cols = lax.broadcasted_iota(jnp.int32, shape, 1)
+    return [lax.convert_element_type(_dot(k, lax.convert_element_type(
+        lax.eq(cols, lax.add(rows, r * dim)), k.dtype), _NN), k.dtype)
+        for r in range(group)]
+
+
+def _past_the_chunk(first, c: int, j, block_k: int):
+    """Does key tile ``j`` lie wholly after the last query of the chunk of
+    ``c`` queries from ``first``? Such a tile is neither fetched nor
+    computed."""
+    return lax.gt(lax.mul(j, block_k), lax.add(first, c - 1))
+
+
+def _chunk_causal(x, first, j, block_k: int):
+    """The tile ``x`` [c, bk] of key block ``j`` with 0 wherever the key
+    comes after the query (query ``i`` of the chunk is ``first + i``)."""
+    rows = lax.add(first, lax.broadcasted_iota(jnp.int32, x.shape, 0))
+    cols = lax.add(lax.mul(j, block_k),
+                   lax.broadcasted_iota(jnp.int32, x.shape, 1))
+    return lax.select(lax.ge(rows, cols), x, lax.full_like(x, 0))
+
+
+def _group_columns(p, width: int):
+    """Head group ``p``'s lanes of the queries [c, heads*dim]."""
+    return pl.ds(pl.multiple_of(lax.mul(p, width), width), width)
+
+
+def _index_fwd_kernel(first_ref, q_ref, w_ref, k_ref, i_ref, *, heads: int,
+                      group: int, dim: int, scale: float, block_k: int):
+    j, first = pl.program_id(1), first_ref[0]
+    width = group * dim
+    past = _past_the_chunk(first, q_ref.shape[1], j, block_k)
+
+    @pl.when(lax.bitwise_not(past))
+    def _tile():
+        placed = _placed(lax.convert_element_type(k_ref[0], q_ref.dtype),
+                         group, dim)
+        w = w_ref[0]                                       # [c, heads]
+        lane = lax.broadcasted_iota(jnp.int32, w.shape, 1)
+        i_ref[...] = _zeros(i_ref)
+
+        def one_group(p, carry):   # the group's heads, summed in the tile
+            q = q_ref[0, :, _group_columns(p, width)]
+            total = i_ref[0]
+            for r in range(group):
+                weight = _row_sum(lax.select(
+                    lax.eq(lane, lax.add(lax.mul(p, group), r)), w,
+                    lax.full_like(w, 0)))                  # [c, 1]
+                total = lax.add(total, lax.mul(
+                    lax.max(_dot(q, placed[r], _NT), 0.0), weight))
+            i_ref[0] = total
+            return carry
+
+        lax.fori_loop(0, heads // group, one_group, 0)
+        i_ref[0] = _chunk_causal(lax.mul(i_ref[0], scale), first, j, block_k)
+
+    @pl.when(past)
+    def _past():
+        i_ref[...] = _zeros(i_ref)
+
+
+def _index_bwd_kernel(first_ref, q_ref, wq_ref, k_ref, g_ref, u_ref, dk_ref,
+                      *, heads: int, group: int, dim: int, block_k: int):
+    j, first = pl.program_id(1), first_ref[0]
+    width = group * dim
+    past = _past_the_chunk(first, q_ref.shape[1], j, block_k)
+
+    @pl.when(lax.eq(j, 0))
+    def _init():
+        u_ref[...] = _zeros(u_ref)
+
+    @pl.when(lax.bitwise_not(past))
+    def _tile():
+        placed = _placed(lax.convert_element_type(k_ref[0], q_ref.dtype),
+                         group, dim)
+        g = _chunk_causal(g_ref[0], first, j, block_k)     # [c, bk]
+        lane_head = lax.div(lax.broadcasted_iota(
+            jnp.int32, (g.shape[0], width), 1), dim)
+        dk_ref[...] = _zeros(dk_ref)
+
+        def one_group(p, carry):
+            cols = _group_columns(p, width)
+            q, wq = q_ref[0, :, cols], wq_ref[0, :, cols]
+            u, dk = u_ref[0, :, cols], dk_ref[0]
+            for r in range(group):
+                # the cotangent where head r's product is positive, as
+                # the MXU takes it
+                ds = lax.convert_element_type(lax.select(
+                    lax.gt(_dot(q, placed[r], _NT), 0.0), g,
+                    lax.full_like(g, 0)), q.dtype)
+                u = lax.add(u, _dot(ds, placed[r], _NN))   # [c, width]
+                if group > 1:   # head r's columns of the weighted queries
+                    wq_r = lax.select(lax.eq(lane_head, r), wq,
+                                      lax.full_like(wq, 0))
+                else:
+                    wq_r = wq
+                dk = lax.add(dk, _dot(ds, wq_r, _TN))      # [bk, width]
+            u_ref[0, :, cols] = u
+            dk_ref[0] = dk
+            return carry
+
+        lax.fori_loop(0, heads // group, one_group, 0)
+
+    @pl.when(past)
+    def _past():
+        dk_ref[...] = _zeros(dk_ref)
+
+
+def _index_call(kernel, name: str, first, in_specs, out_specs, out_shape,
+                grid, *args):
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_specs),
+        out_shape=out_shape, compiler_params=_INDEX_GRID,
+        interpret=_interpret(), name=name,
+    )(jnp.reshape(first, (1,)).astype(jnp.int32), *args)
+
+
+def _index_specs(c: int, width: int, dim: int, block_k: int):
+    """BlockSpecs over the grid ``(b, j)``: a chunk's queries [b, c,
+    width] whole, the key block ``j`` of [b, s, dim] (held at the chunk's
+    last where ``j`` is past it: no DMA), and a [b, c, s] tile."""
+    def k_block(j, first_ref):
+        return lax.min(j, lax.div(lax.add(first_ref[0], c - 1), block_k))
+
+    return (pl.BlockSpec((1, c, width), lambda b, j, f: (b, 0, 0)),
+            pl.BlockSpec((1, block_k, dim), lambda b, j, f: (
+                b, k_block(j, f), 0)),
+            pl.BlockSpec((1, c, block_k), lambda b, j, f: (
+                b, 0, k_block(j, f))))
+
+
+@functools.partial(jax.jit, static_argnames=("group", "block_k", "operands"))
+def _index_fwd(iq, iw, ik, first, group: int, block_k: int, operands):
+    """`index_scores`' forward kernel (``hvd_dsa_index_fwd``), the MXU
+    taking ``operands`` (`_index_operands`)."""
+    B, c, heads, dim = iq.shape
+    s = ik.shape[1]
+    q_spec, k_spec, _ = _index_specs(c, heads * dim, dim, block_k)
+    w_spec = pl.BlockSpec((1, c, heads), lambda b, j, f: (b, 0, 0))
+    kernel = functools.partial(
+        _index_fwd_kernel, heads=heads, group=group, dim=dim,
+        scale=(heads * dim) ** -0.5, block_k=block_k)
+    return _index_call(
+        kernel, "hvd_dsa_index_fwd", first,
+        [q_spec, w_spec, k_spec],
+        pl.BlockSpec((1, c, block_k), lambda b, j, f: (b, 0, j)),
+        jax.ShapeDtypeStruct((B, c, s), jnp.float32, vma=_vma(iq, iw, ik)),
+        (B, s // block_k),
+        iq.reshape(B, c, heads * dim).astype(operands), iw, ik)
+
+
+@functools.partial(jax.jit, static_argnames=("group", "block_k", "operands"))
+def _index_bwd(iq, iw, ik, first, g, group: int, block_k: int, operands):
+    """`index_scores`' VJP: one kernel (``hvd_dsa_index_bwd``) recomputes
+    each head's product in a tile and takes, with the cotangent ``g``
+    where it is positive, ``U = g·mask @ k`` (summed over the key tiles)
+    and each key tile's ``Σ (g·mask)ᵀ @ (w·scale·q)``; the queries' and
+    the weights' gradients follow from ``U`` here, ``w·scale·U`` and
+    ``scale·Σ q·U`` (``relu(q·k) = mask·(q·k)``), a [c, heads, dim]
+    pass."""
+    B, c, heads, dim = iq.shape
+    s, width = ik.shape[1], group * dim
+    scale = (heads * dim) ** -0.5
+    q = iq.reshape(B, c, heads * dim).astype(operands)
+    wq = (iq * (iw * scale)[..., None]).reshape(q.shape).astype(operands)
+    q_spec, k_spec, tile_spec = _index_specs(c, heads * dim, dim, block_k)
+    vma = _vma(iq, iw, ik, g)
+    u, dk = _index_call(
+        functools.partial(_index_bwd_kernel, heads=heads, group=group,
+                          dim=dim, block_k=block_k),
+        "hvd_dsa_index_bwd", first, [q_spec, q_spec, k_spec, tile_spec],
+        [q_spec, pl.BlockSpec((1, block_k, width),
+                              lambda b, j, f: (b, j, 0))],
+        [jax.ShapeDtypeStruct(q.shape, jnp.float32, vma=vma),
+         jax.ShapeDtypeStruct((B, s, width), jnp.float32, vma=vma)],
+        (B, s // block_k), q, wq, ik, g)
+    u = u.reshape(iq.shape)
+    d_iw = jnp.sum(q.astype(jnp.float32).reshape(iq.shape) * u, -1) * scale
+    return (u * (iw * scale)[..., None], d_iw,
+            dk.reshape(B, s, group, dim).sum(2))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def index_scores(iq, iw, ik, first, group: int, block_k: int):
+    """The learned-sparse indexer's scores of a chunk of queries, ``I(t,
+    s) = Σ_j w_j(t) relu(q_j(t)·k(s)) (heads·dim)^-½`` for the keys ``s
+    <= t`` and 0 for the later ones, [B, c, s] float32: queries ``iq``
+    [B, c, heads, dim] ``first ..`` with weights ``iw`` [B, c, heads]
+    against keys ``ik`` [B, s, dim] (``models/transformer.py``
+    ``_index_scores`` where a key is causal, its precision too); ``group``
+    and ``block_k`` as `index_blocks` gives them.
+
+    Two kernels (``hvd_dsa_index_fwd``, ``hvd_dsa_index_bwd``) over the
+    chunk's key tiles: each head's [c, dim] × [dim, bk] product, its ReLU
+    and weight and the sum over the heads stay in VMEM, only ``I`` leaves;
+    a tile past the chunk's last query is neither fetched nor computed
+    (it reads 0, and its keys take no gradient), and the one the diagonal
+    cuts is masked. The gradient recomputes the products once: three
+    products a head and tile, where a flash backward's two kernels take
+    four."""
+    with _pinned_mesh():
+        return _index_fwd(iq, iw, ik, first, group, block_k,
+                          _index_operands())
+
+
+def _index_scores_fwd(iq, iw, ik, first, group, block_k):
+    return index_scores(iq, iw, ik, first, group, block_k), (iq, iw, ik,
+                                                             first)
+
+
+def _index_scores_bwd(group, block_k, res, g):
+    iq, iw, ik, first = res
+    with _pinned_mesh():
+        return (*_index_bwd(iq, iw, ik, first, g, group, block_k,
+                            _index_operands()), None)
+
+
+index_scores.defvjp(_index_scores_fwd, _index_scores_bwd)

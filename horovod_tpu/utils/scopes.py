@@ -74,7 +74,8 @@ the decoder's default one (a looped decoder's once a pass) and
 ``attention_window_calls`` of them with a window,
 ``attention_latent_calls`` of them over latent heads,
 ``attention_sparse_calls`` of them over the keys each query selected
-(learned sparse attention), ``attention_kept_calls``
+(learned sparse attention) and ``indexer_kernel_calls`` of those whose
+indexer's scores run through its kernels, ``attention_kept_calls``
 of them in a checkpointed block that keeps the kernels' residuals, and
 ``remat_kept_mb``, those residuals' and a kept choice's bytes / 1e6 over the blocks
 (counts all: each layer is noted once, outside its checkpoint); of a sparse-expert
@@ -384,7 +385,7 @@ def note_exchange(buffers, axis_name: str, packed: bool = False) -> None:
 
 def note_attention(kernel: bool, window: bool = False,
                    kept: bool = False, latent: bool = False,
-                   sparse: bool = False) -> None:
+                   sparse: bool = False, indexer_kernel: bool = False) -> None:
     """Called once per layer whose attention is the default one, where
     ``models/transformer.py`` lays its blocks out (outside
     ``jax.checkpoint``, which traces a block of one kind once however
@@ -393,7 +394,9 @@ def note_attention(kernel: bool, window: bool = False,
     fused kernels or to `causal_attention`, with a window or without;
     ``kept``: in a checkpointed block whose policy keeps the kernels'
     residuals (`KEPT_BY_REMAT`); ``sparse``: over the keys each query
-    selected. A no-op outside a traced ``data_parallel_step``."""
+    selected, ``indexer_kernel``: the indexer's scores through its
+    kernels (``hvd_dsa_index_*``). A no-op outside a traced
+    ``data_parallel_step``."""
     record = _tracing.get()
     if record is None:
         return
@@ -413,6 +416,8 @@ def note_attention(kernel: bool, window: bool = False,
     if sparse:  # and one without a selection
         c["attention_sparse_calls"] = (c.get("attention_sparse_calls", 0)
                                        + times)
+        c["indexer_kernel_calls"] = (c.get("indexer_kernel_calls", 0)
+                                     + times * int(indexer_kernel))
 
 
 def note_kept(nbytes: int) -> None:

@@ -638,20 +638,24 @@ def test_latent_and_layer_counters_are_there_only_where_noted():
 
 def test_the_sparse_counter_is_there_only_where_noted():
     """A call over each query's selection counts as an attention call
-    too; a decoder that notes none keeps the counters it had; the kept
-    selection's names are none of the kernels' or the router's."""
+    too, and its indexer's kernels as an ``indexer_kernel_calls`` (a
+    number on either path); a decoder that notes none keeps the counters
+    it had; the kept selection's names are none of the kernels' or the
+    router's."""
     record = scopes.StepRecord()
     with scopes.recording(record):
-        scopes.note_attention(kernel=True, kept=True, sparse=True)
+        scopes.note_attention(kernel=True, kept=True, sparse=True,
+                              indexer_kernel=True)
         scopes.note_attention(kernel=False, sparse=True)
     assert record.counters == {
         "attention_calls": 2, "attention_kernel_calls": 1,
         "attention_kept_calls": 1, "remat_kept_mb": 0.0,
-        "attention_sparse_calls": 2}
+        "attention_sparse_calls": 2, "indexer_kernel_calls": 1}
     record = scopes.StepRecord()
     with scopes.recording(record):
         scopes.note_attention(kernel=True)
     assert "attention_sparse_calls" not in record.counters
+    assert "indexer_kernel_calls" not in record.counters
     assert not set(scopes.KEPT_SELECTION) & {
         *scopes.KEPT_BY_REMAT_LATENT, scopes.KEPT_CHOICE,
         scopes.KEPT_ROUTING, *scopes.KEPT_XENT}

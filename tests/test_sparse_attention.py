@@ -316,11 +316,79 @@ def test_the_kept_selection_is_what_the_backward_pass_reads(monkeypatch):
     counters = dp.step_counters(step)
     assert counters["attention_calls"] == cfg.n_layers
     assert counters["attention_sparse_calls"] == cfg.n_layers
+    assert counters["indexer_kernel_calls"] == cfg.n_layers
     assert counters["attention_kept_calls"] == cfg.n_layers
     kept = (T._kept_bytes((1, 256), cfg, True, True, True)
             + T._kept_selection_bytes((1, 256), cfg, True))
     assert counters["remat_kept_mb"] == pytest.approx(
         cfg.n_layers * kept / 1e6)
+
+
+@pytest.mark.parametrize("first,operands", [
+    (0, jnp.float32), (1024, jnp.float32), (1536, jnp.float32),
+    (1024, jnp.bfloat16)], ids=["first", "middle", "last", "bf16"])
+def test_the_indexer_kernels_against_the_einsum(first, operands,
+                                                monkeypatch):
+    """(k) ``hvd_dsa_index_fwd`` and ``hvd_dsa_index_bwd`` in interpret
+    mode at the cell's widths (16 heads of 64, chunks of 512) over 2048
+    keys, for the first, a middle and the last chunk: the scores are
+    `_index_scores`' where a key is causal and 0 after it, whole tiles
+    past the chunk's last query included; the VJP is ``jax.vjp`` of that,
+    and a cotangent on a query's later keys moves no gradient. With the
+    MXU's bf16 operands (the TPU's), against the einsum of the operands
+    so rounded: the scores as tight, the gradients within the rounding of
+    the cotangent the kernel hands the MXU."""
+    monkeypatch.setattr(F, "_index_operands", lambda: operands)
+    B, c, s, heads, dim = 1, 512, 2048, 16, 64
+    keys = jax.random.split(jax.random.PRNGKey(first), 4)
+    iq = jax.random.normal(keys[0], (B, c, heads, dim))
+    iw = jax.random.normal(keys[1], (B, c, heads))
+    ik = jax.random.normal(keys[2], (B, s, dim))
+    g = jax.random.normal(keys[3], (B, c, s))
+    group, block_k = F.index_blocks(c, s, heads, dim)
+    assert (group, block_k) == (2, 512)
+    causal = jnp.arange(s)[None, :] <= first + jnp.arange(c)[:, None]
+
+    def rounded(a):  # as the MXU takes it, the gradient passing through
+        return a + jax.lax.stop_gradient(a.astype(operands) - a)
+
+    def einsum(q, w, k):
+        return jnp.where(causal, T._index_scores(rounded(q), w, rounded(k)),
+                         0.0)
+
+    def kernels(q, w, k):
+        return F.index_scores(q, w, k, first, group, block_k)
+
+    got, back = jax.vjp(kernels, iq, iw, ik)
+    want, want_back = jax.vjp(einsum, iq, iw, ik)
+    close(got, want, 1e-5)
+    assert float(jnp.abs(jnp.where(causal, 0.0, got)).max()) == 0
+    past = -(-(first + c) // block_k) * block_k
+    assert past == s or float(jnp.abs(got[..., past:]).max()) == 0
+    grads = back(g)
+    close(grads, want_back(g), 1e-5 if operands == jnp.float32 else 1e-2)
+    for a, b in zip(grads, back(jnp.where(causal, g, 0.0))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("path", ["kernels", "einsum"])
+def test_the_indexer_kernel_counter(path, monkeypatch):
+    """(l) ``indexer_kernel_calls`` counts the layers whose indexer takes
+    its kernels: all four where the attention kernels are taken and the
+    chunks tile, none on the einsum path (a number there, not absent)."""
+    cfg, _ = on_the_kernels(monkeypatch)
+    cfg = T.dataclasses.replace(cfg, n_layers=4)
+    monkeypatch.setattr(T, "_on_tpu", lambda: path == "kernels")
+    monkeypatch.setattr(T, "_partitioned_by_xla", lambda: False)  # one chip
+    record = scopes.StepRecord()
+    with scopes.recording(record):
+        jax.eval_shape(lambda p, t: T.lm_loss(p, t, cfg, use_constraints=False),
+                       jax.eval_shape(lambda: T.init(jax.random.PRNGKey(0),
+                                                     cfg)),
+                       jax.ShapeDtypeStruct((1, 257), jnp.int32))
+    assert record.counters["attention_sparse_calls"] == 4
+    assert record.counters["indexer_kernel_calls"] == (
+        4 if path == "kernels" else 0)
 
 
 def test_the_held_shares_add_up_under_this_router():

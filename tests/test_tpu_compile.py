@@ -198,6 +198,41 @@ def test_sparse_kernels_compile_at_the_dsa_decoder_shapes(topo):
                    for n in ("fwd", "bwd_dq", "bwd_dkv", "target"))
 
 
+def test_indexer_kernels_compile_at_the_dsa_decoder_shapes(topo):
+    """The learned-sparse cell's indexer: a chunk of 512 queries over
+    16,384 keys, 16 heads of 64 (two to a lane tile), at the blocks
+    `index_blocks` gives, bf16 products as the TPU takes them. The TPU
+    compiler takes both kernels, under names of their own that no
+    reader's pattern matches: not ``hvd_flash_*``, ``hvd_mla_*``, nor the
+    sparse attention kernels' that ``sparse_attention_roofline_pct``
+    reads (``chipbench/dsa_reads.py``)."""
+    import re
+
+    from chipbench import dsa_reads
+
+    one = SingleDeviceSharding(topo.devices[0])
+    c, s, heads, dim = 512, 16384, 16, 64
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    group, block_k = F.index_blocks(c, s, heads, dim)
+    assert (group, block_k) == (2, 512)
+    static = dict(group=group, block_k=block_k, operands=jnp.bfloat16)
+    iq, iw, ik = shape(1, c, heads, dim), shape(1, c, heads), shape(1, s, dim)
+    first = shape(dtype=jnp.int32)
+    fwd = F._index_fwd.lower(iq, iw, ik, first, **static).compile()
+    assert "hvd_dsa_index_fwd" in fwd.as_text()
+    assert fwd.out_info.shape == (1, c, s)
+    bwd = F._index_bwd.lower(iq, iw, ik, first, shape(1, c, s),
+                             **static).compile()
+    assert "hvd_dsa_index_bwd" in bwd.as_text()
+    assert [o.shape for o in bwd.out_info] == [iq.shape, iw.shape, ik.shape]
+    others = re.compile(r"^hvd_(flash|mla)_(fwd|bwd_dq|bwd_dkv)(_w\d+)?$")
+    for name in ("hvd_dsa_index_fwd", "hvd_dsa_index_bwd"):
+        assert not others.match(name) and not dsa_reads._DSA.match(name)
+
+
 @pytest.mark.parametrize("direction", ["forward", "gradient"])
 def test_chunked_expert_layer_compiles_at_the_sparse_cell_sizes(
         topo, direction):
