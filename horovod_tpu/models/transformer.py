@@ -164,8 +164,11 @@ class TransformerConfig:
     layer_types: tuple = ()                # per layer, as given: "conv" or
     #                                        "full_attention"; (): attention
     conv_taps: int = 3                     # a conv layer's causal filter
-    expert_chunk: int = 1                  # an expert layer's chunks of
-    #                                        sorted rows, in tokens' rows
+    expert_chunk: int = 1                  # read by nothing: the chunks
+    #                                        follow from the expected load
+    #                                        (`moe.chunk_rows`); accepted
+    #                                        while the benchmark's conv
+    #                                        family still passes it
 
     @property
     def head_dim(self) -> int:
@@ -551,7 +554,7 @@ def _forward(params, tokens, cfg: TransformerConfig, *,
                 ff = moe.expert_layer(h.reshape(-1, h.shape[-1]), *routed,
                                       blk["experts"], cfg.held,
                                       activation=cfg.expert_activation,
-                                      chunk_tokens=cfg.expert_chunk)
+                                      experts=cfg.n_experts)
                 x = x + ff.reshape(h.shape)
             if "shared" in blk:  # every token's, beside the routed ones
                 with jax.named_scope(scopes.SHARED_EXPERT):
@@ -613,8 +616,8 @@ def _forward(params, tokens, cfg: TransformerConfig, *,
                 bound = rows * min(cfg.experts_per_token, cfg.held[1])
                 scopes.note_moe(cfg.held[1], cfg.n_experts,
                                 cfg.experts_per_token, bound,
-                                moe.chunk_rows(rows, bound,
-                                               cfg.expert_chunk))
+                                moe.chunk_rows(rows, cfg.experts_per_token,
+                                               cfg.held[1], cfg.n_experts))
                 if "shared" in blk:
                     scopes.note_layer("shared_experts")
             elif cfg.n_experts:
@@ -915,7 +918,7 @@ def _kept_bytes(shape, cfg: TransformerConfig, kernels: bool = True,
 
         return (_kept_bytes(shape, cfg, kernels, choice)
                 + moe.index_bytes(b * s, cfg.experts_per_token, cfg.held[1],
-                                  cfg.expert_chunk))
+                                  cfg.n_experts))
     wide = 2 * (cfg.n_heads + cfg.kv_heads) * cfg.head_dim
     if cfg.kv_latent:
         wide += (cfg.n_heads + 1) * cfg.d_rope

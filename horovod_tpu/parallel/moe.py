@@ -18,22 +18,26 @@ the layer is whole, around two functions:
   would add is left out, here and in whatever this is compared with.
 
 The cost follows the rows routed, chunk by chunk (`_sorted_side`). The
-sorted positions are cut into chunks of as many rows as the layer has
-tokens, or a multiple of that (`chunk_rows`: ``min(k, held)`` chunks on
-one chip, fewer for the multiple; one, the whole, behind the exchange),
-and everything whose leading dimension is
+sorted positions are cut into chunks sized from the rows the held
+experts expect, ``tokens * k * held / experts`` with ``experts`` the
+router's width, times `EXPECTED_LOAD` (`chunk_rows`; the whole bound,
+one chunk, where that reaches it: a layer that holds every expert, or
+behind the exchange), and everything whose leading dimension is
 the sorted position is computed for one chunk at a time: the gather of
 the tokens' rows, both grouped matmuls, the activation and the routing
 weight, and the token side, which sums each token's rows of the chunk in
 token order and brings the sums home with one gather of ``tokens`` rows
 (`_token_sums`); in the backward pass the gather of the cotangent to the
 rows and all their transposes. Chunk 0 always runs; one loop a direction
-walks the further chunks that a routed row reaches, so a step in which
-the held experts take no more rows than there are tokens pays for one
-chunk and a loop of no trips, and a skewed one for as many chunks as its
-rows fill: more trips, never a dropped row, and one path whatever the
-routing. Nothing is sized for the (token, expert) pairs but vectors of
-indices (`_indices`).
+walks the further chunks that a routed row reaches, so a step whose held
+experts take no more rows than they expect, with a quarter to spare,
+pays for one chunk and a loop of no trips, and a skewed one for as many
+chunks as its rows fill: more trips, never a dropped row, and one path
+whatever the routing. A chunk sized by the tokens instead would be
+mostly rows of nothing wherever a token's held experts expect fewer
+rows than one, and a second chunk would run by the seed wherever they
+expect about one. Nothing is sized for the (token, expert) pairs but
+vectors of indices (`_indices`).
 
 On one chip nothing is exchanged. With ``axis_name`` (inside a
 ``shard_map`` over the expert-parallel axis, each chip holding
@@ -54,6 +58,8 @@ likewise).
 from __future__ import annotations
 
 import functools
+import math
+from fractions import Fraction
 
 import jax
 import jax.numpy as jnp
@@ -221,13 +227,20 @@ def _indices(chunk: int, rows: int, count: int, key, route_w) -> dict:
     last ``ends`` is the rows routed); ``weight`` [pairs]: the pair's
     routing weight, by sorted position (moved by the sort as its bits:
     ``route_w``'s gradient is `_sorted_side`'s). The token side, by
-    chunk of ``chunk`` positions: ``back`` [rows], the chunk's positions
-    in token order; ``token`` [rows], the token of that slot (``n``
+    chunk of ``chunk`` positions: ``back`` [size], the chunk's positions
+    in token order; ``token`` [size], the token of that slot (``n``
     where no routed row is: last); ``end`` [chunks, n], the slot that
     ends each token's rows in the chunk, or ``chunk`` where the token has
-    none there."""
+    none there.
+
+    The ``rows`` positions are walked in ``chunks = ceil(rows / chunk)``
+    chunks, ``size`` positions: what a chunk slices (``order``,
+    ``weight``, ``back``, ``token``) is at least that long, the positions
+    past ``rows`` no expert's and no token's, so that no chunk's slice
+    is clamped onto another's positions."""
     n, k = route_w.shape
-    chunks = rows // chunk
+    chunks = -(-rows // chunk)
+    size = chunks * chunk
     pairs = lax.iota(jnp.int32, n * k)
     order, weight = lax.sort((key * (n * k) + pairs, _as(
         lax.stop_gradient(route_w).T.reshape(-1), jnp.int32)), **_UNSTABLE)
@@ -246,23 +259,38 @@ def _indices(chunk: int, rows: int, count: int, key, route_w) -> dict:
                     lax.full_like(place, chunks)).reshape(k, 1, n)
     held = jnp.sum(of == lax.iota(jnp.int32, chunks)[:, None], axis=0,
                    dtype=jnp.int32)                  # [chunks, n]
-    ix = {"order": order, "weight": _as(weight, jnp.float32), "ends": ends,
+    if size > rows:  # the last chunk's slots past the bound, in order
+        back = jnp.concatenate([back, rows + lax.iota(jnp.int32,
+                                                      size - rows)])
+    ix = {"order": _padded(order, size, 0),
+          "weight": _padded(_as(weight, jnp.float32), size, 0.0),
+          "ends": ends,
           "starts": jnp.concatenate([jnp.zeros(1, jnp.int32), ends[:-1]]),
-          "back": lax.rem(back, chunk), "token": lax.rem(slot, n + 1),
+          "back": lax.rem(back, chunk),
+          "token": _padded(lax.rem(slot, n + 1), size, n),
           "end": lax.select(held > 0, jnp.cumsum(held, 1) - 1,
                             lax.full_like(held, chunk))}
     return {name: ad_checkpoint.checkpoint_name(a, scopes.KEPT_ROUTING)
             for name, a in ix.items()}
 
 
-def index_bytes(n: int, k: int, count: int, chunk_tokens: int = 1) -> int:
+def _padded(a, size: int, fill):
+    """``a`` [m] with ``fill`` after it up to ``size``, if it is shorter."""
+    if a.shape[0] >= size:
+        return a
+    return lax.pad(a, np.array(fill, a.dtype), [(0, size - a.shape[0], 0)])
+
+
+def index_bytes(n: int, k: int, count: int, experts: int | None = None) -> int:
     """Bytes of `_indices`' arrays for ``n`` tokens, ``k`` choices and
-    ``count`` experts held, in chunks of ``chunk_tokens`` tokens' rows,
-    all int32 or float32: what a checkpointed block keeps of an expert
-    layer."""
+    ``count`` experts held of the router's ``experts`` (default: those
+    held), in `chunk_rows`' chunks, all int32 or float32: what a
+    checkpointed block keeps of an expert layer."""
     rows = n * min(k, count)
-    return 4 * (2 * n * k + 2 * count + 2 * rows
-                + rows // chunk_rows(n, rows, chunk_tokens) * n)
+    chunk = chunk_rows(n, k, count, experts or count)
+    chunks = -(-rows // chunk)
+    size = chunks * chunk
+    return 4 * (2 * max(n * k, size) + 2 * count + 2 * size + chunks * n)
 
 
 def _token_sums(rows, c, chunk: int, span: int, ix, scale=None):
@@ -339,13 +367,14 @@ def _cast(weights, dtype):
             weights["down"].astype(dtype))
 
 
-def _span(pairs: int, n: int, weights) -> int:
-    """The most held rows one token can have: its chosen are distinct."""
-    return min(pairs // n, weights["gate"].shape[0])
+def _span(k: int, weights) -> int:
+    """The most held rows one token can have: its ``k`` chosen are
+    distinct."""
+    return min(k, weights["gate"].shape[0])
 
 
 def _sorted_side_fwd(chunk, activation, x, route_w, weights, ix):
-    n, span = x.shape[0], _span(ix["order"].shape[0], x.shape[0], weights)
+    n, span = x.shape[0], _span(route_w.shape[1], weights)
     gate_up, down = _cast(weights, x.dtype)
 
     def on_chunk(c, y):
@@ -358,14 +387,15 @@ def _sorted_side_fwd(chunk, activation, x, route_w, weights, ix):
                                            activation), c, chunk, span, ix, w)
         return y_c if y is None else y + y_c
 
-    y = _in_chunks(ix["ends"][-1], chunk, n * span // chunk, on_chunk)
+    y = _in_chunks(ix["ends"][-1], chunk, ix["back"].shape[0] // chunk,
+                   on_chunk)
     return y, (x, route_w.shape, weights, ix)
 
 
 def _sorted_side_bwd(chunk, activation, res, g):
     x, shape, weights, ix = res
-    n, pairs, live = x.shape[0], ix["order"].shape[0], ix["ends"][-1]
-    span = _span(pairs, n, weights)
+    n, live, size = x.shape[0], ix["ends"][-1], ix["back"].shape[0]
+    pairs, span = shape[0] * shape[1], _span(shape[1], weights)
     # a custom VJP's backward pass is traced outside the scope its call
     # stood in: name it again, or a trace files it under nothing
     with jax.named_scope(scopes.MOE):
@@ -393,22 +423,24 @@ def _sorted_side_bwd(chunk, activation, res, g):
             d_x = _token_sums(d_xs, c, chunk, span, ix)
             d_p = [d.astype(jnp.float32) for d in d_p]
             if carry is None:
-                return d_x, lax.pad(d_w, np.array(0, d_w.dtype),
-                                    [(0, pairs - chunk, 0)]), d_p
+                return d_x, _padded(d_w, size, 0.0), d_p
             return (carry[0] + d_x,
                     lax.dynamic_update_slice_in_dim(carry[1], d_w, lo, 0),
                     [a + d for a, d in zip(carry[2], d_p)])
 
         d_x, d_w, (d_gate_up, d_down) = _in_chunks(live, chunk,
-                                                   n * span // chunk, on_chunk)
+                                                   size // chunk, on_chunk)
         width = weights["gate"].shape[-1]
         d_weights = {"gate": d_gate_up[..., :width],
                      "up": d_gate_up[..., width:], "down": d_down}
-        # the routing weights' gradient back in pair order: one sort
-        d_w = lax.select(lax.iota(jnp.int32, pairs) < live, d_w,
+        # the routing weights' gradient back in pair order: one sort of
+        # the ``pairs`` positions (those past them are no pair's)
+        d_w = lax.select(lax.iota(jnp.int32, size) < live, d_w,
                          lax.full_like(d_w, 0))
-        d_route = _as(lax.sort((ix["order"], _as(d_w, jnp.int32)),
-                               **_UNSTABLE)[1], jnp.float32)
+        d_w = lax.slice_in_dim(_padded(d_w, pairs, 0.0), 0, pairs)
+        d_route = _as(lax.sort((lax.slice_in_dim(ix["order"], 0, pairs),
+                                _as(d_w, jnp.int32)), **_UNSTABLE)[1],
+                      jnp.float32)
         return (d_x, d_route.reshape(shape[::-1]).T,
                 jax.tree.map(lambda d, w: d.astype(w.dtype), d_weights,
                              weights), None)
@@ -417,31 +449,42 @@ def _sorted_side_bwd(chunk, activation, res, g):
 _sorted_side.defvjp(_sorted_side_fwd, _sorted_side_bwd)
 
 
-def chunk_rows(n: int, rows: int, tokens: int = 1) -> int:
-    """Rows of one of the chunks a layer's ``rows`` sorted positions are
-    walked in, ``n`` being the rows of what the layer gathers from:
-    ``tokens * n`` where that cuts ``rows`` into whole chunks. On one
-    chip it does for ``tokens`` 1 (``rows`` is the tokens times the most
-    held experts one token can have); behind the exchange ``n`` is
-    ``rows``, the received buffer: one chunk. A layer whose held experts
-    expect as many rows as it has tokens (``k * held / experts`` = 1)
-    would run a second chunk in about half its steps, by the seed: there
-    ``tokens`` 2 makes the first chunk hold the rows routed, at a cost
-    that does not hang on which side of a chunk's end they fall."""
-    return tokens * n if rows % (tokens * n) == 0 else rows
+#: the first chunk holds this many times the rows the held experts
+#: expect: one of the capacity factors Switch Transformer trains at
+#: (Fedus et al. 2021, Table 1). It caps nothing, it only sets where the
+#: walk's first stride ends: the fullest layer the expert cells' records
+#: hold took 1.19 times its expected rows (PERF.md §4), so a balanced
+#: step runs one chunk, and a skewed one runs more and drops nothing.
+EXPECTED_LOAD = Fraction(5, 4)
+
+#: a chunk's rows are a whole number of these: a bf16 sublane tile
+ROW_TILE = 16
+
+
+def chunk_rows(n: int, k: int, held: int, experts: int) -> int:
+    """Rows of one of the chunks a layer's sorted positions are walked
+    in, for ``n`` tokens of ``k`` choices each among the router's
+    ``experts``, ``held`` of them here: the rows the held experts expect,
+    ``n * k * held / experts``, times `EXPECTED_LOAD`, rounded up to a
+    whole number of `ROW_TILE`, and at most the bound ``n * min(k,
+    held)`` (one chunk: a layer that holds every expert, or the received
+    buffer behind the exchange, where ``n`` is its rows and ``k`` 1)."""
+    expected = math.ceil(EXPECTED_LOAD * n * k * held / experts)
+    return min(n * min(k, held), -(-expected // ROW_TILE) * ROW_TILE)
 
 
 def _experts_on_tokens(x, key, route_w, params, activation: str = "relu",
-                       chunk_tokens: int = 1):
+                       experts: int | None = None):
     """``x`` [n, d], ``key`` [n * k] (pair ``j*n + i`` → local expert,
     ``count`` = none held here), ``route_w`` [n, k] → [n, d]: each
     token's held pairs' weighted expert outputs, summed (`_sorted_side`),
-    in chunks of ``chunk_tokens * n`` sorted positions (`chunk_rows`) up
-    to the bound ``n * min(k, count)``."""
+    in `chunk_rows`' chunks of the sorted positions (``experts`` the
+    router's width, default the ``count`` held) up to the bound ``n *
+    min(k, count)``."""
     n, k = route_w.shape
     count = params["gate"].shape[0]
     bound = n * min(k, count)
-    chunk = chunk_rows(n, bound, chunk_tokens)
+    chunk = chunk_rows(n, k, count, experts or count)
     route_w = route_w.astype(jnp.float32)
     weights = {name: params[name] for name in ("gate", "up", "down")}
     return _sorted_side(chunk, activation, x, route_w, weights,
@@ -502,7 +545,7 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 def expert_layer(u, chosen, weights, expert_params, held=None, *,
                  axis_name=None, activation: str = "relu",
-                 chunk_tokens: int = 1):
+                 experts: int | None = None):
     """The held experts' part of a gated sparse feed-forward.
 
     Args:
@@ -518,14 +561,16 @@ def expert_layer(u, chosen, weights, expert_params, held=None, *,
       axis_name: exchange the pairs over this mesh axis, so that every
         chosen expert is somebody's.
       activation: ``act``: ``"relu"`` (ReGLU experts) or ``"silu"``.
-      chunk_tokens: on one chip, the chunks of sorted rows hold this
-        many times ``t`` (`chunk_rows`).
+      experts: the router's width, which ``chosen`` counts over (default:
+        the experts held). On one chip the sorted rows are walked in
+        chunks sized from the rows the held experts expect
+        (`chunk_rows`); with the default, one chunk of the bound.
 
     Returns ``sum over the chosen e that are held of w_e * E_e(u)``,
     [t, d] in ``u``'s dtype, exactly zero for a token that chose none of
     them. The weights stay normalised over all the chosen, held or not.
     On one chip the pairs are sorted by expert and walked in chunks of
-    ``t`` sorted rows (`_sorted_side`); each token's rows are summed in
+    sorted rows (`_sorted_side`); each token's rows are summed in
     token order and brought home by one gather of ``t`` rows
     (`_token_sums`), and no array of the pairs' rows is made in either
     direction. Under ``axis_name`` each received row is a token of one
@@ -548,7 +593,7 @@ def expert_layer(u, chosen, weights, expert_params, held=None, *,
     local = chosen - first
     key = jnp.where((local >= 0) & (local < count), local, count)
     return _experts_on_tokens(u, key.T.reshape(-1), weights, expert_params,
-                              activation, chunk_tokens)
+                              activation, experts)
 
 
 def _exchanged(u, chosen, weights, params, axis_name, bound: int,

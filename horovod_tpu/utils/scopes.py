@@ -87,7 +87,8 @@ decoder ``moe_layers``, ``experts_held`` of ``experts_total`` in each,
 ``experts_per_token`` chosen, ``moe_buffer_rows``, the bound its expert layer's buffers are sized for
 (per layer: tokens times the most experts one token can have here),
 ``moe_chunks`` and ``moe_chunk_rows``, the chunks that bound is walked in
-and the rows of each (how many of them ran is the data's: a trace's),
+(the last padded where they do not divide it) and the rows of each (how
+many of them ran is the data's: a trace's),
 ``dense_layers`` its leading layers without experts and ``shared_experts``
 its expert layers with shared experts (a count of layers each);
 ``conv_layers`` a decoder's gated short-convolution layers (which note no
@@ -511,7 +512,7 @@ def note_moe(held: int, total: int, per_token: int,
     c["experts_per_token"] = per_token
     c["moe_buffer_rows"] = buffer_rows
     c["moe_chunk_rows"] = chunk_rows
-    c["moe_chunks"] = buffer_rows // chunk_rows
+    c["moe_chunks"] = -(-buffer_rows // chunk_rows)   # the last may be part
 
 
 # -- set-up, seen from inside the program ----------------------------------

@@ -24,8 +24,7 @@ ARCH = {"rope_theta": 1e6, "num_experts_per_tok": 4,
 
 def toy(**kw):
     """The cut's five layers at d 64: four heads of 16 over two, a dense
-    layer, then 8 of 32 experts held, 4 a token, their sorted rows in
-    chunks of two tokens' worth (as the cell's)."""
+    layer, then 8 of 32 experts held, 4 a token (as the cell's)."""
     return T.TransformerConfig(**{**dict(
         vocab_size=64, d_model=64, n_heads=4, n_kv_heads=2, d_head=16,
         n_layers=5, d_ff=96, max_seq=256, dtype=jnp.float32,
@@ -34,7 +33,7 @@ def toy(**kw):
         tie_embeddings=True, mlp="gated", n_dense_layers=1,
         router_scoring="sigmoid", router_input="normed",
         expert_activation="silu", qk_norm=True, norm_eps=1e-5,
-        layer_types=KINDS, expert_chunk=2), **kw})
+        layer_types=KINDS), **kw})
 
 
 def seeded_params(cfg, key=0):
@@ -126,9 +125,10 @@ def test_a_checkpointed_conv_layer_keeps_its_input_alone():
 def test_the_held_shares_add_up_under_this_router():
     """(d) 32 experts cut four ways, eight held a chip, four chosen a
     token by the largest ``sigmoid(u W_r) + b``, weights the chosen
-    sigmoids normalised, SiLU experts, the sorted rows in chunks of one
-    or two tokens' worth: the four shares' outputs add up to the uncut
-    reference layer's."""
+    sigmoids normalised, SiLU experts, the sorted rows in chunks of the
+    rows the held experts expect (the router's width given) or in one
+    chunk of the bound (not given): the four shares' outputs add up to
+    the uncut reference layer's."""
     rng = np.random.RandomState(0)
     t, d, f, experts = 64, 32, 16, 32
     u = jnp.asarray(rng.randn(t, d), jnp.float32)
@@ -144,7 +144,8 @@ def test_the_held_shares_add_up_under_this_router():
         chosen, w = moe.route(u @ router, 4, scoring="sigmoid", bias=bias)
         shares = [moe.expert_layer(
             u, chosen, w, jax.tree.map(lambda x: x[first:first + 8], params),
-            (first, 8), activation="silu", chunk_tokens=1 + first % 16 // 8)
+            (first, 8), activation="silu",
+            experts=experts if first % 16 else None)
             for first in range(0, experts, 8)]
         return sum(shares), kanana2.experts(u, params, weights, 0)
 

@@ -351,7 +351,8 @@ def test_remat_keeps_what_the_latent_kernels_read(on_the_kernels):
     assert T._kept_bytes((2, 256), cfg, True, True) == a_block + choice
     # and what the expert layer sorted out of its routing
     indices = sum(a.size * a.dtype.itemsize for a in jax.eval_shape(
-        lambda key, w: moe._indices(512, 1024, 4, key, w),
+        lambda key, w: moe._indices(moe.chunk_rows(512, 2, 4, cfg.n_experts),
+                                    1024, 4, key, w),
         jax.ShapeDtypeStruct((1024,), jnp.int32),
         jax.ShapeDtypeStruct((512, 2), jnp.float32)).values())
     assert T._kept_bytes((2, 256), cfg, True, True, True) == \

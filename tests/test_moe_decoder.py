@@ -145,37 +145,68 @@ def test_no_row_is_dropped_under_skew(count):
                                    rtol=2e-3)
 
 
-#: 8 experts, 4 a token, experts 4..7 held: four chunks of a token's rows
-#: each. The scores' bias decides how many of a token's four are held.
+@pytest.mark.parametrize("shape,rows", [
+    ((16384, 4, 8, 32), 20480), ((16384, 8, 8, 128), 10240),
+    ((16384, 6, 8, 64), 15360), ((16384, 6, 16, 128), 15360),
+    ((16384, 6, 8, 8), 16384 * 6), ((24, 2, 4, 8), 32),
+    ((64, 4, 8, 9), 64 * 4), ((100, 1, 3, 3), 100)],
+    ids=["lfm2", "keye", "smallthinker", "kanana", "all-held",
+         "a-tile-up", "past-the-bound", "the-exchange"])
+def test_a_chunk_holds_the_expected_rows_and_a_quarter(shape, rows):
+    """(h) A chunk is the rows the held experts expect (``n * k * held /
+    experts``) times 1.25, rounded up to a whole number of 16, and at
+    most the bound ``n * min(k, held)``: 20,480, 10,240, 15,360 and
+    15,360 at the four expert cells' layers; the bound, one chunk, where
+    every expert is held, where the expected rows reach it, and behind
+    the exchange (a buffer of rows of one choice each)."""
+    assert moe.chunk_rows(*shape) == rows
+
+
+#: 8 experts, 4 a token, experts 4..7 held, 32 tokens: 64 rows expected,
+#: chunks of 80 over the bound of 128, the second padded to 160. Each
+#: case: how many of a token's four choices are held, token by token,
+#: and the chunks the rows routed reach.
 LIVE_CHUNKS = {
-    "none": ((0, 1, 2, 3), 0),       # no token chooses a held expert
-    "one": ((0, 1, 2), 1),           # at most one held expert a token
-    "a-group-split": ((0, 1), 2),    # ~4/3 a token: chunk 0 ends in a group
-    "all": ((4, 5, 6, 7), 4),        # every token chooses all four held
+    "none": ([0] * 32, 0),           # no token chooses a held expert
+    "one": ([1] * 32, 1),            # one held expert a token
+    "a-group-split": ([3] * 32, 2),  # 96 rows: chunk 0 ends in a group
+    "at-a-chunk-end": ([4] * 16 + [1] * 16, 1),  # 80 rows: chunk 0, full
+    "all": ([4] * 32, 2),            # every token chooses all four held
 }
 
 
 @pytest.mark.parametrize("case", LIVE_CHUNKS)
 def test_chunks_follow_the_rows_routed(case, monkeypatch):
-    """(g) The sorted side is walked in chunks of a token's rows, and a
-    chunk no routed row reaches does not run: with none, one, two (an
-    expert's rows split over the boundary) and all four chunks live, the
-    layer equals the reference row for row and gradient for gradient,
-    and the same layer evaluated as one chunk of all the rows."""
-    favoured, live_chunks = LIVE_CHUNKS[case]
-    t, first, count, k = 32, 4, 4, 4
-    u, router, params = layer_inputs(t=t, experts=8, seed=3)
+    """(g) The sorted side is walked in chunks of the rows the held
+    experts expect and a quarter, and a chunk no routed row reaches does
+    not run: with none, one, two (an expert's rows split over the
+    boundary), exactly one full chunk and all the rows routed (the last
+    chunk padded past the bound), the layer equals the reference row for
+    row and gradient for gradient, and the same layer evaluated as one
+    chunk of all the rows."""
+    counts, live_chunks = LIVE_CHUNKS[case]
+    t, first, count, k, experts = 32, 4, 4, 4, 8
+    u, _, params = layer_inputs(t=t, experts=experts, seed=3)
     held = jax.tree.map(lambda x: x[first:first + count], params)
-    bias = jnp.zeros((8,)).at[jnp.asarray(favoured)].set(50.0)
-    chosen, w = moe.route(u @ router + bias, k)
+    rng = np.random.RandomState(4)
+    chosen = jnp.asarray(np.stack([rng.permutation(np.concatenate([
+        first + rng.permutation(count)[:h], rng.permutation(first)[:k - h]]))
+        for h in counts]), jnp.int32)
+    w = jnp.asarray(rng.rand(t, k), jnp.float32)
+    w = w / w.sum(-1, keepdims=True)
+    chunk, bound = moe.chunk_rows(t, k, count, experts), t * k
+    assert (chunk, -(-bound // chunk) * chunk) == (80, 160)
     sizes = np.bincount(np.asarray(chosen).ravel(), minlength=8)[first:]
     live = int(sizes.sum())
-    assert -(-live // t) == live_chunks
+    assert -(-live // chunk) == live_chunks
     if case == "a-group-split":   # the boundary lies inside a group
-        assert t not in np.cumsum(sizes)
+        assert chunk not in np.cumsum(sizes)
+    if case == "at-a-chunk-end":
+        assert live == chunk
 
     def ours(u, held, w):
-        return moe.expert_layer(u, chosen, w, held, (first, count))
+        return moe.expert_layer(u, chosen, w, held, (first, count),
+                                experts=experts)
 
     def ref(u, held, w):
         dense = jnp.zeros((t, 8)).at[jnp.arange(t)[:, None], chosen].set(w)
@@ -192,8 +223,8 @@ def test_chunks_follow_the_rows_routed(case, monkeypatch):
                                    rtol=1e-4)
     if case == "none":
         assert all(not np.asarray(a).any() for a in jax.tree.leaves(got))
-    assert moe.chunk_rows(t, t * k) == t
-    monkeypatch.setattr(moe, "chunk_rows", lambda n, rows, tokens=1: rows)
+    monkeypatch.setattr(moe, "chunk_rows",
+                        lambda n, k, held, experts: n * min(k, held))
     for a, b in zip(jax.tree.leaves(got),
                     jax.tree.leaves(value_and_grads(ours))):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-6,
@@ -285,7 +316,7 @@ def test_the_layer_traces_no_more_than_its_parent():
     held = jax.tree.map(lambda x: x[:8], params)
     chosen, w = moe.route(u @ router, 6)
     grad = jax.make_jaxpr(jax.grad(lambda u, w, p: jnp.sum(moe.expert_layer(
-        u, chosen, w, p, (0, 8)) ** 2), (0, 1, 2)))(u, w, held)
+        u, chosen, w, p, (0, 8), experts=64) ** 2), (0, 1, 2)))(u, w, held)
     assert count(grad.jaxpr) <= PARENT_EQUATIONS
 
 
@@ -359,7 +390,8 @@ def test_step_scopes_and_counters(seeded):
     assert (counters["experts_held"], counters["experts_total"],
             counters["experts_per_token"]) == (4, 8, 2)
     assert counters["moe_buffer_rows"] == 24 * 2    # 24 tokens a chip, k=2
-    assert (counters["moe_chunks"], counters["moe_chunk_rows"]) == (2, 24)
+    # 24 rows expected (2 of 8 a token, 4 held), and a quarter: 30 → 32
+    assert (counters["moe_chunks"], counters["moe_chunk_rows"]) == (2, 32)
     assert 0 < counters["attention_window_calls"] < counters["attention_calls"]
     assert counters["attention_window_calls"] * 2 == \
         counters["attention_calls"]

@@ -235,6 +235,11 @@ def test_note_moe_notes_the_bound_and_its_chunks():
         "moe_layers": 2, "experts_held": 8, "experts_total": 64,
         "experts_per_token": 6, "moe_buffer_rows": 98304,
         "moe_chunk_rows": 16384, "moe_chunks": 6}
+    record = scopes.StepRecord()
+    with scopes.recording(record):   # the last chunk padded past the bound
+        scopes.note_moe(8, 64, 6, 98304, 15360)
+    assert (record.counters["moe_chunk_rows"],
+            record.counters["moe_chunks"]) == (15360, 7)
 
 
 def test_note_loop_counts_what_its_body_notes_once_a_pass():

@@ -261,7 +261,8 @@ def test_indexer_kernels_compile_at_the_dsa_decoder_shapes(topo):
 def test_chunked_expert_layer_compiles_at_the_sparse_cell_sizes(
         topo, direction):
     """The sparse-expert cell's layer: 16,384 tokens of 2560, experts 0-7
-    of 64 held, 6 a token, so six chunks of 16,384 sorted rows. The TPU's
+    of 64 held, 6 a token, so seven chunks of 15,360 sorted rows (12,288
+    expected and a quarter; the last chunk padded past the bound). The TPU's
     compiler keeps one ``while`` a direction for the chunks after the
     first (and no ``conditional``: nothing unrolled), adds no ``scatter``
     (both directions of every row movement are gathers) and leaves no
@@ -282,19 +283,20 @@ def test_chunked_expert_layer_compiles_at_the_sparse_cell_sizes(
               "down": array((held, f, d), jnp.float32)}
 
     def layer(u, w, params, chosen):  # the router's own gradient aside
-        return moe.expert_layer(u, chosen, w, params, (0, held))
+        return moe.expert_layer(u, chosen, w, params, (0, held), experts=64)
 
     fn = layer if direction == "forward" else jax.grad(
         lambda *a: jnp.sum(layer(*a).astype(jnp.float32) ** 2), (0, 1, 2))
     text = jax.jit(fn).lower(
         array((t, d), jnp.bfloat16), array((t, k), jnp.float32), params,
         array((t, k), jnp.int32)).compile().as_text()
-    assert moe.chunk_rows(t, t * k) == t
+    chunk = moe.chunk_rows(t, k, held, 64)
+    assert chunk == 15360
     directions = 1 if direction == "forward" else 2
     assert len(re.findall(r"\swhile\(", text)) == directions
     assert not re.search(r"\sconditional\(", text)
     assert not re.search(r"\sscatter\(", text)
-    assert "ragged-dot" in text and f"[{t},{2 * f}]" in text
+    assert "ragged-dot" in text and f"[{chunk},{2 * f}]" in text
     assert f"[{t * k},{2 * f}]" not in text and f"[{t * k},{f}]" not in text
     assert f"[{t * k},{d}]" not in text
 
