@@ -496,6 +496,14 @@ def _forward(params, tokens, cfg: TransformerConfig, *,
                     if cfg.sparse_topk and cfg.index_rope else None)
     index_blocks = (_index_kernel_blocks(cfg, tokens.shape[1], blocks)
                     if cfg.sparse_topk else None)
+    mask_heads = 0   # the query heads a sparse forward step takes
+    if cfg.sparse_topk and blocks is not None:
+        from ..ops.pallas.flash_attention import mask_heads as heads_a_step
+
+        # the Q block and dtype `sparse_attention`'s forward kernel takes
+        mask_heads = heads_a_step(cfg.n_heads, cfg.kv_heads,
+                                  min(blocks[0], tokens.shape[1]),
+                                  cfg.head_dim, cfg.dtype)
 
     def _attend(x, blk, window, rope):
         """x + the block's attention over heads of one width."""
@@ -593,7 +601,8 @@ def _forward(params, tokens, cfg: TransformerConfig, *,
                     kernel=blocks is not None, window=kind[0] is not None,
                     kept=keeps, latent=bool(cfg.kv_latent),
                     sparse=bool(cfg.sparse_topk),
-                    indexer_kernel=index_blocks is not None)
+                    indexer_kernel=index_blocks is not None,
+                    mask_heads=mask_heads)
             kept = _kept_bytes(tokens.shape, cfg, keeps and not conv,
                                cfg.remat and "experts" in blk,
                                cfg.remat and "experts" in blk)

@@ -40,8 +40,9 @@ Two kernel families of the same machinery beside them: `latent_attention`
 (``hvd_mla_*``: a score of two products, one rotary key for all heads)
 and `sparse_attention` (``hvd_dsa_*``: learned sparse attention, each
 query over the keys an indexer selected, the selection unpacked from
-int32 words in the tile and a tile that holds none skipped), with
-`sparse_target`, the indexer's training target, from the same tiles.
+int32 words in the tile once for the query heads that read it and a
+tile that holds none skipped), with `sparse_target`, the indexer's
+training target, from the same tiles.
 
 On non-TPU backends the kernels run in Pallas interpret mode (tests on
 the virtual CPU mesh), so one code path serves everywhere.
@@ -56,6 +57,7 @@ recomputations and directions call it.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import functools
 import sys
 
@@ -1091,6 +1093,18 @@ latent_attention.defvjp(_latent_fwd, _latent_bwd_rule)
 
 # -- learned sparse attention: each query over the keys it selected --------
 
+#: VMEM the sparse kernels ask for besides what their heads or their
+#: unpacked mask add: the compiler's default scoped limit on a v5e, in
+#: which the one-head kernels and the [bq, bk] float32 tiles a head's
+#: body makes fit at 1024-blocks
+DSA_TILE_VMEM = 16 * 2 ** 20
+#: VMEM the sparse forward kernel gives the query heads of one grid step
+#: (`mask_heads`): eight heads of 128 at 1024-blocks in bf16, a key/value
+#: head's group in the learned-sparse cell, take 20.5 MiB of it, and the
+#: call then asks for 36.5 MiB of the v5e's 128
+DSA_HEADS_VMEM = 24 * 2 ** 20
+
+
 def _unpack(words):
     """[n, cols] int32 words → [32 n, cols] bool: row ``32 r + i`` is bit
     ``i`` of word row ``r``."""
@@ -1102,9 +1116,19 @@ def _unpack(words):
     return lax.ne(lax.reshape(lax.bitwise_and(bit, 1), (n * WORD, cols)), 0)
 
 
+def _head_columns(h, d: int):
+    """Head ``h``'s ``d`` columns of a block that holds a step's heads
+    side by side: a static slice, or in a loop over the heads a dynamic
+    one a lane multiple apart."""
+    if isinstance(h, int):
+        return slice(h * d, (h + 1) * d)
+    return pl.ds(pl.multiple_of(lax.mul(h, d), d), d)
+
+
 def _dsa_fwd_kernel(tiles_ref, q_ref, k_ref, v_ref, sel_ref, o_ref, lse_ref,
                     acc_scr, m_scr, l_scr, *, scale: float, nq: int, nk: int):
     b, qi, ki = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    hs, _, d = acc_scr.shape   # the step's query heads, one K/V head's
 
     @pl.when(lax.eq(ki, 0))
     def _init():
@@ -1114,29 +1138,37 @@ def _dsa_fwd_kernel(tiles_ref, q_ref, k_ref, v_ref, sel_ref, o_ref, lse_ref,
 
     @pl.when(lax.ne(tiles_ref[(b * nq + qi) * nk + ki], 0))
     def _block():
-        keep = _unpack(sel_ref[0])                        # [bq, bk]
-        s = lax.mul(_dot(q_ref[0], k_ref[0], _NT), scale)
-        s = lax.select(keep, s, lax.full_like(s, NEG_INF))
-        m_prev = m_scr[...]
-        m_new = lax.max(m_prev, _row_max(s))
-        # a row with no selected key in this tile adds nothing, also
-        # while its running max is still NEG_INF
-        p = lax.select(keep, lax.exp(lax.sub(s, m_new)), lax.full_like(s, 0))
-        alpha = lax.exp(lax.sub(m_prev, m_new))
-        l_scr[...] = lax.add(lax.mul(l_scr[...], alpha), _row_sum(p))
-        v = v_ref[0]
-        acc_scr[...] = lax.add(
-            lax.mul(acc_scr[...], alpha),
-            _dot(lax.convert_element_type(p, v.dtype), v, _NN))
-        m_scr[...] = m_new
+        keep = _unpack(sel_ref[0])            # [bq, bk], once for the heads
+        k, v = k_ref[0], v_ref[0]
+
+        def head(h):
+            s = lax.mul(_dot(q_ref[0, :, _head_columns(h, d)], k, _NT), scale)
+            s = lax.select(keep, s, lax.full_like(s, NEG_INF))
+            m_prev = m_scr[h]
+            m_new = lax.max(m_prev, _row_max(s))
+            # a row with no selected key in this tile adds nothing, also
+            # while its running max is still NEG_INF
+            p = lax.select(keep, lax.exp(lax.sub(s, m_new)),
+                           lax.full_like(s, 0))
+            alpha = lax.exp(lax.sub(m_prev, m_new))
+            l_scr[h] = lax.add(lax.mul(l_scr[h], alpha), _row_sum(p))
+            acc_scr[h] = lax.add(
+                lax.mul(acc_scr[h], alpha),
+                _dot(lax.convert_element_type(p, v.dtype), v, _NN))
+            m_scr[h] = m_new
+
+        # a loop the compiler schedules once; unrolled, every head's
+        # [bq, bk] tiles would be live at once
+        lax.fori_loop(0, hs, lambda h, carry: (head(h), carry)[1], 0)
 
     @pl.when(lax.eq(ki, nk - 1))
     def _finalize():
-        l = l_scr[...]
-        safe = lax.select(lax.eq(l, 0.0), lax.full_like(l, 1.0), l)
-        o_ref[0] = lax.convert_element_type(lax.div(acc_scr[...], safe),
-                                            o_ref.dtype)
-        lse_ref[0] = _as_row(lax.add(m_scr[...], lax.log(safe)))
+        for h in range(hs):
+            l = l_scr[h]
+            safe = lax.select(lax.eq(l, 0.0), lax.full_like(l, 1.0), l)
+            o_ref[0, :, _head_columns(h, d)] = lax.convert_element_type(
+                lax.div(acc_scr[h], safe), o_ref.dtype)
+            lse_ref[h] = _as_row(lax.add(m_scr[h], lax.log(safe)))
 
 
 def _dsa_bwd_dq_kernel(tiles_ref, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
@@ -1172,23 +1204,33 @@ def _dsa_bwd_dq_kernel(tiles_ref, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
 
 
 def _dsa_bwd_dkv_kernel(tiles_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                        delta_ref, sel_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                        scale: float, nq: int, nk: int, group: int):
+                        delta_ref, sel_ref, dk_ref, dv_ref, dk_scr, dv_scr,
+                        mask_scr, *, scale: float, nq: int, nk: int,
+                        group: int):
     b, ki, step = pl.program_id(0), pl.program_id(2), pl.program_id(3)
-    qi = step if group == 1 else lax.rem(step, nq)
+    # the inner axis walks the Q blocks, and for each the ``group`` query
+    # heads of this key/value head one after the other: they read one
+    # selection tile, unpacked at the first of them into ``mask_scr``
+    qi = lax.div(step, group)
+    live = lax.ne(tiles_ref[(b * nq + qi) * nk + ki], 0)
 
     @pl.when(lax.eq(step, 0))
     def _init():
         dk_scr[...] = _zeros(dk_scr)
         dv_scr[...] = _zeros(dv_scr)
 
-    @pl.when(lax.ne(tiles_ref[(b * nq + qi) * nk + ki], 0))
+    @pl.when(lax.bitwise_and(live, lax.eq(lax.rem(step, group), 0)))
+    def _unpack_once():
+        mask_scr[...] = lax.convert_element_type(_unpack(sel_ref[0]),
+                                                 jnp.int32)
+
+    @pl.when(live)
     def _block():
         # held transposed, a K row per sublane, as `_flash_bwd_dkv_kernel`
         q, do = q_ref[0], do_ref[0]
         st = lax.mul(_dot(k_ref[0], q, _NT), scale)      # [bk, bq]
         pt = lax.exp(lax.sub(st, lse_ref[0]))
-        pt = lax.select(_unpack(sel_ref[0]), pt, lax.full_like(pt, 0))
+        pt = lax.select(lax.ne(mask_scr[...], 0), pt, lax.full_like(pt, 0))
         dst = lax.mul(pt, lax.sub(_dot(v_ref[0], do, _NT), delta_ref[0]))
         dv_scr[...] = lax.add(dv_scr[...], _dot(
             lax.convert_element_type(pt, do.dtype), do, _NN))
@@ -1216,7 +1258,9 @@ def _dsa_name(stem: str, topk: int) -> str:
 
 
 def _dsa_call(kernel, name: str, grid, in_specs, out_specs, out_shape,
-              scratch, interpret: bool, tiles, *args):
+              scratch, interpret: bool, tiles, *args, vmem=None):
+    """``vmem``: the kernel's VMEM limit in bytes (None: the compiler's
+    default)."""
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1224,9 +1268,50 @@ def _dsa_call(kernel, name: str, grid, in_specs, out_specs, out_shape,
             in_specs=[_after_prefetch(s) for s in in_specs],
             out_specs=[_after_prefetch(s) for s in out_specs],
             scratch_shapes=scratch),
-        out_shape=out_shape, compiler_params=_GRID_SEMANTICS,
+        out_shape=out_shape, compiler_params=_GRID_SEMANTICS if vmem is None
+        else dataclasses.replace(_GRID_SEMANTICS, vmem_limit_bytes=vmem),
         interpret=interpret, name=name,
     )(tiles, *args)
+
+
+def _head_vmem(block_q: int, d: int, dtype) -> int:
+    """The VMEM one query head adds to a step of the sparse forward
+    kernel at Q blocks of ``block_q`` rows and heads ``d`` wide in
+    ``dtype``: its Q and O blocks, each double-buffered, its float32
+    accumulator, its running max and sum ([bq, 1] columns a lane tile
+    wide) and its lse block (a row eight sublanes high, twice)."""
+    return block_q * (4 * d * jnp.dtype(dtype).itemsize
+                      + 4 * d + 2 * 4 * LANES + 2 * 4 * 8)
+
+
+def mask_heads(heads: int, kv_heads: int, block_q: int, d: int,
+               dtype) -> int:
+    """The query heads one grid step of the sparse forward kernel takes,
+    all of one key/value head's: the largest divisor of the group whose
+    `_head_vmem` fits `DSA_HEADS_VMEM`, one at least. The step unpacks
+    its selection tile once for them all."""
+    group = heads // kv_heads
+    return max(n for n in range(1, group + 1) if group % n == 0 and (
+        n == 1 or n * _head_vmem(block_q, d, dtype) <= DSA_HEADS_VMEM))
+
+
+def _head_block_specs(bq: int, bk: int, d: int, hs: int, heads: int,
+                      kv_heads: int, q_block, k_block):
+    """BlockSpecs over a grid ``(b, g, x, y)`` whose ``g`` walks the query
+    heads ``hs`` at a time, all ``hs`` of one key/value head's: their
+    ``hs*d`` adjacent columns of a block of rows of a [b, s, heads*d]
+    array (Q-like), their key/value head's ``d`` columns of a [b, s,
+    kv_heads*d] array (K-like), and their (hs, 1, bq) block of a
+    [b*heads, 1, s] row statistic. With ``hs`` 1, `_specs`' blocks for
+    grouped heads."""
+    steps = heads // kv_heads // hs   # grid steps a key/value head
+    return (
+        pl.BlockSpec((1, bq, hs * d), lambda b, g, x, y: (
+            b, q_block(x, y), g)),
+        pl.BlockSpec((1, bk, d), lambda b, g, x, y: (
+            b, k_block(x, y), lax.div(g, steps))),
+        pl.BlockSpec((hs, 1, bq), lambda b, g, x, y: (
+            lax.add(lax.mul(b, heads // hs), g), 0, q_block(x, y))))
 
 
 def _dsa_blocks(q, k, selection, block_q: int, block_k: int, heads: int,
@@ -1258,22 +1343,24 @@ def _dsa_fwd_lse(q, k, v, selection, block_q: int, block_k: int, heads: int,
                                              block_k, heads, kv_heads)
     by_query, _, tiles = selection
     nq, nk = s // bq, s // bk
+    hs = mask_heads(heads, kv_heads, bq, d, q.dtype)
     vma = _vma(q, k, v)
     k_block = functools.partial(_visible_k_block, True, bq, bk, 0)
-    q_spec, k_spec, row_spec = _specs(bq, bk, d, heads, lambda i, j: i,
-                                      k_block,
-                                      kv_head=_kv_head_of(heads, kv_heads))
-    sel_spec = pl.BlockSpec((1, bq // WORD, bk), lambda b, h, i, j: (
+    q_spec, k_spec, row_spec = _head_block_specs(
+        bq, bk, d, hs, heads, kv_heads, lambda i, j: i, k_block)
+    sel_spec = pl.BlockSpec((1, bq // WORD, bk), lambda b, g, i, j: (
         b, i, k_block(i, j)))
     row = jax.ShapeDtypeStruct((B * heads, 1, s), jnp.float32, vma=vma)
     return _dsa_call(
         functools.partial(_dsa_fwd_kernel, scale=d ** -0.5, nq=nq, nk=nk),
-        _dsa_name("hvd_dsa_fwd", topk), (B, heads, nq, nk),
+        _dsa_name("hvd_dsa_fwd", topk), (B, heads // hs, nq, nk),
         [q_spec, k_spec, k_spec, sel_spec], [q_spec, row_spec],
         [jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma), row],
-        [pltpu.VMEM((bq, d), jnp.float32), pltpu.VMEM((bq, 1), jnp.float32),
-         pltpu.VMEM((bq, 1), jnp.float32)],
-        interpret, tiles, q, k, v, by_query)
+        [pltpu.VMEM((hs, bq, d), jnp.float32),
+         pltpu.VMEM((hs, bq, 1), jnp.float32),
+         pltpu.VMEM((hs, bq, 1), jnp.float32)],
+        interpret, tiles, q, k, v, by_query,
+        vmem=DSA_TILE_VMEM + hs * _head_vmem(bq, d, q.dtype))
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k", "heads",
@@ -1281,7 +1368,9 @@ def _dsa_fwd_lse(q, k, v, selection, block_q: int, block_k: int, heads: int,
 def _dsa_bwd(q, k, v, selection, do, o, lse, block_q: int, block_k: int,
              heads: int, kv_heads: int, topk: int):
     """The VJP of `sparse_attention` as two kernels, as `_flash_bwd`:
-    (dq, dk, dv)."""
+    (dq, dk, dv). The dK/dV kernel's inner axis walks the Q blocks and,
+    for each, the group's query heads, so that they read one unpacked
+    selection tile."""
     B, s, d, bq, bk, interpret = _dsa_blocks(q, k, selection, block_q,
                                              block_k, heads, kv_heads)
     by_query, by_key, tiles = selection
@@ -1306,15 +1395,20 @@ def _dsa_bwd(q, k, v, selection, do, o, lse, block_q: int, block_k: int,
          pltpu.VMEM((bq, 1), jnp.float32)],
         interpret, tiles, q, k, v, do, o, lse, by_query)
 
+    def first_q(j):   # the first Q block that sees K block j
+        return lax.div(lax.mul(j, bk), bq)
+
     def q_block(j, i):   # Q blocks above K block j's diagonal hold
-        if group > 1:
-            i = lax.rem(i, nq)
-        return lax.min(lax.max(i, lax.div(lax.mul(j, bk), bq)), nq - 1)
+        return lax.min(lax.max(lax.div(i, group), first_q(j)), nq - 1)
+
+    def q_head(h, j, i):  # and there the group's first head: no DMA
+        held = lax.lt(lax.div(i, group), first_q(j))
+        return lax.add(lax.mul(h, group),
+                       lax.select(held, 0, lax.rem(i, group)))
 
     q_spec, k_spec, row_spec = _specs(
         bq, bk, d, heads, q_block, lambda j, i: j,
-        q_head=None if group == 1 else (
-            lambda h, j, i: lax.add(lax.mul(h, group), lax.div(i, nq))))
+        q_head=q_head)
     sel_spec = pl.BlockSpec((1, bk // WORD, bq), lambda b, h, j, i: (
         b, j, q_block(j, i)))
     dk, dv = _dsa_call(
@@ -1324,8 +1418,10 @@ def _dsa_bwd(q, k, v, selection, do, o, lse, block_q: int, block_k: int,
         [k_spec, k_spec],
         [jax.ShapeDtypeStruct(k.shape, k.dtype, vma=vma),
          jax.ShapeDtypeStruct(v.shape, v.dtype, vma=vma)],
-        [pltpu.VMEM((bk, d), jnp.float32), pltpu.VMEM((bk, d), jnp.float32)],
-        interpret, tiles, q, k, v, do, lse, delta, by_key)
+        [pltpu.VMEM((bk, d), jnp.float32), pltpu.VMEM((bk, d), jnp.float32),
+         pltpu.VMEM((bk, bq), jnp.int32)],
+        interpret, tiles, q, k, v, do, lse, delta, by_key,
+        vmem=DSA_TILE_VMEM + bk * bq * 4)
     return dq, dk, dv
 
 
@@ -1347,7 +1443,11 @@ def sparse_attention(q, k, v, selection, block_q: int = 512,
     ``hvd_dsa_bwd_dq``, ``hvd_dsa_bwd_dkv``, each ``_k<topk>``:
     ``topk``, the keys a query selects, names them and changes nothing
     else): `flash_attention`'s bodies with the selection as the mask of
-    every tile, and a tile that holds no selected key skipped."""
+    every tile, and a tile that holds no selected key skipped. The
+    selection is one per query, whatever the head: the forward kernel
+    takes `mask_heads` query heads of one key/value head a grid step and
+    the dK/dV kernel walks a group's heads one after the other on a
+    tile, each unpacking the tile once for them."""
     with _pinned_mesh():
         return _dsa_fwd_lse(q, k, v, selection, block_q, block_k, heads,
                             kv_heads or heads, topk)
@@ -1385,16 +1485,20 @@ def _dsa_target_kernel(tiles_ref, first_ref, q_ref, k_ref, lse_ref, sel_ref,
     def _init():
         p_ref[...] = _zeros(p_ref)
 
-    @pl.when(lax.ne(tiles_ref[(b * nq + qi) * nk + ki], 0))
+    live = lax.ne(tiles_ref[(b * nq + qi) * nk + ki], 0)
+
+    @pl.when(live)
     def _block():
         s = lax.mul(_dot(q_ref[0], k_ref[0], _NT), scale)
-        p = lax.exp(lax.sub(s, _as_col(lse_ref[0])))
-        p_ref[0] = lax.add(p_ref[0], lax.select(
-            _unpack(sel_ref[0]), p, lax.full_like(p, 0)))
+        p_ref[0] = lax.add(p_ref[0], lax.exp(lax.sub(s, _as_col(lse_ref[0]))))
 
-    @pl.when(lax.eq(h, heads - 1))
+    @pl.when(lax.bitwise_and(live, lax.eq(h, heads - 1)))
     def _finalize():
-        p_ref[0] = lax.mul(p_ref[0], 1.0 / heads)
+        # the selection masks the heads' sum once, not each head's term:
+        # a key off it summed at most to inf (never NaN), and goes here
+        p = p_ref[0]
+        p_ref[0] = lax.mul(lax.select(_unpack(sel_ref[0]), p,
+                                      lax.full_like(p, 0)), 1.0 / heads)
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k", "heads",
@@ -1409,8 +1513,9 @@ def sparse_target(q, k, lse, by_query, first, block_q: int, block_k: int,
     [B, c/32, s]. One kernel (``hvd_dsa_target_k<topk>``) whose grid
     walks the heads innermost and sums them in its output tile; a tile
     that holds no selected key is skipped, and one past the chunk's last
-    query is not fetched. The indexer's KL target, without the scores'
-    logits ever in HBM."""
+    query is not fetched; the selection masks the heads' sum once, at
+    the last head. The indexer's KL target, without the scores' logits
+    ever in HBM."""
     from ..selection import tiles as tiles_of
 
     B, c, width = q.shape

@@ -79,7 +79,9 @@ the decoder's default one (a looped decoder's once a pass) and
 ``attention_latent_calls`` of them over latent heads,
 ``attention_sparse_calls`` of them over the keys each query selected
 (learned sparse attention) and ``indexer_kernel_calls`` of those whose
-indexer's scores run through its kernels, ``attention_kept_calls``
+indexer's scores run through its kernels (``sparse_mask_heads``: where
+their kernels are taken, the query heads one selection tile unpacked in
+the forward kernel serves; a width, not a count), ``attention_kept_calls``
 of them in a checkpointed block that keeps the kernels' residuals, and
 ``remat_kept_mb``, those residuals' and a kept choice's bytes / 1e6 over the blocks
 (counts all: each layer is noted once, outside its checkpoint); of a sparse-expert
@@ -394,7 +396,8 @@ def note_exchange(buffers, axis_name: str, packed: bool = False) -> None:
 
 def note_attention(kernel: bool, window: bool = False,
                    kept: bool = False, latent: bool = False,
-                   sparse: bool = False, indexer_kernel: bool = False) -> None:
+                   sparse: bool = False, indexer_kernel: bool = False,
+                   mask_heads: int = 0) -> None:
     """Called once per layer whose attention is the default one, where
     ``models/transformer.py`` lays its blocks out (outside
     ``jax.checkpoint``, which traces a block of one kind once however
@@ -404,7 +407,9 @@ def note_attention(kernel: bool, window: bool = False,
     ``kept``: in a checkpointed block whose policy keeps the kernels'
     residuals (`KEPT_BY_REMAT`); ``sparse``: over the keys each query
     selected, ``indexer_kernel``: the indexer's scores through its
-    kernels (``hvd_dsa_index_*``). A no-op outside a traced
+    kernels (``hvd_dsa_index_*``), ``mask_heads``: where the sparse
+    kernels are taken, the query heads one selection tile unpacked in
+    their forward kernel serves. A no-op outside a traced
     ``data_parallel_step``."""
     record = _tracing.get()
     if record is None:
@@ -427,6 +432,8 @@ def note_attention(kernel: bool, window: bool = False,
                                        + times)
         c["indexer_kernel_calls"] = (c.get("indexer_kernel_calls", 0)
                                      + times * int(indexer_kernel))
+    if mask_heads:
+        c["sparse_mask_heads"] = mask_heads
 
 
 def note_kept(nbytes: int) -> None:

@@ -30,7 +30,9 @@ F = importlib.import_module("horovod_tpu.ops.pallas.flash_attention")
 #: one with windowed layers, QK-norm and sigmoid-routed experts, and
 #: learned sparse attention over heads of 128. The programs of the three
 #: with experts are taken anew since their sorted rows are walked in
-#: chunks of the rows the held experts expect, not of the tokens
+#: chunks of the rows the held experts expect, not of the tokens, and
+#: the learned-sparse one on the kernels again since they unpack a
+#: selection tile once for the query heads that share it
 PINNED = {
     "learned_sparse": (dict(
         vocab_size=48, d_model=32, n_heads=4, n_kv_heads=2, d_head=8,
@@ -62,7 +64,7 @@ PINNED = {
         tie_embeddings=False, router_input="normed",
         expert_activation="silu", qk_norm=True, sparse_topk=40,
         index_heads=4, index_dim=8, index_rope=4, index_chunk=64,
-        remat=True), (1, 257), "3489d1c5737f0def", "a3c82d49b2e6a9e0"),
+        remat=True), (1, 257), "3489d1c5737f0def", "d5a1b54ff1d7664b"),
 }
 
 

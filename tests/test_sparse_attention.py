@@ -203,14 +203,25 @@ def masked_attention(q, k, v, keep, heads):
     return jnp.einsum("bhst,bthd->bshd", probs, vh).reshape(B, s, width)
 
 
-@pytest.mark.parametrize("heads,kv", [(4, 2), (2, 2)], ids=["grouped", "mha"])
-def test_sparse_kernels_against_a_masked_einsum(heads, kv):
+@pytest.mark.parametrize("heads,kv,hs", [
+    (4, 2, 2), (2, 2, 1), (8, 2, 4), (8, 2, 2)],
+    ids=["grouped", "mha", "group4", "group4_split"])
+def test_sparse_kernels_against_a_masked_einsum(heads, kv, hs, monkeypatch,
+                                                request):
     """(e) ``hvd_dsa_fwd``, ``hvd_dsa_bwd_dq``, ``hvd_dsa_bwd_dkv`` and
     ``hvd_dsa_target`` in interpret mode: 40 keys of each query's past by
     a random score, a tile emptied by hand (skipped), forward and
     backward against the masked einsum, the target against the einsum
-    path's."""
+    path's. The forward kernel takes ``hs`` query heads a step, one
+    unpacked tile for them all (`F.mask_heads`): the whole group where
+    its blocks fit the budget, two of four where the budget holds two."""
     B, s, d, block, topk = 1, 256, 32, 128, 40
+    if hs < heads // kv:   # a budget that holds ``hs`` heads' blocks
+        monkeypatch.setattr(F, "DSA_HEADS_VMEM",
+                            hs * F._head_vmem(block, d, jnp.float32))
+        jax.clear_caches()   # the kernels traced at the default budget
+        request.addfinalizer(jax.clear_caches)   # and those at this one
+    assert F.mask_heads(heads, kv, block, d, jnp.float32) == hs
     q, k, v, do = (jax.random.normal(jax.random.PRNGKey(i), (B, s, w * d))
                    for i, w in ((10, heads), (11, kv), (12, kv), (13, heads)))
     score = jax.random.normal(jax.random.PRNGKey(14), (B, s, s))
@@ -389,6 +400,29 @@ def test_the_indexer_kernel_counter(path, monkeypatch):
     assert record.counters["attention_sparse_calls"] == 4
     assert record.counters["indexer_kernel_calls"] == (
         4 if path == "kernels" else 0)
+
+
+@pytest.mark.parametrize("sparse", [True, False], ids=["sparse", "dense"])
+def test_the_mask_heads_counter(sparse, monkeypatch):
+    """(m) ``sparse_mask_heads`` is the query heads one unpacked selection
+    tile serves in the forward kernel (`F.mask_heads`): at toy widths the
+    whole group of two; a program with no sparse layer notes none."""
+    cfg, _ = on_the_kernels(monkeypatch)
+    if not sparse:
+        cfg = T.dataclasses.replace(cfg, sparse_topk=0)
+    monkeypatch.setattr(T, "_partitioned_by_xla", lambda: False)  # one chip
+    record = scopes.StepRecord()
+    with scopes.recording(record):
+        jax.eval_shape(lambda p, t: T.lm_loss(p, t, cfg, use_constraints=False),
+                       jax.eval_shape(lambda: T.init(jax.random.PRNGKey(0),
+                                                     cfg)),
+                       jax.ShapeDtypeStruct((1, 257), jnp.int32))
+    assert record.counters["attention_kernel_calls"] == cfg.n_layers
+    if sparse:
+        assert record.counters["sparse_mask_heads"] == F.mask_heads(
+            cfg.n_heads, cfg.kv_heads, 128, cfg.head_dim, cfg.dtype) == 2
+    else:
+        assert "sparse_mask_heads" not in record.counters
 
 
 def test_the_held_shares_add_up_under_this_router():

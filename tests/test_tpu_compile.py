@@ -182,10 +182,16 @@ def test_sparse_kernels_compile_at_the_dsa_decoder_shapes(topo):
     query and by key ([1, 512, 16384] int32 each) and a word a tile, at
     the blocks `block_sizes` gives, and the indexer's target over a chunk
     of 512 queries. The TPU compiler takes all four kernels (the
-    selection's words unpacked in the tile), under names of their own
-    that carry the keys a query selects and that no reader of
-    ``hvd_flash_*`` or ``hvd_mla_*`` matches."""
+    selection's words unpacked in the tile, once for the query heads a
+    step takes, within the VMEM their calls ask for), under names of
+    their own that carry the keys a query selects and that no reader of
+    ``hvd_flash_*`` or ``hvd_mla_*`` matches; the reader of
+    ``sparse_attention_roofline_pct`` (``chipbench/dsa_reads.py``)
+    counts the operations of each attention kernel from its compiled
+    instruction's text."""
     import re
+
+    from chipbench import dsa_reads
 
     one = SingleDeviceSharding(topo.devices[0])
     heads, kv, s, topk = 32, 4, 16384, 2048
@@ -220,6 +226,16 @@ def test_sparse_kernels_compile_at_the_dsa_decoder_shapes(topo):
     others = re.compile(r"^hvd_(flash|mla)_(fwd|bwd_dq|bwd_dkv)(_w\d+)?$")
     assert not any(others.match(f"hvd_dsa_{n}_k{topk}")
                    for n in ("fwd", "bwd_dq", "bwd_dkv", "target"))
+    counted = {}
+    for line in (fwd.as_text() + text).splitlines():
+        line = line.strip().removeprefix("ROOT ")
+        found = dsa_reads._DSA.match(line.split(" ", 1)[0])
+        if found:
+            counted[found.group(1)] = dsa_reads._dsa_flops(
+                found.group(1), int(found.group(2)), line)
+    assert sorted(counted) == ["hvd_dsa_bwd_dkv", "hvd_dsa_bwd_dq",
+                               "hvd_dsa_fwd"]
+    assert all(isinstance(f, float) and f > 0 for f in counted.values())
 
 
 def test_indexer_kernels_compile_at_the_dsa_decoder_shapes(topo):
